@@ -21,7 +21,7 @@ import numpy as np
 
 from repro.core.cache_model import simulate_lru
 from repro.core.layout import blockize, blockize_with_halo
-from repro.core.neighbors import FACE_COLS, SELF_COL, neighbor_table, neighbor_table_device
+from repro.core.neighbors import FACE_COLS, SELF_COL, neighbor_table
 from repro.kernels.flash_attn import build_schedule, flash_attention_fwd
 from repro.kernels.ops import uniform_weights
 from repro.kernels.stencil3d import (stencil_step_fused, stencil_sum_blocks,
@@ -131,7 +131,7 @@ def resident_kernel_rows(M: int = 16, T: int = 8, g: int = 1,
                 f";hbm_items_per_substep={repack_items_per_step(M, T, g)}"))
 
     store = blockize(cube, T, kind=kind)
-    nbr = neighbor_table_device(kind, M // T)
+    nbr = neighbor_table(kind, M // T)
     stencil_sum_resident(store, w, nbr, g=g)  # compile
     t0 = time.perf_counter()
     for _ in range(3):

@@ -81,6 +81,9 @@ def collect(fast: bool = False) -> list[tuple[str, float, str]]:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     json_path = None
     if "--json" in sys.argv:

@@ -1,16 +1,15 @@
 """Distributed gol3d: 2×2×2 device mesh, SFC halo packing, ppermute rings.
 
-Part 1 (parent process): the resident-block pipeline — blockize once,
+Part 1: the resident-block pipeline — blockize once,
 run K steps entirely in curve order with in-kernel halo streaming and
 S-deep temporal blocking (stencil/pipeline.py; S substeps per HBM
 round-trip), verify bit-identity against the per-step repack pipeline,
 and print the modelled per-substep HBM bytes of repack / unfused /
 fused forms plus the (T, S) the plan() autotuner picks.
 
-Part 2: spawns itself with 8 host devices (the dry-run rule: never force
-device count in the parent process), decomposes a 32³ cube onto the
-mesh, and runs 10 steps under each ordering two ways: the legacy
-per-step exchange (make_distributed_step) verified against the
+Part 2: on eight devices (host devices on the CPU, forced before JAX
+is imported), decomposes a 32³ cube onto a 2×2×2 mesh and runs 10
+steps under each ordering two ways: the legacy per-step exchange (make_distributed_step) verified against the
 single-device oracle, and the communication-avoiding DistributedPipeline
 (one deep S·g exchange per S fused substeps, DESIGN.md §7) verified
 bit-identical to the per-step form. This is the paper's parallel
@@ -20,7 +19,7 @@ exchange rings, shell-block boundary fill — and the modelled ICI
 savings table prints for both boundary contracts (mesh-edge shards
 skip the wrap links, so clamped shards move strictly fewer wire bytes).
 
-Part 3 (parent process): the multi-field store (DESIGN.md §9) — the
+Part 3: the multi-field store (DESIGN.md §9) — the
 C=2 FDTD-style wave rule rides the same fused resident pipeline at
 S ∈ {2, 4}, bit-identical to its sequential global oracle
 (kernels/ref.fields_step_ref), and the ×C bytes-model table prints the
@@ -32,8 +31,15 @@ Run: PYTHONPATH=src python examples/stencil_halo_demo.py
 """
 
 import os
-import subprocess
 import sys
+
+# Eight host devices for part 2's mesh, set before JAX is imported: the
+# whole demo runs in this one process (a child could not reach a chip
+# this process already holds). The flag touches only the CPU platform.
+_HOST_DEVICES = "--xla_force_host_platform_device_count=8"
+if _HOST_DEVICES not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS"), _HOST_DEVICES) if f)
 
 
 def resident_demo(M=32, g=1, T=8, steps=10, S=4):
@@ -141,92 +147,88 @@ def wave_demo(M=32, g=1, T=8, steps=8):
     print("multi-field wave OK")
 
 
-_WORKER = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import time
-import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.core import ROW_MAJOR, MORTON, HILBERT, NEUMANN0, PERIODIC
-from repro.stencil import (make_stencil_mesh, make_distributed_step,
-                           DistributedPipeline, shard_state, unshard_state,
-                           distributed_bytes_per_step, exchange_bytes_per_step)
-from repro.kernels import ref as kref
+def mesh_demo():
+    """Part 2: the distributed matrix on a 2×2×2 mesh of this process's
+    devices (eight host devices on the CPU)."""
+    import time
+    import numpy as np, jax, jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P, NamedSharding
+    from repro.core import ROW_MAJOR, MORTON, HILBERT, NEUMANN0, PERIODIC
+    from repro.stencil import (make_stencil_mesh, make_distributed_step,
+                               DistributedPipeline, shard_state, unshard_state,
+                               distributed_bytes_per_step, exchange_bytes_per_step)
+    from repro.kernels import ref as kref
 
-mesh = make_stencil_mesh((2, 2, 2))
-procs = (2, 2, 2)
-local_M, g, GM, steps = 16, 1, 32, 10
-rng = np.random.default_rng(0)
-gcube = (rng.random((GM, GM, GM)) < 0.35).astype(np.float32)
+    print("[stencil_halo_demo] distributed gol3d on a 2x2x2 mesh")
+    mesh = make_stencil_mesh((2, 2, 2))
+    procs = (2, 2, 2)
+    local_M, g, GM, steps = 16, 1, 32, 10
+    rng = np.random.default_rng(0)
+    gcube = (rng.random((GM, GM, GM)) < 0.35).astype(np.float32)
 
-sharding = NamedSharding(mesh, P("dx", "dy", "dz"))
-for bc in (PERIODIC, NEUMANN0):
-    print(f"  --- boundaries: {bc.kind} ---")
-    want = jnp.asarray(gcube)
-    for _ in range(steps):
-        want = kref.gol3d_step_ref(want, g, bc=bc)
-    want = np.asarray(want)
-    for spec in (ROW_MAJOR, MORTON, HILBERT):
-        st = jax.device_put(shard_state(jnp.asarray(gcube), spec, (2, 2, 2)),
-                            sharding)
-        # legacy reference: one exchange per step (S=1)
-        step = make_distributed_step(mesh, spec, local_M, g, bc=bc)
-        jax.block_until_ready(step(st))  # compile
-        t0 = time.perf_counter()
-        gs = st
+    sharding = NamedSharding(mesh, P("dx", "dy", "dz"))
+    for bc in (PERIODIC, NEUMANN0):
+        print(f"  --- boundaries: {bc.kind} ---")
+        want = jnp.asarray(gcube)
         for _ in range(steps):
-            gs = step(gs)
-        out_seq = np.asarray(jax.block_until_ready(gs))
-        dt_seq = (time.perf_counter() - t0) / steps
-        ok = np.array_equal(np.asarray(unshard_state(jnp.asarray(out_seq), spec, GM)), want)
-        line = f"  {spec.name:10s} per-step {dt_seq*1e3:6.1f} ms/step (oracle: {ok})"
-        assert ok
-        # communication-avoiding pipeline: one deep exchange per S substeps
-        for S in (2, 4):
-            pipe = DistributedPipeline(mesh=mesh, spec=spec, M=local_M, T=8,
-                                       g=g, S=S, bc=bc)
-            run = pipe.run_fn(steps)
-            jax.block_until_ready(run(st))  # compile
+            want = kref.gol3d_step_ref(want, g, bc=bc)
+        want = np.asarray(want)
+        for spec in (ROW_MAJOR, MORTON, HILBERT):
+            st = jax.device_put(shard_state(jnp.asarray(gcube), spec, (2, 2, 2)),
+                                sharding)
+            # legacy reference: one exchange per step (S=1)
+            step = make_distributed_step(mesh, spec, local_M, g, bc=bc)
+            jax.block_until_ready(step(st))  # compile
             t0 = time.perf_counter()
-            out = np.asarray(jax.block_until_ready(run(st)))
-            dt = (time.perf_counter() - t0) / steps
-            okS = np.array_equal(out, out_seq)  # bit-identical to S=1 reference
-            line += f"  S={S} {dt*1e3:6.1f} ms/step (bit-identical: {okS})"
-            assert okS
-        print(line)
+            gs = st
+            for _ in range(steps):
+                gs = step(gs)
+            out_seq = np.asarray(jax.block_until_ready(gs))
+            dt_seq = (time.perf_counter() - t0) / steps
+            ok = np.array_equal(np.asarray(unshard_state(jnp.asarray(out_seq), spec, GM)), want)
+            line = f"  {spec.name:10s} per-step {dt_seq*1e3:6.1f} ms/step (oracle: {ok})"
+            assert ok
+            # communication-avoiding pipeline: one deep exchange per S substeps
+            for S in (2, 4):
+                pipe = DistributedPipeline(mesh=mesh, spec=spec, M=local_M, T=8,
+                                           g=g, S=S, bc=bc)
+                run = pipe.run_fn(steps)
+                jax.block_until_ready(run(st))  # compile
+                t0 = time.perf_counter()
+                out = np.asarray(jax.block_until_ready(run(st)))
+                dt = (time.perf_counter() - t0) / steps
+                okS = np.array_equal(out, out_seq)  # bit-identical to S=1 reference
+                line += f"  S={S} {dt*1e3:6.1f} ms/step (bit-identical: {okS})"
+                assert okS
+            print(line)
 
-# modelled ICI savings per mesh shard: deep exchange (S) x boundary contract.
-# Periodic torus shards send both faces on every axis; clamped mesh-edge
-# shards skip the wrap links (DESIGN.md §8) - on a 2x2x2 mesh every shard
-# is a corner, so the clamped column is exactly half the torus volume.
-print("  modelled ICI bytes/step/shard (local M=16, g=1):")
-print("    S   periodic   clamped(mean)   edge-shard   clamped/periodic")
-for S in (1, 2, 4):
-    per = exchange_bytes_per_step(local_M, g, S)
-    mean = exchange_bytes_per_step(local_M, g, S, bc=NEUMANN0, procs=procs)
-    edge = exchange_bytes_per_step(local_M, g, S, bc=NEUMANN0, procs=procs,
-                                   coords=(0, 0, 0))
-    print(f"    {S}   {per/1e3:7.1f} KB {mean/1e3:10.1f} KB "
-          f"{edge/1e3:9.1f} KB   x{mean/per:.2f}")
-b1 = distributed_bytes_per_step(local_M, 8, g, steps, S=1)
-b4 = distributed_bytes_per_step(local_M, 8, g, steps, S=4)
-b4c = distributed_bytes_per_step(local_M, 8, g, steps, S=4, bc=NEUMANN0,
-                                 procs=procs)
-print(f"  modelled bytes/step/shard (HBM+ICI): S=1 {b1/1e3:.0f} KB -> "
-      f"S=4 {b4/1e3:.0f} KB (x{b1/b4:.2f}); clamped S=4 {b4c/1e3:.0f} KB")
-print("distributed gol3d OK (periodic + clamped)")
-"""
+    # modelled ICI savings per mesh shard: deep exchange (S) x boundary contract.
+    # Periodic torus shards send both faces on every axis; clamped mesh-edge
+    # shards skip the wrap links (DESIGN.md §8) - on a 2x2x2 mesh every shard
+    # is a corner, so the clamped column is exactly half the torus volume.
+    print("  modelled ICI bytes/step/shard (local M=16, g=1):")
+    print("    S   periodic   clamped(mean)   edge-shard   clamped/periodic")
+    for S in (1, 2, 4):
+        per = exchange_bytes_per_step(local_M, g, S)
+        mean = exchange_bytes_per_step(local_M, g, S, bc=NEUMANN0, procs=procs)
+        edge = exchange_bytes_per_step(local_M, g, S, bc=NEUMANN0, procs=procs,
+                                       coords=(0, 0, 0))
+        print(f"    {S}   {per/1e3:7.1f} KB {mean/1e3:10.1f} KB "
+              f"{edge/1e3:9.1f} KB   x{mean/per:.2f}")
+    b1 = distributed_bytes_per_step(local_M, 8, g, steps, S=1)
+    b4 = distributed_bytes_per_step(local_M, 8, g, steps, S=4)
+    b4c = distributed_bytes_per_step(local_M, 8, g, steps, S=4, bc=NEUMANN0,
+                                     procs=procs)
+    print(f"  modelled bytes/step/shard (HBM+ICI): S=1 {b1/1e3:.0f} KB -> "
+          f"S=4 {b4/1e3:.0f} KB (x{b1/b4:.2f}); clamped S=4 {b4c/1e3:.0f} KB")
+    print("distributed gol3d OK (periodic + clamped)")
 
 
 def main():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    sys.path.insert(0, env["PYTHONPATH"])
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     resident_demo()
     wave_demo()
-    print("[stencil_halo_demo] launching 8-device subprocess...")
-    r = subprocess.run([sys.executable, "-c", _WORKER], env=env)
-    raise SystemExit(r.returncode)
+    mesh_demo()
 
 
 if __name__ == "__main__":

@@ -2,7 +2,7 @@
 
 from .orderings import (  # noqa: F401
     OrderingSpec, ROW_MAJOR, COLUMN_MAJOR, MORTON, HILBERT,
-    rmo_to_path, path_to_rmo, path_index_2d, ordering_from_name,
+    rmo_to_path, path_to_rmo, path_positions, path_index_2d, ordering_from_name,
 )
 from .morton import (  # noqa: F401
     morton_encode3, morton_decode3, morton_encode2, morton_decode2,
@@ -22,8 +22,7 @@ from .layout import (  # noqa: F401
 )
 from .neighbors import (  # noqa: F401
     OFFSETS_FULL, OFFSETS_FACE, FACE_COLS, SELF_COL,
-    block_kind_of, boundary_face_table, neighbor_table,
-    neighbor_table_device, ring_perms,
+    block_kind_of, boundary_face_table, neighbor_table, ring_perms,
 )
 from .boundary import (  # noqa: F401
     BoundarySpec, MixedBoundary, PERIODIC, NEUMANN0, dirichlet, mixed,

@@ -13,89 +13,69 @@ kernel that walks blocks sequentially walks HBM contiguously.
 from __future__ import annotations
 
 import functools
-import threading
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .orderings import OrderingSpec, path_to_rmo, rmo_to_path, _check_pow2, _flat_index
 
+_SIMPLE_KINDS = ("row_major", "column_major", "morton", "hilbert")
+
 __all__ = [
-    "apply_ordering", "undo_ordering", "device_constant",
-    "block_order", "blockize", "unblockize", "blockize_with_halo",
-    "blockize_fields", "unblockize_fields", "store_spec",
+    "apply_ordering", "undo_ordering", "block_order", "blockize", "unblockize", "blockize_with_halo",
+    "blockize_fields", "unblockize_fields", "store_spec", "needs_element_perm",
 ]
 
 
-_DEVICE_CONSTANTS: dict = {}
-_DEVICE_CONSTANTS_CAP = 256
-# The serving path (serve/service.py) queries from a thread pool while
-# pipelines trace on the main thread; every read-modify-write of the
-# LRU dict must hold this lock (move-to-end + eviction are not atomic).
-_DEVICE_CONSTANTS_LOCK = threading.RLock()
+def _blocked_spec(spec: OrderingSpec) -> bool:
+    """True for a block-store ordering (``store_spec``): a simple curve
+    between tiles, row-major inside — realised by reshapes and an
+    nb-sized block gather instead of an M³ element permutation."""
+    return (spec.kind == "hybrid" and spec.inner == "row_major"
+            and spec.outer in _SIMPLE_KINDS)
 
 
-def device_constant(key, build):
-    """Memoised device copy of a precomputed (numpy) table.
-
-    Re-wrapping cached numpy tables at every trace made each jit embed a
-    fresh device constant; memoising the jnp array lets repeated jits
-    reuse one buffer. Creating a device array is only safe *outside*
-    tracing (inside jit/shard_map traces ``jnp.asarray`` yields a trace-
-    local tracer — caching it would leak), so under a trace this returns
-    the numpy table unmemoised — exactly the seed behaviour — while
-    eager call sites (e.g. Gol3d.__post_init__) populate the cache for
-    every later trace to reuse.
-
-    key:   hashable identity of the table
-    build: zero-arg callable producing the numpy array (cheap: the
-           numpy side is lru_cached upstream)
-
-    Eviction is LRU: a hit moves the entry to the back of the (insertion
-    -ordered) dict, so hot permutation/neighbour tables survive a full
-    sweep of one-off keys; eviction pops the front. Device buffers are
-    large (an M=256 permutation is 64 MiB), hence the cap.
-
-    Thread-safe: concurrent misses on the same key may both build (the
-    build is pure — last insert wins, benign), but the dict itself is
-    only ever mutated under the lock, so a concurrent sweep can never
-    corrupt the LRU order or lose entries mid-eviction.
-    """
-    with _DEVICE_CONSTANTS_LOCK:
-        hit = _DEVICE_CONSTANTS.get(key)
-        if hit is not None:
-            _DEVICE_CONSTANTS[key] = _DEVICE_CONSTANTS.pop(key)  # move-to-end
-            return hit
-    arr = build()
-    if jax.core.trace_state_clean():
-        arr = jnp.asarray(arr)
-        with _DEVICE_CONSTANTS_LOCK:
-            while len(_DEVICE_CONSTANTS) >= _DEVICE_CONSTANTS_CAP:
-                _DEVICE_CONSTANTS.pop(next(iter(_DEVICE_CONSTANTS)))
-            _DEVICE_CONSTANTS[key] = arr
-    return arr
-
-
-def _perm_device(spec: OrderingSpec, M: int, inverse: bool):
-    """Device-resident copy of the (int32) permutation, created once."""
-    return device_constant(
-        ("perm", spec, M, inverse),
-        lambda: rmo_to_path(spec, M) if inverse else path_to_rmo(spec, M))
+def needs_element_perm(spec: OrderingSpec) -> bool:
+    """True when relayout under ``spec`` gathers through an M³ element
+    permutation (jit embeds it: 4 GiB of int32 at M=1024). Row- and
+    column-major are a reshape and a transpose; block-store orderings
+    gather nb whole blocks."""
+    return spec.kind not in ("row_major", "column_major") \
+        and not _blocked_spec(spec)
 
 
 def apply_ordering(x: jnp.ndarray, spec: OrderingSpec) -> jnp.ndarray:
-    """Reorder an (M,M,M) cube into a flat (M³,) path-ordered vector."""
-    M = x.shape[0]
-    assert x.shape == (M, M, M), x.shape
-    q = _perm_device(spec, M, False)  # path pos -> rmo
-    return x.reshape(-1)[q]
+    """(..., M, M, M) canonical cubes -> (..., M³) path-ordered vectors.
+
+    Leading axes (channels, shards) ride along. Row-/column-major are a
+    reshape (and a transpose), block-store orderings (:func:`store_spec`)
+    gather nb whole blocks; any other ordering gathers through its M³
+    element permutation (:func:`needs_element_perm`).
+    """
+    M = x.shape[-1]
+    if x.shape[-3:] != (M, M, M):
+        raise ValueError(f"apply_ordering needs cubes, got {x.shape}")
+    lead = x.shape[:-3]
+    if spec.kind == "row_major":
+        return x.reshape(lead + (-1,))
+    if spec.kind == "column_major":
+        return jnp.swapaxes(x, -1, -3).reshape(lead + (-1,))
+    if _blocked_spec(spec):
+        return _to_blocks(x, spec.tile, spec.outer).reshape(lead + (-1,))
+    return jnp.take(x.reshape(lead + (-1,)), path_to_rmo(spec, M), axis=-1)
 
 
 def undo_ordering(v: jnp.ndarray, spec: OrderingSpec, M: int) -> jnp.ndarray:
-    """Inverse of :func:`apply_ordering`."""
-    p = _perm_device(spec, M, True)  # rmo -> path pos
-    return v[p].reshape(M, M, M)
+    """Inverse of :func:`apply_ordering`: (..., M³) -> (..., M, M, M)."""
+    lead = v.shape[:-1]
+    if spec.kind == "row_major":
+        return v.reshape(lead + (M,) * 3)
+    if spec.kind == "column_major":
+        return jnp.swapaxes(v.reshape(lead + (M,) * 3), -1, -3)
+    if _blocked_spec(spec):
+        T = spec.tile
+        return _from_blocks(v.reshape(lead + (-1, T, T, T)), M, spec.outer)
+    return jnp.take(v, rmo_to_path(spec, M), axis=-1).reshape(lead + (M,) * 3)
 
 
 @functools.lru_cache(maxsize=64)
@@ -107,7 +87,7 @@ def block_order(kind: str, nt: int) -> np.ndarray:
     """
     _check_pow2(nt)
     if nt == 1:  # single-block grid: every curve is trivial
-        if kind not in ("row_major", "column_major", "morton", "hilbert"):
+        if kind not in _SIMPLE_KINDS:
             raise ValueError(f"unknown simple ordering {kind!r}")
         out = np.zeros((1, 3), dtype=np.int64)
         out.setflags(write=False)
@@ -123,20 +103,18 @@ def block_order(kind: str, nt: int) -> np.ndarray:
     return out
 
 
+@functools.lru_cache(maxsize=64)
 def _block_perm(kind: str, nt: int, inverse: bool) -> np.ndarray:
+    """Block permutation path↔linear (int32, read-only). Jit embeds it:
+    nt³ entries, not M³."""
     bo = block_order(kind, nt)
     lin = (bo[:, 0] * nt * nt + bo[:, 1] * nt + bo[:, 2]).astype(np.int32)
-    if not inverse:
-        return lin
-    inv = np.empty(nt ** 3, dtype=np.int32)
-    inv[lin] = np.arange(nt ** 3, dtype=np.int32)
-    return inv
-
-
-def _block_perm_device(kind: str, nt: int, inverse: bool):
-    """Cached device copy of the block permutation (path↔linear), int32."""
-    return device_constant(("blockperm", kind, nt, inverse),
-                           lambda: _block_perm(kind, nt, inverse))
+    if inverse:
+        inv = np.empty(nt ** 3, dtype=np.int32)
+        inv[lin] = np.arange(nt ** 3, dtype=np.int32)
+        lin = inv
+    lin.setflags(write=False)
+    return lin
 
 
 def store_spec(kind: str, T: int) -> OrderingSpec:
@@ -163,28 +141,45 @@ def _check_blockable(M: int, T: int) -> int:
     return nt
 
 
+def _to_blocks(x: jnp.ndarray, T: int, kind: str) -> jnp.ndarray:
+    """(..., M, M, M) -> (..., nb, T, T, T), blocks in ``kind`` order."""
+    M = x.shape[-1]
+    lead = x.shape[:-3]
+    nt = _check_blockable(M, T)
+    n = len(lead)
+    x6 = x.reshape(lead + (nt, T, nt, T, nt, T)).transpose(
+        tuple(range(n)) + tuple(n + a for a in (0, 2, 4, 1, 3, 5)))
+    flat = x6.reshape(lead + (nt ** 3, T, T, T))
+    return jnp.take(flat, _block_perm(kind, nt, False), axis=n)
+
+
+def _from_blocks(store: jnp.ndarray, M: int, kind: str) -> jnp.ndarray:
+    """Inverse of :func:`_to_blocks`: (..., nb, T, T, T) -> (..., M, M, M)."""
+    lead = store.shape[:-4]
+    nb, T = store.shape[-4], store.shape[-3]
+    nt = _check_blockable(M, T)
+    if nb != nt ** 3:
+        raise ValueError(f"store has {nb} blocks, M={M}, T={T} "
+                         f"implies {nt ** 3}")
+    n = len(lead)
+    x6 = jnp.take(store, _block_perm(kind, nt, True), axis=n)
+    x6 = x6.reshape(lead + (nt,) * 3 + (T,) * 3).transpose(
+        tuple(range(n)) + tuple(n + a for a in (0, 3, 1, 4, 2, 5)))
+    return x6.reshape(lead + (M, M, M))
+
+
 def blockize(x: jnp.ndarray, T: int, kind: str = "morton") -> jnp.ndarray:
     """(M,M,M) -> (nb, T, T, T) with blocks in ``kind`` curve order."""
     M = x.shape[0]
     if x.shape != (M, M, M):
         raise ValueError(f"blockize needs a cubic (M,M,M) state, "
                          f"got {x.shape}")
-    nt = _check_blockable(M, T)
-    x6 = x.reshape(nt, T, nt, T, nt, T).transpose(0, 2, 4, 1, 3, 5)  # (nt,nt,nt,T,T,T)
-    flat = x6.reshape(nt ** 3, T, T, T)
-    return flat[_block_perm_device(kind, nt, False)]
+    return _to_blocks(x, T, kind)
 
 
 def unblockize(blocks: jnp.ndarray, M: int, kind: str = "morton") -> jnp.ndarray:
     """Inverse of :func:`blockize`."""
-    nb, T = blocks.shape[0], blocks.shape[1]
-    nt = _check_blockable(M, T)
-    if nb != nt ** 3:
-        raise ValueError(f"store has {nb} blocks, M={M}, T={T} "
-                         f"implies {nt ** 3}")
-    x6 = blocks[_block_perm_device(kind, nt, True)]
-    x6 = x6.reshape(nt, nt, nt, T, T, T).transpose(0, 3, 1, 4, 2, 5)
-    return x6.reshape(M, M, M)
+    return _from_blocks(blocks, M, kind)
 
 
 def blockize_fields(fields: jnp.ndarray, T: int,
@@ -203,23 +198,13 @@ def blockize_fields(fields: jnp.ndarray, T: int,
     if fields.shape != (C, M, M, M):
         raise ValueError(f"blockize_fields needs (C,M,M,M) stacked "
                          f"fields, got {fields.shape}")
-    nt = _check_blockable(M, T)
-    x7 = fields.reshape(C, nt, T, nt, T, nt, T).transpose(0, 1, 3, 5, 2, 4, 6)
-    flat = x7.reshape(C, nt ** 3, T, T, T)
-    return jnp.take(flat, _block_perm_device(kind, nt, False), axis=1)
+    return _to_blocks(fields, T, kind)
 
 
 def unblockize_fields(store: jnp.ndarray, M: int,
                       kind: str = "morton") -> jnp.ndarray:
     """Inverse of :func:`blockize_fields`: (C, nb, T³) -> (C, M, M, M)."""
-    C, nb, T = store.shape[0], store.shape[1], store.shape[2]
-    nt = _check_blockable(M, T)
-    if nb != nt ** 3:
-        raise ValueError(f"store has {nb} blocks, M={M}, T={T} "
-                         f"implies {nt ** 3}")
-    x7 = jnp.take(store, _block_perm_device(kind, nt, True), axis=1)
-    x7 = x7.reshape(C, nt, nt, nt, T, T, T).transpose(0, 1, 4, 2, 5, 3, 6)
-    return x7.reshape(C, M, M, M)
+    return _from_blocks(store, M, kind)
 
 
 def blockize_with_halo(x: jnp.ndarray, T: int, g: int, kind: str = "morton",

@@ -19,18 +19,15 @@ from __future__ import annotations
 
 import functools
 
-import jax.numpy as jnp
 import numpy as np
 
-from .layout import block_order, device_constant
+from .layout import block_order
 from .orderings import OrderingSpec
 
 __all__ = [
     "OFFSETS_FULL", "OFFSETS_FACE", "FACE_COLS", "SELF_COL",
-    "block_kind_of", "neighbor_table", "neighbor_table_device", "ring_perms",
-    "boundary_face_table", "boundary_face_table_device",
+    "block_kind_of", "neighbor_table", "ring_perms", "boundary_face_table",
     "shell_block_count", "shell_block_index", "extended_neighbor_table",
-    "extended_neighbor_table_device",
 ]
 
 OFFSETS_FULL = tuple((a - 1, b - 1, c - 1)
@@ -135,18 +132,6 @@ def _full_table(kind: str, nt: int,
     return tab
 
 
-def neighbor_table_device(spec: OrderingSpec | str, nt: int, *,
-                          connectivity: str = "full",
-                          periodic=True) -> jnp.ndarray:
-    """Cached device-resident copy (the kernel's scalar-prefetch operand)."""
-    kind = block_kind_of(spec)
-    per = _periodic_axes(periodic)
-    return device_constant(
-        ("nbrtab", kind, nt, connectivity, per),
-        lambda: neighbor_table(kind, nt, connectivity=connectivity,
-                               periodic=per))
-
-
 def shell_block_count(nt: int) -> int:
     """Blocks in the one-block-thick shell around an nt³ core grid."""
     return (nt + 2) ** 3 - nt ** 3
@@ -202,14 +187,6 @@ def extended_neighbor_table(spec: OrderingSpec | str, nt: int) -> np.ndarray:
     return tab
 
 
-def extended_neighbor_table_device(spec: OrderingSpec | str,
-                                   nt: int) -> jnp.ndarray:
-    """Cached device-resident copy of :func:`extended_neighbor_table`."""
-    kind = block_kind_of(spec)
-    return device_constant(("extnbrtab", kind, nt),
-                           lambda: extended_neighbor_table(kind, nt))
-
-
 def ring_perms(n: int, periodic: bool = True
                ) -> tuple[list[tuple[int, int]], list[tuple[int, int]]]:
     """(forward, backward) ppermute partner lists for a ring of n devices.
@@ -254,10 +231,3 @@ def boundary_face_table(spec: OrderingSpec | str, nt: int) -> np.ndarray:
     tab = np.stack(cols, axis=1).astype(np.int32)
     tab.setflags(write=False)
     return tab
-
-
-def boundary_face_table_device(spec: OrderingSpec | str, nt: int) -> jnp.ndarray:
-    """Cached device-resident copy of :func:`boundary_face_table`."""
-    kind = block_kind_of(spec)
-    return device_constant(("bndtab", kind, nt),
-                           lambda: boundary_face_table(kind, nt))

@@ -34,7 +34,8 @@ from .hilbert import hilbert_encode, hilbert_encode3
 from .morton import morton_encode2, morton_encode3, morton_encode3_level
 
 __all__ = ["OrderingSpec", "ROW_MAJOR", "COLUMN_MAJOR", "MORTON", "HILBERT",
-           "rmo_to_path", "path_to_rmo", "path_index_2d", "block_index_3d",
+           "rmo_to_path", "path_to_rmo", "path_positions", "path_index_2d",
+           "block_index_3d",
            "ordering_from_name"]
 
 
@@ -138,10 +139,36 @@ def block_index_3d(kind: str, k, i, j, n: int) -> np.ndarray:
     return _flat_index(kind, k, i, j, n).astype(np.int64)
 
 
+def path_positions(spec: OrderingSpec, k, i, j, M: int) -> np.ndarray:
+    """Path positions of the sites (k, i, j) of an M³ cube under ``spec``.
+
+    Coordinates are integer arrays of one shape; the result (uint64) has
+    that shape. :func:`rmo_to_path` evaluates it on every site; a face
+    or a shard needs only its own sites.
+    """
+    m = _check_pow2(M)
+    kk, ii, jj = (np.asarray(a).astype(np.uint64) for a in (k, i, j))
+    if spec.kind in ("row_major", "column_major", "hilbert"):
+        return _flat_index(spec.kind, kk, ii, jj, M)
+    if spec.kind == "morton":
+        r = m if spec.level is None else spec.level
+        return morton_encode3_level(kk, ii, jj, m, r)
+    if spec.kind == "hybrid":
+        T = spec.tile
+        if T is None or M % T:
+            raise ValueError(f"tile {T} must divide M={M}")
+        nt = M // T
+        tt = np.uint64(T)
+        outer_idx = _flat_index(spec.outer, kk // tt, ii // tt, jj // tt, nt)
+        inner_idx = _flat_index(spec.inner, kk % tt, ii % tt, jj % tt, T)
+        return outer_idx * np.uint64(T * T * T) + inner_idx
+    raise ValueError(spec.kind)  # pragma: no cover
+
+
 @functools.lru_cache(maxsize=128)
 def rmo_to_path(spec: OrderingSpec, M: int) -> np.ndarray:
     """p: row-major index -> path position. int32 array of length M³."""
-    m = _check_pow2(M)
+    _check_pow2(M)
     _check_int32(M ** 3)
     kk, ii, jj = np.meshgrid(
         np.arange(M, dtype=np.uint64),
@@ -149,22 +176,7 @@ def rmo_to_path(spec: OrderingSpec, M: int) -> np.ndarray:
         np.arange(M, dtype=np.uint64),
         indexing="ij",
     )
-    kk, ii, jj = kk.ravel(), ii.ravel(), jj.ravel()
-    if spec.kind in ("row_major", "column_major", "hilbert"):
-        p = _flat_index(spec.kind, kk, ii, jj, M)
-    elif spec.kind == "morton":
-        r = m if spec.level is None else spec.level
-        p = morton_encode3_level(kk, ii, jj, m, r)
-    elif spec.kind == "hybrid":
-        T = spec.tile
-        if T is None or M % T:
-            raise ValueError(f"tile {T} must divide M={M}")
-        nt = M // T
-        outer_idx = _flat_index(spec.outer, kk // T, ii // T, jj // T, nt)
-        inner_idx = _flat_index(spec.inner, kk % T, ii % T, jj % T, T)
-        p = outer_idx * np.uint64(T * T * T) + inner_idx
-    else:  # pragma: no cover
-        raise ValueError(spec.kind)
+    p = path_positions(spec, kk.ravel(), ii.ravel(), jj.ravel(), M)
     p = p.astype(np.int32)
     p.setflags(write=False)
     return p

@@ -16,10 +16,9 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from .cache_model import face_mask
-from .orderings import OrderingSpec, rmo_to_path
+from .orderings import OrderingSpec, path_positions
 
-__all__ = ["FACES", "PAPER_SURFACE_NAMES", "surface_path_indices",
+__all__ = ["FACES", "PAPER_SURFACE_NAMES", "face_coords", "surface_path_indices",
            "run_lengths", "RunStats", "run_stats", "surface_runs",
            "shell_slab_shapes", "shell_slab_positions"]
 
@@ -34,6 +33,18 @@ PAPER_SURFACE_NAMES = {
 }
 
 
+def face_coords(face: str, M: int, g: int):
+    """(k, i, j) int64 coordinates of one width-g face, each of the
+    face's slab shape ((g,M,M), (M,g,M) or (M,M,g)), row-major over it —
+    the same sites as ``cache_model.face_mask`` without an M³ pass."""
+    if face not in FACES:
+        raise ValueError(f"face must be one of {FACES}")
+    ax = "kij".index(face[0])
+    span = [np.arange(M, dtype=np.int64)] * 3
+    span[ax] = np.arange(g, dtype=np.int64) + (0 if face[1] == "0" else M - g)
+    return np.meshgrid(*span, indexing="ij")
+
+
 @functools.lru_cache(maxsize=256)
 def surface_path_indices(spec: OrderingSpec, M: int, g: int, face: str) -> np.ndarray:
     """Path indices (positions in the ordering) of one face, ascending.
@@ -41,9 +52,8 @@ def surface_path_indices(spec: OrderingSpec, M: int, g: int, face: str) -> np.nd
     Ascending path order == the order in which the curve visits the face,
     which is the pack order used by the paper (p_t in §3.2). Length gM².
     """
-    p = rmo_to_path(spec, M)
-    idx = p[face_mask(face, M, g)]
-    idx = np.sort(idx)
+    p = path_positions(spec, *face_coords(face, M, g), M).ravel()
+    idx = np.sort(p).astype(np.int32)
     idx.setflags(write=False)
     return idx
 

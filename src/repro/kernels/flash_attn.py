@@ -32,6 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.orderings import path_index_2d
 
+from .backend import resolve_interpret
+
 __all__ = ["build_schedule", "flash_attention_fwd"]
 
 _NEG_INF = float("-inf")
@@ -106,7 +108,7 @@ def _flash_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref,
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = True, block_q: int = 64,
                         block_k: int = 64, schedule: str = "morton",
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool | None = None) -> jnp.ndarray:
     """Flash attention forward. q: (BH, Sq, D); k, v: (BH, Sk, D).
 
     Heads are pre-folded into the batch axis (ops.py handles GQA).
@@ -142,5 +144,5 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                 pltpu.VMEM((nq, block_q), jnp.float32),
             ],
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(iq_arr), jnp.asarray(ik_arr), q, k, v)

@@ -1,9 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Every op has an exact pure-jnp fallback (ref.py) selected by
-``use_kernel=False`` — the default model/stencil code paths run the
-fallback on CPU (interpret-mode kernels are functionally identical but
-slow), and flip to the kernels on TPU deployment via config.
+``use_kernel=False``. Kernels compile on a TPU and run in interpret mode
+on the CPU (kernels/backend.py); the caller never picks the mode.
 """
 
 from __future__ import annotations
@@ -16,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.boundary import PERIODIC
-from repro.core.layout import blockize_with_halo, device_constant, unblockize
+from repro.core.layout import blockize_with_halo, unblockize
 from repro.core.orderings import OrderingSpec
 from repro.core.surfaces import surface_path_indices
 
@@ -29,33 +28,22 @@ __all__ = ["gol3d_step", "pack_surface", "unpack_surface",
            "flash_attention", "sfc_gather_take", "uniform_weights"]
 
 
-def _build_uniform_weights(g: int) -> np.ndarray:
+@functools.lru_cache(maxsize=16)
+def uniform_weights(g: int) -> np.ndarray:
+    """All-ones stencil with a zero centre (neighbour count), read-only
+    numpy: jit embeds it as a constant."""
     s = 2 * g + 1
     w = np.ones((s, s, s), dtype=np.float32)
     w[g, g, g] = 0.0
+    w.setflags(write=False)
     return w
-
-
-def uniform_weights(g: int):
-    """All-ones stencil with a zero centre (neighbour count).
-
-    Cached device constant: repeated jits of the stencil pipelines reuse
-    one buffer instead of re-uploading per trace.
-    """
-    return device_constant(("golw", g), lambda: _build_uniform_weights(g))
-
-
-def _surface_idx_device(spec: OrderingSpec, M: int, g: int, face: str):
-    """Cached device copy of a face's path-index list (int32)."""
-    return device_constant(("surfidx", spec, M, g, face),
-                           lambda: surface_path_indices(spec, M, g, face))
 
 
 @functools.partial(jax.jit, static_argnames=("g", "block_kind", "T",
                                              "use_kernel", "bc", "interpret"))
 def gol3d_step(cube: jnp.ndarray, *, g: int, T: int = 8,
                block_kind: str = "morton", use_kernel: bool = False,
-               bc=PERIODIC, interpret: bool = True) -> jnp.ndarray:
+               bc=PERIODIC, interpret: bool | None = None) -> jnp.ndarray:
     """One gol3d update via the SFC-blocked stencil pipeline.
 
     blockize_with_halo (SFC layout) → stencil kernel → rule → unblockize.
@@ -77,8 +65,8 @@ def gol3d_step(cube: jnp.ndarray, *, g: int, T: int = 8,
 
 _ROW_PLANS: dict = {}
 _ROW_PLANS_CAP = 256
-# Same contract as layout._DEVICE_CONSTANTS_LOCK: the serving thread
-# pool and the main trace thread share this LRU — mutate under the lock.
+# The serving thread pool and the main trace thread share this LRU —
+# mutate it under the lock.
 _ROW_PLANS_LOCK = threading.RLock()
 
 
@@ -104,7 +92,7 @@ def _row_plan(idx: np.ndarray, line: int, plan_key=None):
     pos = (np.searchsorted(rows, idx // line) * line + idx % line).astype(np.int32)
     rows.setflags(write=False)
     pos.setflags(write=False)
-    if key is not None:  # numpy only — trace-safe to cache (cf. device_constant)
+    if key is not None:  # numpy only — trace-safe to cache
         with _ROW_PLANS_LOCK:
             while len(_ROW_PLANS) >= _ROW_PLANS_CAP:
                 _ROW_PLANS.pop(next(iter(_ROW_PLANS)))
@@ -113,7 +101,7 @@ def _row_plan(idx: np.ndarray, line: int, plan_key=None):
 
 
 def sfc_gather_take(data: jnp.ndarray, idx: np.ndarray, *, line: int = 64,
-                    use_kernel: bool = False, interpret: bool = True,
+                    use_kernel: bool = False, interpret: bool | None = None,
                     plan_key=None) -> jnp.ndarray:
     """data[idx] for a flat array, via line-granularity kernel gather.
 
@@ -141,7 +129,7 @@ def sfc_gather_take(data: jnp.ndarray, idx: np.ndarray, *, line: int = 64,
 
 def pack_surface(data_path: jnp.ndarray, spec: OrderingSpec, M: int, g: int,
                  face: str, *, line: int = 64, use_kernel: bool = False,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Pack one face of a path-ordered cube into a contiguous buffer.
 
     ``data_path`` is the (M³,) cube in ``spec`` order (apply_ordering) —
@@ -164,7 +152,7 @@ def pack_surface(data_path: jnp.ndarray, spec: OrderingSpec, M: int, g: int,
 def unpack_surface(data_path: jnp.ndarray, buf: jnp.ndarray,
                    spec: OrderingSpec, M: int, g: int, face: str) -> jnp.ndarray:
     """Inverse of pack_surface: scatter a buffer back into the cube."""
-    return data_path.at[_surface_idx_device(spec, M, g, face)].set(buf)
+    return data_path.at[surface_path_indices(spec, M, g, face)].set(buf)
 
 
 # ----------------------------------------------------------------------
@@ -204,7 +192,7 @@ def flash_attention(q, k, v, causal: bool = True, schedule: str = "morton",
     bq = _pick_block(Sq, block_q)
     bk = _pick_block(kf.shape[1], block_k)
     o = flash_attention_fwd(qf, kf, vf, causal=causal, block_q=bq,
-                            block_k=bk, schedule=schedule, interpret=True)
+                            block_k=bk, schedule=schedule)
     return o.reshape(B, Hq, Sq, D)
 
 

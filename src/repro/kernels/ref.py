@@ -172,21 +172,24 @@ def gol_rule_ref(state: jnp.ndarray, neigh_sum: jnp.ndarray, g: int) -> jnp.ndar
 
 
 def gol3d_step_ref(cube: jnp.ndarray, g: int, bc=PERIODIC) -> jnp.ndarray:
-    """One gol3d update on an (M,M,M) cube in canonical row-major layout.
+    """One gol3d update on a (Gk,Gi,Gj) box in canonical row-major layout.
 
-    ``bc`` is the boundary contract (core.boundary): the ghost extension
-    is a wrap pad (periodic), a constant pad (dirichlet) or an edge-
-    replication pad (neumann0) — the ordering-independent oracle every
-    pipeline form is validated against, for every boundary kind.
+    The box is usually a cube (M,M,M); a mesh of cubic shards covers a
+    non-cubic global box, which this oracle takes unchanged. ``bc`` is
+    the boundary contract (core.boundary): the ghost extension is a wrap
+    pad (periodic), a constant pad (dirichlet) or an edge-replication
+    pad (neumann0) — the ordering-independent oracle every pipeline form
+    is validated against, for every boundary kind.
     """
     s = 2 * g + 1
     xp = pad_cube(cube, g, bc)
-    M = cube.shape[0]
+    gk, gi, gj = cube.shape
     total = jnp.zeros_like(cube, dtype=jnp.float32)
     for dk in range(s):
         for di in range(s):
             for dj in range(s):
-                total = total + xp[dk:dk + M, di:di + M, dj:dj + M].astype(jnp.float32)
+                total = total + xp[dk:dk + gk, di:di + gi,
+                                   dj:dj + gj].astype(jnp.float32)
     neigh = total - cube.astype(jnp.float32)  # exclude centre
     return gol_rule_ref(cube, neigh, g)
 

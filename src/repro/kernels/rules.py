@@ -79,10 +79,12 @@ def gol_thresholds(g: int) -> tuple[int, int, int]:
 
 
 def _gol(centre: jnp.ndarray, tap: jnp.ndarray, g: int) -> jnp.ndarray:
+    # Float selects only: Mosaic cannot select between boolean vectors.
     lo, hi, born = gol_thresholds(g)
-    alive = centre > 0.5
-    nxt = jnp.where(alive, (tap >= lo) & (tap <= hi), tap == born)
-    return nxt.astype(jnp.float32)
+    one, zero = jnp.float32(1.0), jnp.float32(0.0)
+    survive = jnp.where(tap >= lo, jnp.where(tap <= hi, one, zero), zero)
+    birth = jnp.where(tap == born, one, zero)
+    return jnp.where(centre > 0.5, survive, birth)
 
 
 def _jacobi(centre: jnp.ndarray, tap: jnp.ndarray, g: int) -> jnp.ndarray:
@@ -154,13 +156,14 @@ def _plane(x: jnp.ndarray, axis: int, i: int) -> jnp.ndarray:
 
 
 def apply_window_bc(x: jnp.ndarray, flags, depth: int,
-                    bc: BoundarySpec | MixedBoundary | str) -> jnp.ndarray:
+                    bc: BoundarySpec | MixedBoundary | str,
+                    axes: tuple[int, ...] = (0, 1, 2)) -> jnp.ndarray:
     """Substitute boundary values into a window's ghost layers.
 
     x:      a stencil window whose last three axes span the spatial
-            extent — ``(E, E, E)`` or ``(C, E, E, E)`` inside the fused
-            kernel, ``(nb, E, E, E)`` / ``(C, nb, E, E, E)`` in the
-            batched jnp oracles. All leading axes (channels, blocks)
+            extent — ``(Ek, E, E)`` or ``(C, Ek, E, E)`` inside the fused
+            kernel (one k-chunk of a block), ``(nb, E, E, E)`` /
+            ``(C, nb, E, E, E)`` in the batched jnp oracles. All leading axes (channels, blocks)
             broadcast: the contract applies to every channel alike.
     flags:  which of the window's six faces are clamped *domain* faces,
             in ``core.neighbors.OFFSETS_FACE`` order [k-,k+,i-,i+,j-,j+]
@@ -174,6 +177,9 @@ def apply_window_bc(x: jnp.ndarray, flags, depth: int,
             ``MixedBoundary`` applies its own spec per axis — periodic
             axes are skipped entirely, so their ghost layers keep the
             wrapped/exchanged data.
+    axes:   the spatial axes to refresh (0=k, 1=i, 2=j), in this order.
+            The fused kernel refreshes whole k planes itself and passes
+            one plane at a time with ``axes=(1, 2)``.
 
     Axes are refreshed sequentially (k, then i, then j) so corner ghost
     regions compose exactly like ``jnp.pad``'s per-axis semantics — the
@@ -187,7 +193,6 @@ def apply_window_bc(x: jnp.ndarray, flags, depth: int,
     bc = as_boundary(bc)
     if not bc.clamped or depth == 0:
         return x
-    E = x.shape[-1]
     batch = x.ndim > 3
 
     def flag(col):
@@ -197,11 +202,12 @@ def apply_window_bc(x: jnp.ndarray, flags, depth: int,
             f = flags[..., col] != 0
         return f[..., None, None, None] if batch else f
 
-    for ax in range(3):
+    for ax in axes:
         ax_bc = bc.axes[ax]
         if not ax_bc.clamped:
             continue
         axis = ax - 3
+        E = x.shape[axis]
         iota = jax.lax.broadcasted_iota(jnp.int32, x.shape[-3:], ax)
         if ax_bc.kind == "dirichlet":
             lo_fill = hi_fill = jnp.asarray(ax_bc.value, x.dtype)

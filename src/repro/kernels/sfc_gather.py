@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
+
 __all__ = ["gather_rows"]
 
 
@@ -31,7 +33,7 @@ def _copy_kernel(idx_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows(src: jnp.ndarray, idx: jnp.ndarray, *,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """out[r] = src[idx[r]].  src: (N, L); idx: (R,) int32; out: (R, L)."""
     n, L = src.shape
     r = idx.shape[0]
@@ -45,5 +47,5 @@ def gather_rows(src: jnp.ndarray, idx: jnp.ndarray, *,
             in_specs=[pl.BlockSpec((1, L), lambda i, idx_ref: (idx_ref[i], 0))],
             out_specs=pl.BlockSpec((1, L), lambda i, idx_ref: (i, 0)),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, src)

@@ -53,14 +53,19 @@ writes C tiles. C=1 stores keep the original 4-D kernel program
 byte-for-byte (bit-identity of the scalar rules to their pre-§9 runs is
 load-bearing: XLA's contraction choices shift with rank).
 
-VMEM budget: ``4B·(2·(T+2Sg)³ + 2·T³ + (2g+1)³)`` — e.g. T=8, g=1, S=4
-→ ~37 KiB; the ``plan()`` autotuner in stencil/pipeline.py picks (T, S)
-against the ~16 MiB/core budget. MXU note: a pure stencil is VPU work
-(elementwise FMA); the kernels unroll the (2g+1)³ taps for g ≤ 2 so the
-adds pipeline, and fall back to a ``fori_loop`` for larger g to bound
-code size. Production layouts would pad the minor dim to the 128-lane
-register width; correctness here is validated in interpret mode against
-ref.stencil_sum_ref / ref.stencil_sum_resident_ref / ref.stencil_fused_ref.
+TPU layout (DESIGN.md §4): Mosaic refuses block shapes that cut the two
+minor dimensions off the (8, 128) tiling, so ``stencil_step_fused`` runs
+a ``(nb, T/kc)`` grid of k-chunks whose pieces are whole along j and
+whole sublane tiles along i, and cuts the i/j halos in VMEM. Its VMEM
+per grid step is the padded ``(C, kc+2h, T+2h, T+2h)`` scratch plus the
+double-buffered pieces and output slab (``fused_kernel_vmem_bytes``) —
+8.9 MiB at T=128, S=4, g=1; only T=128 makes the store lane-dense. ``stencil_sum_resident``
+keeps the ``(h, T, h)`` pieces and does not lower for the TPU (it fails
+loudly there); it remains the interpret-mode baseline. A pure stencil
+is VPU work; the kernels unroll the (2g+1)³ taps for g ≤ 2, and
+``_tap_sum`` falls back to a ``fori_loop`` for larger g to bound code
+size. Correctness is checked against ref.stencil_sum_ref /
+ref.stencil_sum_resident_ref / ref.stencil_fused_ref.
 """
 
 from __future__ import annotations
@@ -75,11 +80,21 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
                                  as_boundary)
 
+from .backend import resolve_interpret
 from .rules import apply_window_bc, get_rule
 
-__all__ = ["stencil_sum_blocks", "stencil_sum_resident", "stencil_step_fused"]
+__all__ = ["stencil_sum_blocks", "stencil_sum_resident", "stencil_step_fused",
+           "fused_geometry", "fused_kernel_vmem_bytes"]
 
 _UNROLL_TAP_LIMIT = 125  # unroll (2g+1)^3 taps up to g=2
+
+# The fused kernel's two scalar-prefetch tables live in SMEM (1 MiB on a
+# v5e core); leave room for Mosaic's own scalars.
+SMEM_TABLE_BYTES = 3 * 2 ** 18
+# Scoped VMEM the fused kernel may use (a v5e core has 128 MiB; the
+# default scope is 16 MiB, which one T=128 window plus its double-
+# buffered pieces outgrows).
+VMEM_LIMIT_BYTES = 100 * 2 ** 20
 
 
 def _tap_sum(x: jnp.ndarray, w_ref, T: int, s: int) -> jnp.ndarray:
@@ -112,7 +127,7 @@ def _halo_kernel(w_ref, x_ref, o_ref, *, T: int, s: int):
 
 @functools.partial(jax.jit, static_argnames=("g", "interpret"))
 def stencil_sum_blocks(blocks: jnp.ndarray, weights: jnp.ndarray, *,
-                       g: int, interpret: bool = True) -> jnp.ndarray:
+                       g: int, interpret: bool | None = None) -> jnp.ndarray:
     """acc[b] = sum_d w[d] * blocks[b, z+d] for every block b.
 
     blocks:  (nb, T+2g, T+2g, T+2g)  — SFC-ordered, halo-extended
@@ -133,7 +148,7 @@ def stencil_sum_blocks(blocks: jnp.ndarray, weights: jnp.ndarray, *,
             pl.BlockSpec((1, W, W, W), lambda i: (i, 0, 0, 0)),  # one block/step
         ],
         out_specs=pl.BlockSpec((1, T, T, T), lambda i: (i, 0, 0, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(weights, blocks)
 
 
@@ -211,7 +226,7 @@ def _piece_specs(T: int, h: int, channels: int | None = None) -> list:
 @functools.partial(jax.jit, static_argnames=("g", "interpret"))
 def stencil_sum_resident(store: jnp.ndarray, weights: jnp.ndarray,
                          nbr: jnp.ndarray, *, g: int,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool | None = None) -> jnp.ndarray:
     """In-kernel halo streaming over the persistent block store.
 
     store:   (nb, T, T, T)  — SFC-ordered, *no* halo duplication
@@ -244,23 +259,166 @@ def stencil_sum_resident(store: jnp.ndarray, weights: jnp.ndarray,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, T, T, T), lambda i, nbr_ref: (i, 0, 0, 0)),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(nbr.astype(jnp.int32), weights, *([store] * 27))
 
 
 # ------------------------------------------------------- temporal-blocked form
 
-def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
-                  S: int, rule, bc):
-    """S substeps of tap-sum + update rule, entirely in VMEM.
+SUBLANES = 8   # f32 rows in one (8, 128) vreg tile
+LANES = 128    # f32 lanes in one (8, 128) vreg tile: the lane-dense T
 
-    The assembled window starts at (C, (T+2·S·g)³) and shrinks by g per
-    side each substep — boundary sites are recomputed redundantly instead
-    of re-read from HBM (DESIGN.md §4). Nothing intermediate (tap sums,
-    partial states) ever touches HBM; the single write is the C·T³ tile.
+
+def fused_geometry(T: int, h: int) -> tuple[int, int]:
+    """(kc, hb) of ``stencil_step_fused`` for block edge T, halo depth h | T.
+
+    kc: output planes per grid step — the smallest multiple of h that
+    spans a sublane tile and divides T (else T). hb: rows fetched per
+    i-halo piece — h rounded up to whole sublane tiles when that divides
+    T (else the whole block edge). Both keep every block shape on the
+    TPU's (8, 128) tiling (DESIGN.md §4).
+    """
+    kc = -(-SUBLANES // h) * h
+    if kc > T or T % kc:
+        kc = T
+    hb = -(-h // SUBLANES) * SUBLANES
+    if hb >= T or T % hb:
+        hb = T
+    return kc, hb
+
+
+def fused_kernel_vmem_bytes(T: int, h: int, fields: int = 1,
+                            itemsize: int = 4) -> int:
+    """VMEM one grid step of ``stencil_step_fused`` allocates.
+
+    The f32 ``(C, kc+2h, T+2h, T+2h)`` window scratch, the 27 pieces
+    (three j-columns of ``(kc+2h) x (T+2·hb) x T`` each) and the
+    ``C·kc·T²`` output slab, both double-buffered.
+    """
+    kc, hb = fused_geometry(T, h)
+    W = T + 2 * h
+    scratch = 4 * fields * (kc + 2 * h) * W * W
+    pieces = 3 * (kc + 2 * h) * (T + 2 * hb) * T
+    return scratch + 2 * itemsize * fields * (pieces + kc * T * T)
+
+
+def _fused_piece_index(i, z, nbr_ref, _bnd_ref, *, a: int, col: int,
+                       irow: int, nz: int, kc_h: int, T_h: int,
+                       channels: bool):
+    # Piece (a, b, c) of grid step (i, z): b/c pick the i/j neighbour
+    # column; along k the low (a=0) and high (a=2) halo slabs come from
+    # the k-neighbour block only on the block's first/last chunk, and
+    # from the block's own column otherwise. k is addressed in units of
+    # the piece's k extent (h for halos, kc for the centre).
+    mid = col - (a - 1) * 9  # same (b, c) column, k offset 0
+    if a == 1:
+        blk, kidx = nbr_ref[i, col], z
+    elif nz == 1:
+        blk, kidx = nbr_ref[i, col], (T_h - 1 if a == 0 else 0)
+    elif a == 0:
+        first = z == 0
+        blk = jnp.where(first, nbr_ref[i, col], nbr_ref[i, mid])
+        kidx = jnp.where(first, T_h - 1, z * kc_h - 1)
+    else:
+        last = z == nz - 1
+        blk = jnp.where(last, nbr_ref[i, col], nbr_ref[i, mid])
+        kidx = jnp.where(last, 0, (z + 1) * kc_h)
+    idx = (blk, kidx, irow, 0)
+    return (0,) + idx if channels else idx
+
+
+def _fused_piece_specs(T: int, h: int, kc: int, hb: int,
+                       channels: int | None) -> list:
+    """The 27 BlockSpecs of one (kc+2h, T+2h, T+2h) window.
+
+    Piece (a, b, c) spans (h | kc | h) planes along k, (hb | T | hb)
+    rows along i, and the whole block edge T along j: no block shape
+    cuts the lane axis, and the i cut is whole sublane tiles. The
+    window's i/j halos are cut from these pieces in VMEM.
+    """
+    nz = T // kc
+    ext_k, ext_i = (h, kc, h), (hb, T, hb)
+    specs = []
+    for a in range(3):
+        for b in range(3):
+            for c in range(3):
+                shape = (1, ext_k[a], ext_i[b], T)
+                if channels is not None:
+                    shape = (channels,) + shape
+                specs.append(pl.BlockSpec(shape, functools.partial(
+                    _fused_piece_index, a=a, col=a * 9 + b * 3 + c,
+                    irow=T // hb - 1 if b == 0 else 0, nz=nz, kc_h=kc // h,
+                    T_h=T // h, channels=channels is not None)))
+    return specs
+
+
+def _fused_assemble(pieces, win, T: int, h: int, kc: int, hb: int):
+    """Write the (C, kc+2h, T+2h, T+2h) window into the ``win`` scratch.
+
+    Plane by plane: each window plane joins the matching planes of the 9
+    pieces of its k-slab, with the i/j halos cut out of the fetched rows
+    and the whole-edge lane span (the only unaligned work is in VMEM).
+    """
+    cut_i = (slice(hb - h, hb), slice(None), slice(0, h))
+    cut_j = (slice(T - h, T), slice(None), slice(0, h))
+    for a, (k0, ext) in enumerate(((0, h), (h, kc), (h + kc, h))):
+        def put(p, carry, a=a, k0=k0):
+            rows = []
+            for b in range(3):
+                row = []
+                for c in range(3):
+                    r = pieces[a * 9 + b * 3 + c]
+                    v = r[0, p][None] if len(r.shape) == 4 else r[:, 0, p]
+                    row.append(v.astype(jnp.float32)[:, cut_i[b], cut_j[c]])
+                rows.append(jnp.concatenate(row, axis=-1))
+            win[:, k0 + p] = jnp.concatenate(rows, axis=-2)
+            return carry
+        jax.lax.fori_loop(0, ext, put, 0)
+
+
+def _fused_refresh(win, flags, d: int, Ek: int, Ei: int, bc):
+    """Clamped ghost refresh of the current (Ek, Ei, Ei) window, in place.
+
+    The same substitution as rules.apply_window_bc, axis by axis: whole k
+    planes first (a k face is flagged only on the block's first or last
+    chunk), then the i/j ghosts of every plane, which depend only on
+    that plane.
+    """
+    kbc = bc.axes[0]
+    if kbc.clamped:
+        for t in range(d):
+            for flag, dst, src in ((flags[0], t, d),
+                                   (flags[1], Ek - 1 - t, Ek - 1 - d)):
+                @pl.when(flag != 0)
+                def _(dst=dst, src=src):
+                    if kbc.kind == "dirichlet":
+                        win[:, dst, :Ei, :Ei] = jnp.full(
+                            (win.shape[0], Ei, Ei), kbc.value, jnp.float32)
+                    else:
+                        win[:, dst, :Ei, :Ei] = win[:, src, :Ei, :Ei]
+    if bc.axes[1].clamped or bc.axes[2].clamped:
+        def plane(p, carry):
+            x = win[:, pl.ds(p, 1), :Ei, :Ei]
+            win[:, pl.ds(p, 1), :Ei, :Ei] = apply_window_bc(
+                x, flags, d, bc, axes=(1, 2))
+            return carry
+        jax.lax.fori_loop(0, Ek, plane, 0)
+
+
+def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
+                  S: int, kc: int, hb: int, rule, bc):
+    """S substeps of tap-sum + update rule on one k-chunk, in VMEM.
+
+    Grid step (i, z) computes planes [z·kc, (z+1)·kc) of block i. Its
+    window starts at (C, kc+2·S·g, T+2·S·g, T+2·S·g) and shrinks by g
+    per side each substep — boundary sites are recomputed redundantly
+    instead of re-read from HBM (DESIGN.md §4). Each substep walks the
+    output planes in order and overwrites the window in place (output
+    plane p needs input planes p..p+2g only, so slot p is free once it
+    is computed); the last substep writes the C·kc·T² slab instead.
+    Nothing intermediate (tap sums, partial states) ever touches HBM.
     Every substep tap-sums **all C channels** and hands the stacked
-    fields to the rule (DESIGN.md §9) — C=1 rules see a leading axis of
-    one, bit-identical to the scalar form.
+    fields to the rule (DESIGN.md §9).
 
     Clamped runs (DESIGN.md §8): before every substep, the outer
     ``g·(S-u)`` ghost layers on faces flagged in ``bnd_ref`` (the second
@@ -269,35 +427,52 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
     — so domain sites only ever consume valid taps and clamped faces
     temporally block exactly as deep as periodic ones.
     """
-    o_ref = refs[-1]
-    x = _assemble_window(refs[:-1])  # (T+2·S·g,)³ f32, or (C, …) stacked
-    multi = x.ndim == 4
-    i = pl.program_id(0)
-    flags = tuple(bnd_ref[i, c] for c in range(6))
+    pieces, o_ref, win = refs[:27], refs[27], refs[28]
+    multi = len(o_ref.shape) == 5
+    h = S * g
+    _fused_assemble(pieces, win, T, h, kc, hb)
+    i, z = pl.program_id(0), pl.program_id(1)
+    nz = T // kc
+    flags = (jnp.where(z == 0, bnd_ref[i, 0], 0),
+             jnp.where(z == nz - 1, bnd_ref[i, 1], 0),
+             *(bnd_ref[i, c] for c in range(2, 6)))
     for u in range(S):
-        x = apply_window_bc(x, flags, g * (S - u), bc)
-        out_e = T + 2 * g * (S - 1 - u)      # window edge after this substep
-        if multi:
-            tap = jnp.stack([_tap_sum(x[c], w_ref, out_e, s)
-                             for c in range(x.shape[0])])
-            centre = x[:, g:g + out_e, g:g + out_e, g:g + out_e]
-        else:
-            tap = _tap_sum(x, w_ref, out_e, s)
-            centre = x[g:g + out_e, g:g + out_e, g:g + out_e]
-        x = rule.apply(centre, tap, g)
-    if multi:
-        o_ref[:, 0] = x.astype(o_ref.dtype)
-    else:
-        o_ref[0] = x.astype(o_ref.dtype)
+        d = g * (S - u)                          # ghost depth of this substep
+        Ek, Ei = kc + 2 * d, T + 2 * d           # current window extents
+        Oi = Ei - 2 * g
+        if bc.clamped:
+            _fused_refresh(win, flags, d, Ek, Ei, bc)
+
+        def plane(p, carry, Ei=Ei, Oi=Oi, last=u == S - 1):
+            x = [win[:, p + dk, :Ei, :Ei] for dk in range(s)]
+            tap = []
+            for c in range(win.shape[0]):
+                acc = jnp.zeros((Oi, Oi), jnp.float32)
+                for dk in range(s):
+                    for di in range(s):
+                        for dj in range(s):
+                            acc = acc + w_ref[dk, di, dj].astype(jnp.float32) \
+                                * x[dk][c, di:di + Oi, dj:dj + Oi]
+                tap.append(acc)
+            centre = x[g][:, g:g + Oi, g:g + Oi]
+            new = rule.apply(centre, jnp.stack(tap), g)
+            if not last:
+                win[:, p, :Oi, :Oi] = new
+            elif multi:
+                o_ref[:, 0, p] = new.astype(o_ref.dtype)
+            else:
+                o_ref[0, p] = new[0].astype(o_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, Ek - 2 * g, plane, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("g", "S", "rule", "bc", "interpret"))
+@functools.partial(jax.jit, static_argnames=("g", "S", "rule", "bc",
+                                             "interpret"))
 def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
                        nbr: jnp.ndarray, bnd: jnp.ndarray | None = None,
                        *, g: int, S: int = 1, rule: str = "gol",
                        bc: BoundarySpec | MixedBoundary | str = PERIODIC,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """S fused timesteps over the resident store, one HBM round-trip.
 
     store:   (nb_src, T, T, T) — or the multi-field ``(C, nb_src, T³)``
@@ -309,7 +484,7 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
              (core/neighbors.extended_neighbor_table) and the kernel
              only writes the nbr-indexed core. All C channels share the
              one block permutation, neighbour table and grid: one grid
-             step assembles C windows and writes C tiles.
+             step assembles C windows and writes C slabs.
     weights: (2g+1, 2g+1, 2g+1) tap weights (ops.uniform_weights for the
              classic neighbour-count rules), shared by every channel
     nbr:     (nb, 27) int32 neighbour table (core.neighbors — periodic,
@@ -326,16 +501,22 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
     bc:      boundary contract (core.boundary): "periodic" (default) |
              "dirichlet" | "neumann0" | a per-axis ``MixedBoundary``,
              applied to every channel alike
+    interpret: None (default) compiles on a TPU and interprets on the
+             CPU; True on a TPU raises (kernels/backend.py)
     returns: same shape as ``store``'s computed core, in store dtype —
              bit-identical (for f32 stores) to S sequential resident
              steps of the same rule and boundary.
 
-    Halo pieces have extent S·g and are addressed in block-shape units,
-    so S·g must divide T (deep temporal blocking needs S·g ≤ T: the
-    window may only reach into directly adjacent blocks). Substeps run
-    in f32; non-f32 stores would round once per launch instead of once
-    per step, so bit-identity to the sequential path is f32-only.
+    The grid is (nb, T/kc): one step per k-chunk of a block
+    (``fused_geometry``). Halo pieces along k have extent S·g and are
+    addressed in those units, so S·g must divide T (S·g ≤ T: the window
+    may only reach into directly adjacent blocks). The two scalar-
+    prefetch tables live in SMEM, so nb is capped on the TPU
+    (:data:`SMEM_TABLE_BYTES`). Substeps
+    run in f32; non-f32 stores would round once per launch instead of
+    once per step, so bit-identity to the sequential path is f32-only.
     """
+    interpret = resolve_interpret(interpret)
     r = get_rule(rule)
     multi = store.ndim == 5
     C = store.shape[0] if multi else 1
@@ -361,27 +542,41 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
     if bnd is None:
         bnd = jnp.zeros((nb, 6), jnp.int32)
     assert bnd.shape == (nb, 6), bnd.shape
+    if not interpret and 4 * nb * (27 + 6) > SMEM_TABLE_BYTES:
+        raise ValueError(
+            f"{nb} blocks need {4 * nb * 33} B of scalar-prefetched tables, "
+            f"over the {SMEM_TABLE_BYTES} B SMEM budget: use a larger block "
+            f"edge than T={T}")
+    kc, hb = fused_geometry(T, h)
 
-    in_specs = [pl.BlockSpec((s, s, s), lambda i, nbr_ref, bnd_ref: (0, 0, 0))]
-    in_specs += _piece_specs(T, h, channels=C if multi else None)
+    def fixed(i, z, nbr_ref, bnd_ref):
+        return (0, 0, 0)
+
+    in_specs = [pl.BlockSpec((s, s, s), fixed)]
+    in_specs += _fused_piece_specs(T, h, kc, hb, C if multi else None)
     if multi:
         out_shape = jax.ShapeDtypeStruct((C, nb, T, T, T), store.dtype)
-        out_spec = pl.BlockSpec((C, 1, T, T, T),
-                                lambda i, nbr_ref, bnd_ref: (0, i, 0, 0, 0))
+        out_spec = pl.BlockSpec((C, 1, kc, T, T),
+                                lambda i, z, nbr_ref, bnd_ref: (0, i, z, 0, 0))
     else:
         out_shape = jax.ShapeDtypeStruct((nb, T, T, T), store.dtype)
-        out_spec = pl.BlockSpec((1, T, T, T),
-                                lambda i, nbr_ref, bnd_ref: (i, 0, 0, 0))
-    kern = functools.partial(_fused_kernel, T=T, s=s, g=g, S=S,
+        out_spec = pl.BlockSpec((1, kc, T, T),
+                                lambda i, z, nbr_ref, bnd_ref: (i, z, 0, 0))
+    kern = functools.partial(_fused_kernel, T=T, s=s, g=g, S=S, kc=kc, hb=hb,
                              rule=r, bc=bc)
+    W = T + 2 * h
+    scratch = [pltpu.VMEM((C, kc + 2 * h, W, W), jnp.float32)]
     return pl.pallas_call(
         kern,
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(nb,),
+            grid=(nb, T // kc),
             in_specs=in_specs,
             out_specs=out_spec,
+            scratch_shapes=scratch,
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="stencil_step_fused",
         interpret=interpret,
     )(nbr.astype(jnp.int32), bnd.astype(jnp.int32), weights, *([store] * 27))

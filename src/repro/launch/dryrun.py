@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SHAPES, cells, get_config, input_specs, shape_skip_reason
 from repro.launch.mesh import batch_axes, make_production_mesh
 from repro.models import abstract_params, build_model
@@ -206,6 +207,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -43,6 +43,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.checkpoint import ckpt  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.data import TokenPipeline  # noqa: E402
 from repro.models import build_model  # noqa: E402
@@ -51,7 +52,8 @@ from repro.train.optimizer import init_opt_state  # noqa: E402
 
 
 def _mesh(shape):
-    return jax.make_mesh(tuple(shape), ("data", "model"))
+    return jax.make_mesh(tuple(shape), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _shardings(mesh, model, params_abs):
@@ -62,6 +64,7 @@ def _shardings(mesh, model, params_abs):
 
 
 def main():
+    enable_compile_cache()
     ckpt_dir = "/tmp/repro_elastic"
     import shutil
     shutil.rmtree(ckpt_dir, ignore_errors=True)

@@ -256,6 +256,9 @@ def state_crc(state: np.ndarray) -> int:
 def main(a) -> None:
     import jax  # noqa: F401  (devices forced above)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.core.orderings import ordering_from_name
     from repro.stencil import (CheckpointedRun, DistributedPipeline,
                                ResidentPipeline, make_stencil_mesh)

@@ -165,6 +165,8 @@ def stencil_main(args) -> None:
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = build_parser().parse_args()
     if args.stencil:
         stencil_main(args)

@@ -17,6 +17,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.data import TokenPipeline
 from repro.models import build_model
@@ -24,6 +25,7 @@ from repro.train import OptConfig, Trainer, TrainerConfig, TrainConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

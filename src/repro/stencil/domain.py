@@ -20,17 +20,20 @@ def make_stencil_mesh(shape: tuple[int, int, int]) -> jax.sharding.Mesh:
     its smaller mesh from a prefix of ``jax.devices()`` — so a 2×2×1
     mesh is valid on an 8-device host. When the shape covers the whole
     machine this defers to ``jax.make_mesh`` (which picks an
-    ICI-friendly device order on real hardware).
+    ICI-friendly device order on real hardware). Both branches give
+    every axis the ``Auto`` type: the state is placed by shard_map
+    specs, and layout reshapes of sharded arrays stay legal.
     """
     n = int(np.prod(shape))
     devices = jax.devices()
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
     if n == len(devices):
-        return jax.make_mesh(shape, STENCIL_AXES)
+        return jax.make_mesh(shape, STENCIL_AXES, axis_types=auto)
     if n > len(devices):
         raise ValueError(f"mesh shape {shape} needs {n} devices, "
                          f"have {len(devices)}")
-    return jax.sharding.Mesh(
-        np.asarray(devices[:n]).reshape(shape), STENCIL_AXES)
+    return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape),
+                             STENCIL_AXES, axis_types=auto)
 
 
 def _as_shape3(global_shape) -> tuple[int, int, int]:

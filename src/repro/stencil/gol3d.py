@@ -21,7 +21,8 @@ Two execution modes (DESIGN.md §3):
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import functools
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -29,12 +30,12 @@ import numpy as np
 
 from repro.core import (OrderingSpec, PERIODIC, ROW_MAJOR, BoundarySpec,
                         apply_ordering, as_boundary, undo_ordering)
-from repro.kernels import ops
+from repro.kernels import backend, ops
 from repro.kernels import ref as kref
 
 from .domain import Decomposition3D, STENCIL_AXES
 from .halo import stencil_block_kind
-from .pipeline import DistributedPipeline, ResidentPipeline
+from .pipeline import DistributedPipeline, ResidentPipeline, _default_kernel
 
 __all__ = ["Gol3dConfig", "Gol3d"]
 
@@ -46,10 +47,14 @@ class Gol3dConfig:
     M:          cube edge (power of 2)
     g:          stencil radius — the update reads a (2g+1)³ tap cube
     ordering:   storage ordering of the public path state (core.orderings)
-    block_T:    SFC block edge of the kernel pipelines (T | M)
+    block_T:    SFC block edge of the kernel pipelines (T | M); None
+                (default) lets the platform decide — the lane-dense
+                min(M, 128) for the compiled TPU kernel, else 8
     substeps:   S fused timesteps per HBM round-trip (temporal blocking,
                 DESIGN.md §4); 0 delegates (T, S) to the plan() autotuners
-    use_kernel: Pallas kernels (interpret mode off-TPU) vs jnp oracles
+    use_kernel: Pallas kernels vs jnp oracles; None (default) lets the
+                platform decide — the compiled kernels on a TPU, the
+                oracles on the CPU
     bc:         boundary contract (core.boundary.BoundarySpec or kind
                 string): "periodic" wraps like a torus; "dirichlet" /
                 "neumann0" clamp the domain edges physically
@@ -61,23 +66,28 @@ class Gol3dConfig:
     M: int = 64                      # cube edge (power of 2)
     g: int = 1                       # stencil radius
     ordering: OrderingSpec = ROW_MAJOR
-    block_T: int = 8                 # SFC block edge for the kernel pipeline
+    block_T: int | None = None       # None: 128 for the TPU kernel, else 8
     substeps: int = 1                # S per fused launch; 0 = autotune (T, S)
-    use_kernel: bool = False         # Pallas kernel (interpret on CPU) vs jnp
+    use_kernel: bool | None = None   # None: kernel on TPU, jnp oracle on CPU
     density: float = 0.3             # initial live fraction
     seed: int = 0
     bc: BoundarySpec = PERIODIC      # boundary contract (core.boundary)
 
     def __post_init__(self):
         object.__setattr__(self, "bc", as_boundary(self.bc))
+        _default_kernel(self, edge="block_T")
 
 
 @dataclass
 class Gol3d:
+    """One gol3d run. ``state_path`` is the (M³,) state in ``cfg.ordering``
+    order; None (default) draws the seeded random cube."""
     cfg: Gol3dConfig
-    state_path: jnp.ndarray = field(init=False)  # (M³,) in ordering order
+    state_path: jnp.ndarray | None = None
 
     def __post_init__(self):
+        if self.state_path is not None:
+            return
         rng = np.random.default_rng(self.cfg.seed)
         cube = (rng.random((self.cfg.M,) * 3) < self.cfg.density).astype(np.float32)
         self.state_path = apply_ordering(jnp.asarray(cube), self.cfg.ordering)
@@ -129,13 +139,29 @@ class Gol3d:
                                 kind=self.block_kind, S=cfg.substeps,
                                 bc=cfg.bc, use_kernel=cfg.use_kernel)
 
+    def resident_fn(self, n_steps: int):
+        """jit'd (state_path -> state_path) fused n_steps run: one program
+        from the path-ordered state through the block store and back,
+        with the state donated on a TPU — a chip holds the state and
+        the store, not every intermediate of the layout boundary (and a
+        reference kept to the old state is invalidated there)."""
+        pipe = self.resident_pipeline()
+        ordering, M = self.cfg.ordering, self.cfg.M
+        donate = (0,) if backend.on_tpu() else ()
+
+        @functools.partial(jax.jit, donate_argnums=donate)
+        def run(state_path):
+            cube = pipe.run(undo_ordering(state_path, ordering, M), n_steps)
+            return apply_ordering(cube, ordering)
+
+        return run
+
     def run_resident(self, n_steps: int) -> jnp.ndarray:
         """Fused multi-step run: the curve-ordered block store is the
         resident state for all n_steps; layout conversions happen once at
         each end. Bit-identical to ``run`` (same block kind, same rule)."""
-        pipe = self.resident_pipeline()
-        cube = pipe.run(self.cube, n_steps)
-        self.state_path = jax.block_until_ready(apply_ordering(cube, self.cfg.ordering))
+        self.state_path = jax.block_until_ready(
+            self.resident_fn(n_steps)(self.state_path))
         return self.state_path
 
     def distributed_pipeline(self, mesh: jax.sharding.Mesh) -> DistributedPipeline:

@@ -45,17 +45,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.core import OrderingSpec, path_to_rmo, rmo_to_path
+from repro.core import OrderingSpec, path_positions, path_to_rmo, rmo_to_path
 from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
                                  as_boundary, axes_periodic)
-from repro.core.cache_model import face_mask
-from repro.core.layout import device_constant, store_spec
-from repro.core.neighbors import (block_kind_of, boundary_face_table_device,
-                                  extended_neighbor_table_device,
-                                  ring_perms, shell_block_count)
-from repro.core.surfaces import shell_slab_positions, shell_slab_shapes
+from repro.core.layout import (apply_ordering, needs_element_perm,
+                               store_spec, undo_ordering)
+from repro.core.neighbors import (block_kind_of, boundary_face_table,
+                                  extended_neighbor_table, ring_perms,
+                                  shell_block_count)
+from repro.core.surfaces import (face_coords, shell_slab_positions,
+                                 shell_slab_shapes)
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from repro.kernels.ops import uniform_weights
@@ -66,7 +66,8 @@ from .domain import STENCIL_AXES
 
 __all__ = ["surface_slab_scatter", "exchange_shell", "shard_substeps",
            "shard_boundary_flags", "make_distributed_step",
-           "stencil_block_kind", "shard_state", "unshard_state"]
+           "stencil_block_kind", "shard_state", "unshard_state",
+           "to_store", "from_store"]
 
 
 @functools.lru_cache(maxsize=256)
@@ -79,23 +80,9 @@ def surface_slab_scatter(spec: OrderingSpec, M: int, g: int, face: str) -> np.nd
     the face's two free axes plus the g-width axis, in (k,i,j) order with
     the face axis collapsed to width g.
     """
-    q = path_to_rmo(spec, M)
-    mask = face_mask(face, M, g)
-    # rmo indices of face points, in path order (matches pack order)
-    rmo = q[mask[q]]
-    M2 = M * M
-    k, i, j = rmo // M2, (rmo // M) % M, rmo % M
-    ax, side = face[0], face[1]
-    if ax == "k":
-        kk = k if side == "0" else k - (M - g)
-        pos = (kk * M + i) * M + j
-    elif ax == "i":
-        ii = i if side == "0" else i - (M - g)
-        pos = (k * g + ii) * M + j
-    else:
-        jj = j if side == "0" else j - (M - g)
-        pos = (k * M + i) * g + jj
-    pos = pos.astype(np.int32)  # int32: M³ < 2³¹ (core.orderings._check_int32)
+    # slab sites in slab row-major order; pack order is ascending path
+    p = path_positions(spec, *face_coords(face, M, g), M).ravel()
+    pos = np.argsort(p, kind="stable").astype(np.int32)
     pos.setflags(write=False)
     return pos
 
@@ -108,15 +95,10 @@ def stencil_block_kind(spec: OrderingSpec) -> str:
     return kind if kind in ("morton", "hilbert") else "morton"
 
 
-def _slab_scatter_device(spec: OrderingSpec, M: int, h: int, face: str):
-    return device_constant(("slabscatter", spec, M, h, face),
-                           lambda: surface_slab_scatter(spec, M, h, face))
-
-
 def _pack_to_slab(store_flat, hspec, M, h, face, shape):
     """Pack one deep face from the (C, nb·T³) store, canonical slab layout."""
     buf = ops.pack_surface(store_flat, hspec, M, h, face)  # (C, L)
-    pos = _slab_scatter_device(hspec, M, h, face)
+    pos = surface_slab_scatter(hspec, M, h, face)
     C = store_flat.shape[0]
     return jnp.zeros((C, h * M * M), buf.dtype).at[:, pos].set(buf) \
         .reshape((C,) + shape)
@@ -126,7 +108,7 @@ def _unpack_recv(buf, hspec, M, h, face, shape):
     """Scatter a received deep-face buffer (sender's pack order) into the
     canonical slab — sender and receiver share the index lists, so the
     receiver knows the order the remote pack produced."""
-    pos = _slab_scatter_device(hspec, M, h, face)
+    pos = surface_slab_scatter(hspec, M, h, face)
     C = buf.shape[0]
     return jnp.zeros((C, h * M * M), buf.dtype).at[:, pos].set(buf) \
         .reshape((C,) + shape)
@@ -258,11 +240,6 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
     return tuple(s[0] for s in slabs) if squeeze else slabs
 
 
-def _shell_positions_device(nt: int, T: int, h: int):
-    return device_constant(("shellpos", nt, T, h),
-                           lambda: shell_slab_positions(nt, T, h))
-
-
 def shard_boundary_flags(kind: str, nt: int,
                          axis_names=STENCIL_AXES) -> jnp.ndarray:
     """(nb, 6) clamped-domain-face flags for this shard's blocks.
@@ -275,7 +252,7 @@ def shard_boundary_flags(kind: str, nt: int,
     mixed contracts the refresh (rules.apply_window_bc) skips periodic
     axes by itself, so the table needs no further bc masking.
     """
-    base = jnp.asarray(boundary_face_table_device(kind, nt))
+    base = jnp.asarray(boundary_face_table(kind, nt))
     edge = []
     for ax in axis_names:
         n = jax.lax.psum(1, ax)
@@ -286,7 +263,7 @@ def shard_boundary_flags(kind: str, nt: int,
 
 def shard_substeps(store: jnp.ndarray, *, kind: str, M: int, g: int, S: int,
                    rule: str = "gol", bc: BoundarySpec | MixedBoundary | str = PERIODIC,
-                   use_kernel: bool = False, interpret: bool = True,
+                   use_kernel: bool = False,
                    axis_names=STENCIL_AXES) -> jnp.ndarray:
     """One deep exchange + S fused substeps on the resident shard store.
 
@@ -315,7 +292,7 @@ def shard_substeps(store: jnp.ndarray, *, kind: str, M: int, g: int, S: int,
     h = S * g
     flat = store.reshape(store.shape[0], -1) if multi else store.reshape(-1)
     slabs = exchange_shell(flat, kind, M, T, h, axis_names, bc=bc)
-    pos = _shell_positions_device(nt, T, h)
+    pos = shell_slab_positions(nt, T, h)
     if multi:
         C = store.shape[0]
         vals = jnp.concatenate([s.reshape(C, -1) for s in slabs], axis=1)
@@ -327,23 +304,24 @@ def shard_substeps(store: jnp.ndarray, *, kind: str, M: int, g: int, S: int,
         shell = jnp.zeros((shell_block_count(nt) * T ** 3,), store.dtype
                           ).at[pos].set(vals).reshape(-1, T, T, T)
         ext = jnp.concatenate([store, shell], axis=0)
-    nbr = extended_neighbor_table_device(kind, nt)
+    nbr = extended_neighbor_table(kind, nt)
     bnd = shard_boundary_flags(kind, nt, axis_names) if bc.clamped else None
     w = uniform_weights(g)
     if use_kernel:
         return stencil_step_fused(ext, w, nbr, bnd, g=g, S=S, rule=rule,
-                                  bc=bc, interpret=interpret)
+                                  bc=bc)
     return kref.stencil_fused_ref(ext, w, nbr, S=S, rule=rule, bc=bc, bnd=bnd)
 
 
 def _store_perm(spec: OrderingSpec, kind: str, T: int, M: int,
                 inverse: bool) -> np.ndarray:
-    """Permutation between spec-path-ordered state and the block store.
+    """M³ permutation between spec-path-ordered state and the block store.
 
     Forward: ``store_flat = state_path[perm]``; inverse:
     ``state_path = store_flat[perm_inv]``. Composition of the two
     orderings' permutations — applied once per K-step run (the layout
-    boundary), never per step.
+    boundary), never per step, and only for orderings that
+    core.layout.needs_element_perm names.
     """
     hspec = store_spec(kind, T)
     if inverse:
@@ -351,10 +329,35 @@ def _store_perm(spec: OrderingSpec, kind: str, T: int, M: int,
     return rmo_to_path(spec, M)[path_to_rmo(hspec, M)]
 
 
-def _store_perm_device(spec: OrderingSpec, kind: str, T: int, M: int,
-                       inverse: bool):
-    return device_constant(("storeperm", spec, kind, T, M, inverse),
-                           lambda: _store_perm(spec, kind, T, M, inverse))
+def to_store(state_path: jnp.ndarray, spec: OrderingSpec, kind: str,
+             T: int, M: int) -> jnp.ndarray:
+    """A shard's (1,1,1,[C,]M³) path state -> its ``([C,] nb, T, T, T)``
+    block store (shard_map body). The store's own ordering
+    (``store_spec(kind, T)``) is already the store; row-/column-major
+    states go through the canonical cube by reshapes and an nb-sized
+    block gather; any other ordering gathers through one M³
+    permutation."""
+    lead = state_path.shape[3:-1]
+    flat = state_path.reshape(lead + (-1,))
+    hspec = store_spec(kind, T)
+    if needs_element_perm(spec):
+        flat = jnp.take(flat, _store_perm(spec, kind, T, M, False), axis=-1)
+    elif spec != hspec:
+        flat = apply_ordering(undo_ordering(flat, spec, M), hspec)
+    return flat.reshape(lead + (-1, T, T, T))
+
+
+def from_store(store: jnp.ndarray, spec: OrderingSpec, kind: str,
+               T: int, M: int) -> jnp.ndarray:
+    """Inverse of :func:`to_store`: block store -> (1,1,1,[C,]M³)."""
+    lead = store.shape[:-4]
+    flat = store.reshape(lead + (-1,))
+    hspec = store_spec(kind, T)
+    if needs_element_perm(spec):
+        flat = jnp.take(flat, _store_perm(spec, kind, T, M, True), axis=-1)
+    elif spec != hspec:
+        flat = apply_ordering(undo_ordering(flat, hspec, M), spec)
+    return flat.reshape((1, 1, 1) + lead + (-1,))
 
 
 def _state_pspec(channels: int) -> P:
@@ -367,7 +370,7 @@ def _state_pspec(channels: int) -> P:
 def make_distributed_step(mesh: jax.sharding.Mesh, spec: OrderingSpec,
                           local_M: int, g: int, *, T: int | None = None,
                           rule: str = "gol", bc: BoundarySpec | MixedBoundary | str = PERIODIC,
-                          use_kernel: bool = False, interpret: bool = True):
+                          use_kernel: bool = False):
     """jit'd distributed stencil step on a sharded (P·M)³ global state.
 
     Global state layout: (px, py, pz, M³) — device (a,b,c) owns row
@@ -391,33 +394,16 @@ def make_distributed_step(mesh: jax.sharding.Mesh, spec: OrderingSpec,
     C = get_rule(rule).channels
     pspec = _state_pspec(C)
     kind = stencil_block_kind(spec)
-    nt = local_M // T
 
     def local_step(state_path):  # (1,1,1,[C,]M³) per device
-        if C == 1:
-            s = state_path.reshape(-1)
-            store = s[_store_perm_device(spec, kind, T, local_M, False)]
-            store = store.reshape(nt ** 3, T, T, T)
-        else:
-            s = state_path.reshape(C, -1)
-            store = jnp.take(s, _store_perm_device(spec, kind, T, local_M,
-                                                   False), axis=-1)
-            store = store.reshape(C, nt ** 3, T, T, T)
-        store = shard_substeps(store, kind=kind,
-                               M=local_M, g=g, S=1, rule=rule, bc=bc,
-                               use_kernel=use_kernel, interpret=interpret)
-        if C == 1:
-            out = store.reshape(-1)[_store_perm_device(spec, kind, T,
-                                                       local_M, True)]
-            return out.reshape(1, 1, 1, -1)
-        out = jnp.take(store.reshape(C, -1),
-                       _store_perm_device(spec, kind, T, local_M, True),
-                       axis=-1)
-        return out.reshape(1, 1, 1, C, -1)
+        store = to_store(state_path, spec, kind, T, local_M)
+        store = shard_substeps(store, kind=kind, M=local_M, g=g, S=1,
+                               rule=rule, bc=bc, use_kernel=use_kernel)
+        return from_store(store, spec, kind, T, local_M)
 
-    # check_rep=False: pallas_call has no shard_map replication rule yet
-    step = shard_map(local_step, mesh=mesh, in_specs=pspec, out_specs=pspec,
-                     check_rep=False)
+    # check_vma=False: pallas_call has no shard_map replication rule
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=pspec,
+                         out_specs=pspec, check_vma=False)
     return jax.jit(step)
 
 
@@ -436,8 +422,6 @@ def shard_state(cube: jnp.ndarray, spec: OrderingSpec,
     a cubic power-of-2 block, because that is what the SFC machinery
     orders.
     """
-    from repro.core.layout import _perm_device
-
     squeeze = cube.ndim == 3
     if squeeze:
         cube = cube[None]
@@ -453,8 +437,7 @@ def shard_state(cube: jnp.ndarray, spec: OrderingSpec,
                          f"from global {(gk, gi, gj)} over procs {procs}")
     parts = cube.reshape(C, px, lk, py, li, pz, lj) \
         .transpose(1, 3, 5, 0, 2, 4, 6)  # (px,py,pz,C,lk,li,lj)
-    q = _perm_device(spec, lk, False)  # path pos -> rmo (apply_ordering)
-    out = jnp.take(parts.reshape(px, py, pz, C, -1), q, axis=-1)
+    out = apply_ordering(parts, spec)
     return out[:, :, :, 0] if squeeze else out
 
 
@@ -464,8 +447,6 @@ def unshard_state(state: jnp.ndarray, spec: OrderingSpec,
     (C, Gk, Gi, Gj)). ``global_M`` — a cube edge or (Gk,Gi,Gj) triple —
     is optional: the global box is derivable from the state shape and
     the argument is only checked against it when given."""
-    from repro.core.layout import _perm_device
-
     squeeze = state.ndim == 4
     if squeeze:
         state = state[:, :, :, None]
@@ -478,7 +459,6 @@ def unshard_state(state: jnp.ndarray, spec: OrderingSpec,
         if want != shape:
             raise ValueError(f"state {state.shape} implies global {shape}, "
                              f"caller said {want}")
-    p = _perm_device(spec, lk, True)  # rmo -> path pos (undo_ordering)
-    parts = jnp.take(state, p, axis=-1).reshape(px, py, pz, C, lk, lk, lk)
+    parts = undo_ordering(state, spec, lk)  # (px,py,pz,C,lk,lk,lk)
     out = parts.transpose(3, 0, 4, 1, 5, 2, 6).reshape(C, *shape)
     return out[0] if squeeze else out

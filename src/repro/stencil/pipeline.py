@@ -48,23 +48,24 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
                                  as_boundary, axes_periodic)
 from repro.core.layout import (blockize, blockize_fields, unblockize,
                                unblockize_fields)
-from repro.core.neighbors import (boundary_face_table_device,
-                                  neighbor_table_device)
+from repro.core.neighbors import boundary_face_table, neighbor_table
 from repro.core.orderings import OrderingSpec
 from repro.kernels import ref as kref
+from repro.kernels import backend
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import get_rule
-from repro.kernels.stencil3d import stencil_step_fused
+from repro.kernels.stencil3d import (LANES, VMEM_LIMIT_BYTES,
+                                     fused_kernel_vmem_bytes,
+                                     stencil_step_fused)
 
 from .domain import STENCIL_AXES
-from .halo import (shard_substeps, shard_state, stencil_block_kind,
-                   unshard_state, _state_pspec, _store_perm_device)
+from .halo import (from_store, shard_substeps, shard_state,
+                   stencil_block_kind, to_store, unshard_state, _state_pspec)
 
 __all__ = [
     "ResidentPipeline", "DistributedPipeline", "VMEM_BUDGET_BYTES",
@@ -88,7 +89,9 @@ class ResidentPipeline:
     """Stencil updates over a persistent curve-ordered block store.
 
     M:          cube edge (power of 2)
-    T:          block edge (T | M; S·g | T for the kernel path)
+    T:          block edge (T | M; S·g | T for the kernel path). None
+                (default) lets the platform decide: the lane-dense
+                min(M, 128) for the compiled TPU kernel, else 8
     g:          stencil radius
     kind:       block-grid curve — "morton" | "hilbert" | "row_major" |
                 "column_major" (core.neighbors.block_kind_of maps an
@@ -106,23 +109,25 @@ class ResidentPipeline:
                 mixed contracts) and refresh ghost layers per substep —
                 temporal blocking stays exactly as deep at domain edges
                 (DESIGN.md §8).
-    use_kernel: Pallas fused kernel (interpret on CPU) vs jnp oracle
+    use_kernel: Pallas fused kernel vs the jnp oracle. None (default)
+                lets the platform decide: the compiled kernel on a TPU,
+                the oracle on the CPU (where a kernel runs interpreted)
 
     Every knob is a static (hashable) field: a pipeline instance is both
     the configuration and the jit cache key of its runners.
     """
     M: int
-    T: int = 8
+    T: int | None = None
     g: int = 1
     kind: str = "morton"
-    use_kernel: bool = False
-    interpret: bool = True
+    use_kernel: bool | None = None
     S: int = 1
     rule: str = "gol"
     bc: BoundarySpec | MixedBoundary = PERIODIC
 
     def __post_init__(self):
         object.__setattr__(self, "bc", as_boundary(self.bc))
+        _default_kernel(self)
         assert self.M % self.T == 0, (self.M, self.T)
         if not self._valid_S(self.S):
             raise ValueError(
@@ -152,7 +157,7 @@ class ResidentPipeline:
              rule: str = "gol", n_steps: int = 10, *,
              bc: BoundarySpec | MixedBoundary | str = PERIODIC,
              vmem_limit: int = VMEM_BUDGET_BYTES, max_S: int = 8,
-             use_kernel: bool = False, interpret: bool = True,
+             use_kernel: bool | None = None,
              itemsize: int = 4) -> "ResidentPipeline":
         """Pick (T, S) minimising modelled HBM bytes/substep under VMEM.
 
@@ -169,16 +174,17 @@ class ResidentPipeline:
         pipeline unchanged: the single-device HBM stream is
         boundary-independent (clamped runs trade wrapped halo reads for
         in-window substitution, same window), so the plan itself does
-        not shift.
+        not shift. For the compiled TPU kernel only the lane-dense T is
+        searched (``_plan_search``).
         """
         C = get_rule(rule).channels
         T, S = _plan_search(
             M, g, max_S, vmem_limit, itemsize,
             lambda T, S: resident_bytes_per_step(M, T, g, n_steps,
                                                  itemsize, S=S, fields=C),
-            fields=C)
+            fields=C, compiled=_compiled(use_kernel))
         return cls(M=M, T=T, g=g, kind=kind, S=S, rule=rule, bc=bc,
-                   use_kernel=use_kernel, interpret=interpret)
+                   use_kernel=use_kernel)
 
     # -- layout boundary (paid once per K-step run, not per step) ---------
     def to_blocks(self, cube: jnp.ndarray) -> jnp.ndarray:
@@ -209,18 +215,15 @@ class ResidentPipeline:
         S = self.S if substeps is None else substeps
         assert self._valid_S(S), (self.T, self.g, S)
         g, bc, w = self.g, self.bc, uniform_weights(self.g)
-        nbr = neighbor_table_device(self.kind, self.nt,
-                                    periodic=axes_periodic(bc))
-        bnd = boundary_face_table_device(self.kind, self.nt) \
-            if bc.clamped else None
+        nbr = neighbor_table(self.kind, self.nt, periodic=axes_periodic(bc))
+        bnd = boundary_face_table(self.kind, self.nt) if bc.clamped else None
         rule = get_rule(self.rule)
-        use_kernel, interpret = self.use_kernel, self.interpret
+        use_kernel = self.use_kernel
 
         def step(store):
             if use_kernel:
                 return stencil_step_fused(store, w, nbr, bnd, g=g, S=S,
-                                          rule=rule.name, bc=bc,
-                                          interpret=interpret)
+                                          rule=rule.name, bc=bc)
             out = store
             for _ in range(S):
                 out = kref.stencil_fused_ref(out, w, nbr, S=1,
@@ -274,27 +277,41 @@ class ResidentPipeline:
 
 
 def _plan_search(M: int, g: int, max_S: int, vmem_limit: int, itemsize: int,
-                 cost_fn, fields: int = 1) -> tuple[int, int]:
+                 cost_fn, fields: int = 1, compiled: bool = False
+                 ) -> tuple[int, int]:
     """Enumerate valid power-of-two (T, S) under the VMEM budget and pick
     the ``cost_fn(T, S)``-cheapest pair (ties toward smaller windows) —
     the one search behind both the resident and the distributed plan.
     ``fields`` scales the modelled working set (multi-field stores keep
-    C windows live)."""
+    C windows live).
+
+    ``compiled`` (the fused kernel compiled for a TPU) searches only
+    the lane-dense edge min(M, 128) — a smaller T pads every vreg's
+    lanes and, at chip sizes, overflows the SMEM tables (DESIGN.md §4)
+    — and holds S to what the kernel's own VMEM allocation
+    (``fused_kernel_vmem_bytes``) fits in its scoped limit.
+    """
     best = None
-    T = 1
-    while T <= M:
-        if M % T == 0 and T % g == 0:
-            S = 1
-            while S <= max_S:
-                h = S * g
-                if h <= T and T % h == 0:
+    edges = ([min(M, LANES)] if compiled
+             else [1 << e for e in range(M.bit_length())])
+    for T in edges:
+        if M % T or T % g:
+            continue
+        S = 1
+        while S <= max_S:
+            h = S * g
+            if h <= T and T % h == 0:
+                if compiled:
+                    vm = fused_kernel_vmem_bytes(T, h, fields, itemsize)
+                    fits = vm <= VMEM_LIMIT_BYTES
+                else:
                     vm = fused_vmem_bytes(T, g, S, itemsize, fields=fields)
-                    if vm <= vmem_limit:
-                        cost = cost_fn(T, S)
-                        if best is None or (cost, vm) < best[0]:
-                            best = ((cost, vm), T, S)
-                S *= 2
-        T *= 2
+                    fits = vm <= vmem_limit
+                if fits:
+                    cost = cost_fn(T, S)
+                    if best is None or (cost, vm) < best[0]:
+                        best = ((cost, vm), T, S)
+            S *= 2
     if best is None:
         raise ValueError(
             f"no (T, S) fits vmem_limit={vmem_limit} for M={M}, g={g}, "
@@ -312,6 +329,26 @@ def fused_vmem_bytes(T: int, g: int, S: int, itemsize: int = 4, *,
     """
     W3 = (T + 2 * S * g) ** 3
     return itemsize * (fields * (2 * W3 + 2 * T ** 3) + (2 * g + 1) ** 3)
+
+
+def _compiled(use_kernel: bool | None) -> bool:
+    """True when the fused kernel will run compiled, i.e. on a TPU."""
+    return use_kernel is not False and backend.on_tpu()
+
+
+def _default_kernel(pipe, edge: str = "T") -> None:
+    """Resolve the platform defaults of a pipeline (or Gol3dConfig).
+
+    ``use_kernel=None``: the compiled kernel on a TPU, the jnp oracle on
+    the CPU (the only backend where a kernel runs interpreted). A block
+    edge (field ``edge``) of None: the lane-dense min(M, 128) for the
+    compiled kernel, else 8.
+    """
+    if pipe.use_kernel is None:
+        object.__setattr__(pipe, "use_kernel", backend.on_tpu())
+    if getattr(pipe, edge) is None:
+        T = min(pipe.M, LANES) if _compiled(pipe.use_kernel) else 8
+        object.__setattr__(pipe, edge, T)
 
 
 # ---------------------------------------------------------------------------
@@ -535,7 +572,8 @@ class DistributedPipeline:
 
     mesh:  3D device mesh over STENCIL_AXES (domain.make_stencil_mesh)
     spec:  element ordering of the public sharded state (shard_state)
-    M:     local shard edge (power of 2); T: block edge (T | M, S·g | T)
+    M:     local shard edge (power of 2); T: block edge (T | M, S·g | T;
+           None picks as ResidentPipeline does)
     g:     stencil radius; S: substeps per exchange; rule: rules.py key
            (its ``channels`` selects the C of the store and state layout)
     bc:    boundary contract (core.boundary): "periodic" (torus wrap,
@@ -550,16 +588,16 @@ class DistributedPipeline:
     mesh: jax.sharding.Mesh = field(compare=False)
     spec: OrderingSpec = field(default=None)  # type: ignore[assignment]
     M: int = 16
-    T: int = 8
+    T: int | None = None
     g: int = 1
     S: int = 1
     rule: str = "gol"
-    use_kernel: bool = False
-    interpret: bool = True
+    use_kernel: bool | None = None
     bc: BoundarySpec | MixedBoundary = PERIODIC
 
     def __post_init__(self):
         object.__setattr__(self, "bc", as_boundary(self.bc))
+        _default_kernel(self)
         assert self.spec is not None, "DistributedPipeline needs an OrderingSpec"
         assert self.M % self.T == 0, (self.M, self.T)
         if not self._valid_S(self.S):
@@ -601,7 +639,7 @@ class DistributedPipeline:
              rule: str = "gol", n_steps: int = 10, *,
              bc: BoundarySpec | MixedBoundary | str = PERIODIC,
              vmem_limit: int = VMEM_BUDGET_BYTES, max_S: int = 8,
-             use_kernel: bool = False, interpret: bool = True,
+             use_kernel: bool | None = None,
              itemsize: int = 4) -> "DistributedPipeline":
         """Pick (T, S) minimising modelled HBM **plus ICI** bytes/step.
 
@@ -622,9 +660,9 @@ class DistributedPipeline:
             lambda T, S: distributed_bytes_per_step(M, T, g, n_steps,
                                                     itemsize, S=S, bc=bc,
                                                     procs=procs, fields=C),
-            fields=C)
+            fields=C, compiled=_compiled(use_kernel))
         return cls(mesh=mesh, spec=spec, M=M, T=T, g=g, S=S, rule=rule,
-                   bc=bc, use_kernel=use_kernel, interpret=interpret)
+                   bc=bc, use_kernel=use_kernel)
 
     # -- the K-step runner -------------------------------------------------
     def run_fn(self, n_steps: int):
@@ -639,20 +677,13 @@ class DistributedPipeline:
             tail_rounds, tail_S = rem, 1
         else:
             tail_rounds, tail_S = (1, rem) if rem else (0, 0)
-        C = self.channels
-        pspec = _state_pspec(C)
+        pspec = _state_pspec(self.channels)
         spec, kind, M, T = self.spec, self.kind, self.M, self.T
-        nt = M // T
         round_kw = dict(kind=kind, M=M, g=self.g, rule=self.rule, bc=self.bc,
-                        use_kernel=self.use_kernel, interpret=self.interpret)
+                        use_kernel=self.use_kernel)
 
         def local_run(state_path):  # (1,1,1,[C,]M³) per device
-            perm = _store_perm_device(spec, kind, T, M, False)
-            if C == 1:
-                store = state_path.reshape(-1)[perm].reshape(nt ** 3, T, T, T)
-            else:
-                store = jnp.take(state_path.reshape(C, -1), perm, axis=-1)
-                store = store.reshape(C, nt ** 3, T, T, T)
+            store = to_store(state_path, spec, kind, T, M)
             if full:
                 store = jax.lax.fori_loop(
                     0, full,
@@ -663,15 +694,12 @@ class DistributedPipeline:
                     0, tail_rounds,
                     lambda _, st: shard_substeps(st, S=tail_S, **round_kw),
                     store)
-            iperm = _store_perm_device(spec, kind, T, M, True)
-            if C == 1:
-                return store.reshape(-1)[iperm].reshape(1, 1, 1, -1)
-            out = jnp.take(store.reshape(C, -1), iperm, axis=-1)
-            return out.reshape(1, 1, 1, C, -1)
+            return from_store(store, spec, kind, T, M)
 
-        # check_rep=False: pallas_call has no shard_map replication rule yet
-        return jax.jit(shard_map(local_run, mesh=self.mesh, in_specs=pspec,
-                                 out_specs=pspec, check_rep=False))
+        # check_vma=False: pallas_call has no shard_map replication rule
+        return jax.jit(jax.shard_map(local_run, mesh=self.mesh,
+                                     in_specs=pspec, out_specs=pspec,
+                                     check_vma=False))
 
     def run(self, state: jnp.ndarray, n_steps: int) -> jnp.ndarray:
         """Advance a (px,py,pz,[C,]M³) sharded path-ordered state K steps."""

@@ -34,7 +34,7 @@ from repro.core import (COLUMN_MAJOR, HILBERT, MORTON, NEUMANN0, PERIODIC,
                         as_boundary, axes_periodic, blockize,
                         boundary_face_table, dirichlet, mixed, pad_cube,
                         unblockize)
-from repro.core.neighbors import neighbor_table_device, ring_perms
+from repro.core.neighbors import neighbor_table, ring_perms
 from repro.kernels import ref as kref
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import apply_window_bc
@@ -182,7 +182,7 @@ def test_multi_clamped_face_blocks_against_oracle():
 
 def test_fused_kernel_requires_flags_when_clamped():
     store = jnp.zeros((8, 8, 8, 8), jnp.float32)
-    nbr = neighbor_table_device("morton", 2, periodic=False)
+    nbr = neighbor_table("morton", 2, periodic=False)
     with pytest.raises(ValueError):
         stencil_step_fused(store, uniform_weights(1), nbr, None,
                            g=1, S=1, rule="gol", bc=NEUMANN0)
@@ -313,9 +313,9 @@ def _collect_ppermute_perms(jaxpr):
             out.append(tuple(eqn.params["perm"]))
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
                     out += _collect_ppermute_perms(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jax.extend.core.Jaxpr):
                     out += _collect_ppermute_perms(sub)
     return out
 
@@ -324,16 +324,16 @@ def _collect_ppermute_perms(jaxpr):
 def test_exchange_shell_clamped_single_shard_matches_pad(bc):
     """On a 1×1×1 clamped mesh every ring is empty — zero ppermute pairs
     in the jaxpr — and the six slabs must equal the pad_cube ghost."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     M, T, h = 16, 8, 2
     mesh = make_stencil_mesh((1, 1, 1))
     cube = _cube(M, "jacobi")
     store = blockize(jnp.asarray(cube), T, kind="hilbert")
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         lambda st: exchange_shell(st.reshape(-1), "hilbert", M, T, h, bc=bc),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     perms = [p for p in _collect_ppermute_perms(jax.make_jaxpr(fn)(store).jaxpr)
              if p]
     assert perms == []  # clamped single-shard mesh: no pairs anywhere
@@ -351,7 +351,7 @@ def test_exchange_shell_clamped_single_shard_matches_pad(bc):
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_shard_substeps_clamped_single_shard_matches_oracle(use_kernel):
     """One clamped deep round on a 1×1×1 mesh == S clamped oracle steps."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     M, T, g, S = 16, 8, 1, 4
@@ -359,10 +359,10 @@ def test_shard_substeps_clamped_single_shard_matches_oracle(use_kernel):
     for bc in CLAMPED:
         cube = _cube(M)
         store = blockize(jnp.asarray(cube), T, kind="morton")
-        fn = shard_map(
+        fn = jax.jit(shard_map(
             lambda st: shard_substeps(st, kind="morton", M=M, g=g, S=S,
                                       bc=bc, use_kernel=use_kernel),
-            mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
         got = np.asarray(unblockize(fn(store), M, kind="morton"))
         np.testing.assert_array_equal(got, _oracle_run(cube, g, bc, S),
                                       err_msg=bc.kind)
@@ -461,7 +461,7 @@ def test_mixed_exchange_model_per_axis():
 def test_shard_substeps_mixed_single_shard_matches_oracle(use_kernel):
     """One mixed deep round on a 1×1×1 mesh == S mixed oracle steps, and
     the jaxpr carries ppermute pairs for the periodic axes only."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     M, T, g, S = 16, 8, 1, 4
@@ -469,10 +469,10 @@ def test_shard_substeps_mixed_single_shard_matches_oracle(use_kernel):
     mesh = make_stencil_mesh((1, 1, 1))
     cube = _cube(M)
     store = blockize(jnp.asarray(cube), T, kind="hilbert")
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         lambda st: shard_substeps(st, kind="hilbert", M=M, g=g, S=S,
                                   bc=duct, use_kernel=use_kernel),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     got = np.asarray(unblockize(fn(store), M, kind="hilbert"))
     np.testing.assert_array_equal(got, _oracle_run(cube, g, duct, S))
     # structural: the clamped k ring is empty, the periodic i/j rings

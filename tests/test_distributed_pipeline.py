@@ -151,12 +151,12 @@ def test_exchange_shell_self_wrap_matches_pad():
     mesh = make_stencil_mesh((1, 1, 1))
     cube = rng.normal(size=(M, M, M)).astype(np.float32)
     store = blockize(jnp.asarray(cube), T, kind="hilbert")
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         lambda st: exchange_shell(st.reshape(-1), "hilbert", M, T, h),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     k_lo, k_hi, i_lo, i_hi, j_lo, j_hi = map(np.asarray, fn(store))
     xp = np.pad(cube, h, mode="wrap")
     e = M + 2 * h
@@ -172,17 +172,17 @@ def test_exchange_shell_self_wrap_matches_pad():
 def test_shard_substeps_self_wrap_matches_oracle(use_kernel):
     """One deep round on a 1×1×1 mesh == S periodic oracle steps (gol)."""
     from repro.core import blockize, unblockize
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     M, T, g, S = 16, 8, 1, 4
     mesh = make_stencil_mesh((1, 1, 1))
     cube = (rng.random((M, M, M)) < 0.3).astype(np.float32)
     store = blockize(jnp.asarray(cube), T, kind="morton")
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         lambda st: shard_substeps(st, kind="morton", M=M, g=g, S=S,
                                   use_kernel=use_kernel),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     got = np.asarray(unblockize(fn(store), M, kind="morton"))
     want = jnp.asarray(cube)
     for _ in range(S):

@@ -26,7 +26,8 @@ from repro.roofline.analysis import analyze
 from repro.serve import make_serve_step
 from repro.train import TrainConfig, make_train_step
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 arch = "%s"
 cfg = get_smoke(arch)
 cfg = dataclasses.replace(cfg, act_spec=(("data",), "model", None))

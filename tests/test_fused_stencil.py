@@ -14,11 +14,12 @@ import numpy as np
 import pytest
 
 from repro.core import MORTON, blockize, blockize_fields
-from repro.core.neighbors import neighbor_table_device
+from repro.core.neighbors import neighbor_table
 from repro.kernels import ref
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import RULES, get_rule
-from repro.kernels.stencil3d import stencil_step_fused, stencil_sum_resident
+from repro.kernels.stencil3d import (VMEM_LIMIT_BYTES, fused_kernel_vmem_bytes,
+                                     stencil_step_fused, stencil_sum_resident)
 from repro.stencil import Gol3d, Gol3dConfig
 from repro.stencil.pipeline import (VMEM_BUDGET_BYTES, ResidentPipeline,
                                     fused_items_per_launch, fused_vmem_bytes,
@@ -61,7 +62,7 @@ def test_fused_kernel_matches_sequential_seed_steps(kind, S, rule):
     the kernel-family matrix, now spanning the multi-field C=2 wave
     store (DESIGN.md §9) next to the scalar rules."""
     w = uniform_weights(G)
-    nbr = neighbor_table_device(kind, M // T)
+    nbr = neighbor_table(kind, M // T)
     store = _store(kind, rule)
     r = get_rule(rule)
     fused = stencil_step_fused(store, w, nbr, g=G, S=S, rule=rule)
@@ -90,7 +91,7 @@ def test_fused_kernel_matches_sequential_seed_steps(kind, S, rule):
 def test_fused_identity_rule_is_raw_stencil_sum():
     """rule="identity", S=1 reproduces the PR-1 resident tap-sum kernel."""
     w = uniform_weights(G)
-    nbr = neighbor_table_device("morton", M // T)
+    nbr = neighbor_table("morton", M // T)
     store = _store("morton", "jacobi")
     a = stencil_step_fused(store, w, nbr, g=G, S=1, rule="identity")
     b = stencil_sum_resident(store, w, nbr, g=G)
@@ -99,7 +100,7 @@ def test_fused_identity_rule_is_raw_stencil_sum():
 
 def test_fused_kernel_rejects_bad_S():
     store = jnp.zeros((8, 8, 8, 8), jnp.float32)
-    nbr = neighbor_table_device("morton", 2)
+    nbr = neighbor_table("morton", 2)
     w = uniform_weights(1)
     with pytest.raises(ValueError):
         stencil_step_fused(store, w, nbr, g=1, S=3, rule="gol")  # 3 ∤ 8
@@ -239,27 +240,6 @@ def test_benchmark_rows_share_accounting():
 
 
 # ----------------------------------------------------------- cache satellites
-def test_device_constant_lru_eviction():
-    """Satellite: a hit moves the entry to the back, so hot tables
-    survive a sweep of one-off keys that would evict them under FIFO."""
-    from repro.core import layout
-
-    cap = layout._DEVICE_CONSTANTS_CAP
-    cache = layout._DEVICE_CONSTANTS
-    hot = ("test-lru-hot",)
-    layout.device_constant(hot, lambda: np.zeros(1, np.int32))
-    for i in range(cap):  # a full sweep: FIFO would now have evicted `hot`
-        if i == cap // 2:
-            layout.device_constant(hot, lambda: np.zeros(1, np.int32))
-        layout.device_constant(("test-lru-sweep", i),
-                               lambda: np.zeros(1, np.int32))
-    assert hot in cache
-    assert ("test-lru-sweep", 0) not in cache  # untouched entries do rotate out
-    assert len(cache) <= cap
-    for k in [hot] + [("test-lru-sweep", i) for i in range(cap)]:
-        cache.pop(k, None)
-
-
 def test_surface_row_plan_cached():
     """Satellite: pack_surface memoises the unique/searchsorted row plan
     on (spec, M, g, face, line); repeated packs reuse the same arrays."""
@@ -280,3 +260,75 @@ def test_surface_row_plan_cached():
     ref_buf = ops.pack_surface(data, MORTON, M_, g, "k0", use_kernel=False)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(ref_buf))
     np.testing.assert_array_equal(np.asarray(b), np.asarray(ref_buf))
+
+
+# ------------------------------------------- chunked grid (TPU tiling form)
+@pytest.mark.parametrize("rule", ["gol", "jacobi", "wave"])
+@pytest.mark.parametrize("bc", ["periodic", "neumann0", "dirichlet"])
+def test_fused_kernel_k_chunks_match_oracle(rule, bc):
+    """The (nb, T/kc) grid with 8-row i-halo pieces — the form that keeps
+    every block shape on the TPU's (8, 128) tiling — equals the jnp
+    oracle on every k-chunk (first and last chunks take one k halo from
+    the k-neighbour block, interior chunks take both from their own)."""
+    from repro.core.boundary import as_boundary
+    from repro.core.neighbors import boundary_face_table
+    from repro.kernels.stencil3d import fused_geometry
+
+    M_, T_, S = 64, 32, 2
+    bcs = as_boundary(bc)
+    nbr = neighbor_table("hilbert", M_ // T_, periodic=not bcs.clamped)
+    bnd = boundary_face_table("hilbert", M_ // T_) if bcs.clamped else None
+    C = get_rule(rule).channels
+    fields = rng.normal(size=(C, M_, M_, M_)).astype(np.float32)
+    if rule == "gol":
+        fields = (fields > 0.5).astype(np.float32)
+    store = blockize_fields(jnp.asarray(fields), T_, kind="hilbert")
+    if C == 1:
+        store = store[0]
+    w = uniform_weights(G)
+    assert fused_geometry(T_, S * G) == (8, 8)        # 4 chunks, 8-row halos
+    chunked = stencil_step_fused(store, w, nbr, bnd, g=G, S=S, rule=rule,
+                                 bc=bc)
+    oracle = ref.stencil_fused_ref(store, w, nbr, S=S, rule=rule, bc=bc,
+                                   bnd=bnd)
+    if rule == "jacobi":
+        np.testing.assert_allclose(np.asarray(chunked), np.asarray(oracle),
+                                   rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_array_equal(np.asarray(chunked), np.asarray(oracle))
+
+
+def test_fused_geometry_keeps_tpu_tiling():
+    from repro.kernels.stencil3d import fused_geometry
+
+    assert fused_geometry(128, 1) == (8, 8)
+    assert fused_geometry(128, 2) == (8, 8)
+    assert fused_geometry(128, 16) == (16, 16)
+    assert fused_geometry(8, 4) == (8, 8)   # small blocks: whole edges
+    assert fused_geometry(12, 3) == (12, 12)  # no tile divides T: whole
+
+
+def test_platform_picks_kernel_mode(monkeypatch):
+    """Interpret mode is CPU-only: on a TPU backend the kernels compile,
+    an explicit interpret=True raises, and the pipelines default to the
+    compiled kernel instead of the jnp oracle."""
+    from repro.kernels import backend
+
+    assert backend.resolve_interpret(None) is True      # this CPU run
+    assert ResidentPipeline(M=16, T=8).use_kernel is False
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    assert backend.resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="TPU"):
+        backend.resolve_interpret(True)
+    assert ResidentPipeline(M=16, T=8).use_kernel is True
+    assert Gol3dConfig(M=16).use_kernel is True
+    assert ResidentPipeline(M=16, T=8, use_kernel=False).use_kernel is False
+    # the compiled kernel takes the lane-dense block edge by default and
+    # in plan(); the oracle keeps the CPU's default T=8
+    assert ResidentPipeline(M=1024).T == 128
+    assert Gol3dConfig(M=1024).block_T == 128
+    assert Gol3dConfig(M=64).block_T == 64
+    assert Gol3dConfig(M=1024, use_kernel=False).block_T == 8
+    tuned = ResidentPipeline.plan(1024, g=1)
+    assert tuned.T == 128
+    assert fused_kernel_vmem_bytes(128, tuned.S) <= VMEM_LIMIT_BYTES

@@ -35,7 +35,7 @@ import pytest
 from repro.core import (COLUMN_MAJOR, HILBERT, MORTON, NEUMANN0, ROW_MAJOR,
                         blockize, blockize_fields, dirichlet, mixed,
                         unblockize_fields)
-from repro.core.neighbors import neighbor_table_device
+from repro.core.neighbors import neighbor_table
 from repro.kernels import ref as kref
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import RULES, get_rule
@@ -92,7 +92,7 @@ def test_blockize_fields_roundtrip_shares_block_permutation():
 
 def test_channel_mismatch_rejected():
     w = uniform_weights(G)
-    nbr = neighbor_table_device("morton", M // T)
+    nbr = neighbor_table("morton", M // T)
     scalar = blockize(_fields()[0], T, kind="morton")
     stacked = blockize_fields(_fields(), T, kind="morton")
     with pytest.raises(ValueError):  # wave needs the stacked store
@@ -249,7 +249,7 @@ def test_pipeline_wave_bytes_accessors_carry_C():
 def test_exchange_shell_multifield_matches_per_channel_pad():
     """The C-channel shell exchange packs every channel through one set
     of messages and equals the per-channel wrap pad on a self-mesh."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.stencil.halo import exchange_shell
@@ -258,9 +258,9 @@ def test_exchange_shell_multifield_matches_per_channel_pad():
     mesh = make_stencil_mesh((1, 1, 1))
     fields = np.asarray(_fields(2, M_))
     store = blockize_fields(jnp.asarray(fields), T_, kind="hilbert")
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         lambda st: exchange_shell(st.reshape(2, -1), "hilbert", M_, T_, h),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     slabs = [np.asarray(s) for s in fn(store)]
     e = M_ + 2 * h
     for c in range(2):
@@ -275,7 +275,7 @@ def test_exchange_shell_multifield_matches_per_channel_pad():
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_shard_substeps_wave_self_wrap_matches_oracle(use_kernel):
     """One deep C=2 round on a 1×1×1 mesh == S global wave steps."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.stencil.halo import shard_substeps
@@ -284,10 +284,10 @@ def test_shard_substeps_wave_self_wrap_matches_oracle(use_kernel):
     mesh = make_stencil_mesh((1, 1, 1))
     fields = _fields()
     store = blockize_fields(fields, T, kind="morton")
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         lambda st: shard_substeps(st, kind="morton", M=M, g=G, S=S,
                                   rule="wave", use_kernel=use_kernel),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     got = np.asarray(unblockize_fields(fn(store), M, kind="morton"))
     np.testing.assert_array_equal(got, _oracle_run(fields, G, S))
 

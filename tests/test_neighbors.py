@@ -8,7 +8,7 @@ from repro.core import (HILBERT, MORTON, ROW_MAJOR, OrderingSpec,
                         blockize, blockize_with_halo, unblockize)
 from repro.core.neighbors import (FACE_COLS, OFFSETS_FACE, OFFSETS_FULL,
                                   SELF_COL, block_kind_of, neighbor_table,
-                                  neighbor_table_device, ring_perms)
+                                  ring_perms)
 from repro.core.layout import block_order
 from repro.core.orderings import path_to_rmo, rmo_to_path
 from repro.kernels import ref
@@ -91,8 +91,6 @@ def test_neighbor_table_cached_and_readonly():
     a = neighbor_table("morton", 4)
     assert neighbor_table("morton", 4) is a
     assert not a.flags.writeable
-    d = neighbor_table_device("morton", 4)
-    assert neighbor_table_device("morton", 4) is d
 
 
 def test_ring_perms():
@@ -109,7 +107,7 @@ def test_assemble_halo_bit_identical(kind, g):
     cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
     halo = blockize_with_halo(cube, T, g, kind=kind, periodic=True)
     store = blockize(cube, T, kind=kind)
-    nbr = neighbor_table_device(kind, M // T)
+    nbr = neighbor_table(kind, M // T)
     asm = ref.assemble_halo_ref(store, nbr, g)
     np.testing.assert_array_equal(np.asarray(asm), np.asarray(halo))
 
@@ -117,20 +115,52 @@ def test_assemble_halo_bit_identical(kind, g):
 @pytest.mark.parametrize("kind", ("morton", "hilbert"))
 @pytest.mark.parametrize("g,T", [(1, 8), (2, 8), (1, 4), (4, 4)])
 def test_resident_kernel_bit_identical(kind, g, T):
-    """Pallas resident kernel == Pallas repack kernel, bit for bit."""
+    """Pallas resident kernel == Pallas repack kernel, bit for bit.
+
+    The weights are random signed powers of two, so every w·x product
+    is exact: the two programs then agree bit for bit whether or not
+    the compiler contracts a multiply-add into one FMA.
+    """
     M = 16
     cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=(2 * g + 1,) * 3).astype(np.float32))
+    shape = (2 * g + 1,) * 3
+    w = jnp.asarray((rng.choice([-1.0, 1.0], size=shape)
+                     * np.exp2(rng.integers(-3, 4, size=shape)))
+                    .astype(np.float32))
     old = stencil_sum_blocks(
         blockize_with_halo(cube, T, g, kind=kind, periodic=True), w, g=g)
     new = stencil_sum_resident(blockize(cube, T, kind=kind), w,
-                               neighbor_table_device(kind, M // T), g=g)
+                               neighbor_table(kind, M // T), g=g)
     np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+
+
+@pytest.mark.parametrize("kind", ("morton", "hilbert"))
+@pytest.mark.parametrize("g,T", [(1, 8), (2, 8), (1, 4), (4, 4)])
+def test_resident_kernel_normal_weights_within_rounding(kind, g, T):
+    """With normal weights the two kernels may round differently: the
+    CPU compiler fuses a different subset of the multiply-adds into FMAs
+    in each program (at g=1 about a tenth of the sites differ, by at
+    most 5e-8 of the largest sum; g=2 and g=4 agree bit for bit). Two
+    roundings of an n-term sum differ by at most 2·n·u·Σ|w·x| (u = 2⁻²⁴);
+    a halo or neighbour-table fault moves a site by O(|w·x|)."""
+    M = 16
+    s = 2 * g + 1
+    cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
+    w = rng.normal(size=(s,) * 3).astype(np.float32)
+    halo = blockize_with_halo(cube, T, g, kind=kind, periodic=True)
+    old = stencil_sum_blocks(halo, jnp.asarray(w), g=g)
+    new = stencil_sum_resident(blockize(cube, T, kind=kind), jnp.asarray(w),
+                               neighbor_table(kind, M // T), g=g)
+    x = np.abs(np.asarray(halo, np.float64))
+    mag = sum(abs(float(w[a, b, c])) * x[:, a:a + T, b:b + T, c:c + T]
+              for a in range(s) for b in range(s) for c in range(s))
+    diff = np.abs(np.asarray(new, np.float64) - np.asarray(old, np.float64))
+    assert np.all(diff <= 2 * s ** 3 * 2.0 ** -24 * mag)
 
 
 def test_resident_kernel_rejects_non_dividing_g():
     store = jnp.zeros((8, 8, 8, 8), jnp.float32)
-    nbr = neighbor_table_device("morton", 2)
+    nbr = neighbor_table("morton", 2)
     with pytest.raises(ValueError):
         stencil_sum_resident(store, jnp.zeros((7, 7, 7)), nbr, g=3)
 

@@ -17,8 +17,8 @@ Three layers, matching serve/roi.py and serve/service.py:
    ``missing_ranges`` manifest, rejected, or error. Never a hang, never
    a silently wrong payload.
 
-Plus the thread-safety satellites (layout.device_constant and the ops
-row-plan LRU hammered from a pool) and the benchmark-model consistency
+Plus the thread-safety satellite (the ops row-plan LRU hammered from a
+pool) and the benchmark-model consistency
 row the CI diff gate pins.
 """
 
@@ -394,34 +394,6 @@ def test_fetch_error_is_runtime_error():
 # ---------------------------------------------------------------------------
 # satellites: thread-safe LRU caches under the serving pool
 # ---------------------------------------------------------------------------
-
-def test_device_constant_thread_safe_under_hammer():
-    from repro.core import layout as L
-
-    nkeys = L._DEVICE_CONSTANTS_CAP // 2
-    errs = []
-
-    def worker(t):
-        try:
-            for i in range(200):
-                k = ("tsafe-hammer", (t + i) % nkeys)
-                v = L.device_constant(
-                    k, lambda k=k: np.full((8,), k[1], np.float32))
-                assert int(np.asarray(v)[0]) == k[1]
-        except Exception as e:  # pragma: no cover - failure path
-            errs.append(e)
-
-    threads = [threading.Thread(target=worker, args=(t,)) for t in range(8)]
-    for th in threads:
-        th.start()
-    for th in threads:
-        th.join()
-    assert errs == []
-    with L._DEVICE_CONSTANTS_LOCK:
-        assert len(L._DEVICE_CONSTANTS) <= L._DEVICE_CONSTANTS_CAP
-        for k in [k for k in L._DEVICE_CONSTANTS if k[0] == "tsafe-hammer"]:
-            del L._DEVICE_CONSTANTS[k]  # don't leak into other tests
-
 
 def test_row_plan_thread_safe_under_hammer():
     from repro.kernels import ops

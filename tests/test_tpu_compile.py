@@ -1,0 +1,158 @@
+"""Compile the main path for a described TPU v5e chip, at chip_smoke.py's
+sizes. Nothing runs: the TPU compiler refuses here what the chip would
+refuse (block shapes off the (8, 128) tiling, SMEM or VMEM overflow, a
+program over the chip's HBM), at no chip time.
+
+The topology is described inside a fixture, never at import time: only
+one process may load the TPU library, and the test workers all import
+this file.
+"""
+
+import base64
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.neighbors import boundary_face_table, neighbor_table
+from repro.kernels import backend
+from repro.kernels.ops import uniform_weights
+from repro.kernels.stencil3d import stencil_step_fused, stencil_sum_resident
+from repro.stencil import Gol3d, Gol3dConfig, ResidentPipeline
+
+HBM_BYTES = int(15.75 * 2 ** 30)   # what XLA lets one v5e program use
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_path(monkeypatch):
+    """The backend the kernels see is the TPU: no interpret mode, and
+    the pipelines pick the fused kernel."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+
+
+def _check(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used <= HBM_BYTES, used
+
+
+@pytest.mark.parametrize("rule,bc,M", [
+    ("gol", "periodic", 1024),     # chip_smoke.py's main phase
+    ("wave", "periodic", 256),     # its two-field phase
+    ("jacobi", "neumann0", 256),
+    ("gol", "dirichlet", 256),
+])
+def test_fused_kernel_compiles(one_chip, compiled_path, rule, bc, M):
+    T, S, g = 128, 4, 1
+    nt = M // T
+    nb = nt ** 3
+    C = 2 if rule == "wave" else 1
+    shape = (nb, T, T, T) if C == 1 else (C, nb, T, T, T)
+
+    def sds(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    clamped = bc != "periodic"
+    nbr = neighbor_table("hilbert", nt, periodic=not clamped)
+    bnd = boundary_face_table("hilbert", nt) if clamped else None
+    fn = jax.jit(lambda store: stencil_step_fused(
+        store, uniform_weights(g), nbr, bnd, g=g, S=S, rule=rule, bc=bc))
+    _check(fn.lower(sds(shape)).compile())
+
+
+def test_resident_pipeline_run_fn_compiles(one_chip, compiled_path):
+    pipe = ResidentPipeline(M=1024, T=128, g=1, kind="hilbert", S=4)
+    assert pipe.use_kernel is True
+    store = jax.ShapeDtypeStruct((pipe.nb, 128, 128, 128), jnp.float32,
+                                 sharding=one_chip)
+    _check(pipe.run_fn(8).lower(store).compile())
+
+
+def test_gol3d_resident_defaults_compile(one_chip, compiled_path):
+    """Gol3d at the smoke's M with every default: the platform picks the
+    compiled kernel and the lane-dense block edge, and the row-major
+    state reaches the store by reshapes — no M³ permutation is embedded
+    (it alone would be 4 GiB of program constants)."""
+    M = 1024
+    state = jax.ShapeDtypeStruct((M ** 3,), jnp.float32, sharding=one_chip)
+    app = Gol3d(Gol3dConfig(M=M), state_path=state)
+    assert app.cfg.use_kernel is True and app.cfg.block_T == 128
+    lowered = app.resident_fn(8).lower(state)
+    assert len(lowered.as_text()) < 2 ** 20
+    _check(lowered.compile())
+
+
+def _kernel_body(lowered) -> bytes:
+    """The serialized Mosaic module of the program's one TPU kernel."""
+    body = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                     lowered.as_text())
+    return base64.b64decode(body.group(1))
+
+
+def test_kernel_cache_key_ignores_checkout(one_chip, compiled_path,
+                                           monkeypatch, tmp_path):
+    """A TPU kernel's body embeds its source locations, which the
+    persistent-cache key hashes. enable_compile_cache cuts the
+    checkout's path from them, so every checkout of the same code finds
+    the same cache entries."""
+    from repro import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    name = "jax_hlo_source_file_canonicalization_regex"
+    was = jax.config.jax_hlo_source_file_canonicalization_regex
+    store = jax.ShapeDtypeStruct((1, 128, 128, 128), jnp.float32,
+                                 sharding=one_chip)
+    nbr = neighbor_table("hilbert", 1)
+    root = str(compile_cache.CHECKOUT).encode()
+
+    def body(S):
+        return _kernel_body(jax.jit(lambda s: stencil_step_fused(
+            s, uniform_weights(1), nbr, g=1, S=S)).lower(store))
+
+    try:
+        jax.config.update(name, None)
+        assert root in body(2)
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        cut = body(8)
+        assert root not in cut and b"src/repro/kernels/stencil3d.py" in cut
+    finally:
+        jax.config.update(name, was)
+
+
+def test_resident_sum_kernel_refused_not_interpreted(one_chip, compiled_path):
+    """stencil_sum_resident keeps its (h, T, h) halo pieces, off the TPU
+    tiling: on the chip it must be refused, never run interpreted."""
+    store = jax.ShapeDtypeStruct((512, 128, 128, 128), jnp.float32,
+                                 sharding=one_chip)
+    fn = jax.jit(lambda s: stencil_sum_resident(
+        s, uniform_weights(1), neighbor_table("hilbert", 8), g=1))
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        fn.lower(store).compile()
