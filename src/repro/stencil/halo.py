@@ -95,6 +95,7 @@ def stencil_block_kind(spec: OrderingSpec) -> str:
     return kind if kind in ("morton", "hilbert") else "morton"
 
 
+@jax.named_scope("sfc.pack")
 def _pack_to_slab(store_flat, hspec, M, h, face, shape):
     """Pack one deep face from the (C, nb·T³) store, canonical slab layout."""
     buf = ops.pack_surface(store_flat, hspec, M, h, face)  # (C, L)
@@ -104,6 +105,7 @@ def _pack_to_slab(store_flat, hspec, M, h, face, shape):
         .reshape((C,) + shape)
 
 
+@jax.named_scope("sfc.unpack")
 def _unpack_recv(buf, hspec, M, h, face, shape):
     """Scatter a received deep-face buffer (sender's pack order) into the
     canonical slab — sender and receiver share the index lists, so the
@@ -175,6 +177,7 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
         store_flat = store_flat[None]
     shp_k, _, shp_i, _, shp_j, _ = shell_slab_shapes(M, h)
 
+    @jax.named_scope("sfc.pack")
     def _fill_edges(slab_lo, slab_hi, face_lo, face_hi, axis, ax_name):
         """On mesh-edge shards, replace received-zero slabs with BC data."""
         n = jax.lax.psum(1, ax_name)
@@ -188,8 +191,9 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
         return slab_lo, slab_hi
 
     # --- k axis: pack the deep slab faces, ring-shift, unpack
-    buf_k0 = ops.pack_surface(store_flat, hspec, M, h, "k0")
-    buf_k1 = ops.pack_surface(store_flat, hspec, M, h, "k1")
+    with jax.named_scope("sfc.pack"):
+        buf_k0 = ops.pack_surface(store_flat, hspec, M, h, "k0")
+        buf_k1 = ops.pack_surface(store_flat, hspec, M, h, "k1")
     fwd, bwd = ring_perms(jax.lax.psum(1, axis_names[0]), periodic=periodic[0])
     recv_lo = jax.lax.ppermute(buf_k1, axis_names[0], fwd)  # prev's high face
     recv_hi = jax.lax.ppermute(buf_k0, axis_names[0], bwd)  # next's low face
@@ -202,12 +206,13 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
                                            own_k0, own_k1, 0, axis_names[0])
 
     # --- i axis: core faces + k-received edges (corner-correct)
-    my_i0 = _pack_to_slab(store_flat, hspec, M, h, "i0", (M, h, M))
-    my_i1 = _pack_to_slab(store_flat, hspec, M, h, "i1", (M, h, M))
-    face_i0 = jnp.concatenate(
-        [slab_k_lo[..., :h, :], my_i0, slab_k_hi[..., :h, :]], axis=-3)
-    face_i1 = jnp.concatenate(
-        [slab_k_lo[..., M - h:, :], my_i1, slab_k_hi[..., M - h:, :]], axis=-3)
+    with jax.named_scope("sfc.pack"):
+        my_i0 = _pack_to_slab(store_flat, hspec, M, h, "i0", (M, h, M))
+        my_i1 = _pack_to_slab(store_flat, hspec, M, h, "i1", (M, h, M))
+        face_i0 = jnp.concatenate(
+            [slab_k_lo[..., :h, :], my_i0, slab_k_hi[..., :h, :]], axis=-3)
+        face_i1 = jnp.concatenate(
+            [slab_k_lo[..., M - h:, :], my_i1, slab_k_hi[..., M - h:, :]], axis=-3)
     fwd, bwd = ring_perms(jax.lax.psum(1, axis_names[1]), periodic=periodic[1])
     slab_i_lo = jax.lax.ppermute(face_i1, axis_names[1], fwd)
     slab_i_hi = jax.lax.ppermute(face_i0, axis_names[1], bwd)
@@ -217,17 +222,17 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
     assert slab_i_lo.shape[-3:] == shp_i, (slab_i_lo.shape, shp_i)
 
     # --- j axis: core faces + both received edge sets
-    my_j0 = _pack_to_slab(store_flat, hspec, M, h, "j0", (M, M, h))
-    my_j1 = _pack_to_slab(store_flat, hspec, M, h, "j1", (M, M, h))
-
     def _j_face(mine, sl):
         mid = jnp.concatenate(
             [slab_k_lo[..., sl], mine, slab_k_hi[..., sl]], axis=-3)
         return jnp.concatenate(
             [slab_i_lo[..., sl], mid, slab_i_hi[..., sl]], axis=-2)
 
-    face_j0 = _j_face(my_j0, slice(0, h))
-    face_j1 = _j_face(my_j1, slice(M - h, M))
+    with jax.named_scope("sfc.pack"):
+        my_j0 = _pack_to_slab(store_flat, hspec, M, h, "j0", (M, M, h))
+        my_j1 = _pack_to_slab(store_flat, hspec, M, h, "j1", (M, M, h))
+        face_j0 = _j_face(my_j0, slice(0, h))
+        face_j1 = _j_face(my_j1, slice(M - h, M))
     fwd, bwd = ring_perms(jax.lax.psum(1, axis_names[2]), periodic=periodic[2])
     slab_j_lo = jax.lax.ppermute(face_j1, axis_names[2], fwd)
     slab_j_hi = jax.lax.ppermute(face_j0, axis_names[2], bwd)
@@ -293,17 +298,18 @@ def shard_substeps(store: jnp.ndarray, *, kind: str, M: int, g: int, S: int,
     flat = store.reshape(store.shape[0], -1) if multi else store.reshape(-1)
     slabs = exchange_shell(flat, kind, M, T, h, axis_names, bc=bc)
     pos = shell_slab_positions(nt, T, h)
-    if multi:
-        C = store.shape[0]
-        vals = jnp.concatenate([s.reshape(C, -1) for s in slabs], axis=1)
-        shell = jnp.zeros((C, shell_block_count(nt) * T ** 3), store.dtype
-                          ).at[:, pos].set(vals).reshape(C, -1, T, T, T)
-        ext = jnp.concatenate([store, shell], axis=1)
-    else:
-        vals = jnp.concatenate([s.reshape(-1) for s in slabs])
-        shell = jnp.zeros((shell_block_count(nt) * T ** 3,), store.dtype
-                          ).at[pos].set(vals).reshape(-1, T, T, T)
-        ext = jnp.concatenate([store, shell], axis=0)
+    with jax.named_scope("sfc.shell"):
+        if multi:
+            C = store.shape[0]
+            vals = jnp.concatenate([s.reshape(C, -1) for s in slabs], axis=1)
+            shell = jnp.zeros((C, shell_block_count(nt) * T ** 3), store.dtype
+                              ).at[:, pos].set(vals).reshape(C, -1, T, T, T)
+            ext = jnp.concatenate([store, shell], axis=1)
+        else:
+            vals = jnp.concatenate([s.reshape(-1) for s in slabs])
+            shell = jnp.zeros((shell_block_count(nt) * T ** 3,), store.dtype
+                              ).at[pos].set(vals).reshape(-1, T, T, T)
+            ext = jnp.concatenate([store, shell], axis=0)
     nbr = extended_neighbor_table(kind, nt)
     bnd = shard_boundary_flags(kind, nt, axis_names) if bc.clamped else None
     w = uniform_weights(g)
