@@ -56,10 +56,16 @@ load-bearing: XLA's contraction choices shift with rank).
 TPU layout (DESIGN.md §4): Mosaic refuses block shapes that cut the two
 minor dimensions off the (8, 128) tiling, so ``stencil_step_fused`` runs
 a ``(nb, T/kc)`` grid of k-chunks whose pieces are whole along j and
-whole sublane tiles along i, and cuts the i/j halos in VMEM. Its VMEM
-per grid step is the padded ``(C, kc+2h, T+2h, T+2h)`` scratch plus the
+whole sublane tiles along i, and cuts the i/j halos in VMEM. A tap at
+an (i, j) offset off that tiling costs a rotate and a select per vreg,
+so each substep shifts every window plane once into its (2g+1)² - 1
+offset copies, stored aligned in a VMEM ring that holds the 2g+1
+planes in flight, and every tap is an aligned load of a copy, summed
+in the original order (``_fused_kernel``). Its VMEM per grid step is
+the ``(C, kc+2h, T+2h, T+2h)`` window, the ring of
+``(2g+1)·((2g+1)² - 1)·C`` planes of ``(T+2h, T+2h-2g)``, and the
 double-buffered pieces and output slab (``fused_kernel_vmem_bytes``) —
-8.9 MiB at T=128, S=4, g=1; only T=128 makes the store lane-dense. ``stencil_sum_resident``
+13.1 MiB at T=128, S=4, g=1; only T=128 makes the store lane-dense. ``stencil_sum_resident``
 keeps the ``(h, T, h)`` pieces and does not lower for the TPU (it fails
 loudly there); it remains the interpret-mode baseline. A pure stencil
 is VPU work; the kernels unroll the (2g+1)³ taps for g ≤ 2, and
@@ -287,19 +293,28 @@ def fused_geometry(T: int, h: int) -> tuple[int, int]:
     return kc, hb
 
 
+def _tile_bytes(rows: int, lanes: int) -> int:
+    """VMEM of one f32 (rows, lanes) plane, padded to whole (8, 128) tiles."""
+    return 4 * (-(-rows // SUBLANES) * SUBLANES) * (-(-lanes // LANES) * LANES)
+
+
 def fused_kernel_vmem_bytes(T: int, h: int, fields: int = 1,
-                            itemsize: int = 4) -> int:
+                            itemsize: int = 4, *, g: int = 1) -> int:
     """VMEM one grid step of ``stencil_step_fused`` allocates.
 
-    The f32 ``(C, kc+2h, T+2h, T+2h)`` window scratch, the 27 pieces
-    (three j-columns of ``(kc+2h) x (T+2·hb) x T`` each) and the
-    ``C·kc·T²`` output slab, both double-buffered.
+    Two f32 scratches, each plane padded to whole (8, 128) tiles: the
+    ``(C, kc+2h, T+2h, T+2h)`` window and the tap-copy ring of
+    ``(2g+1)·((2g+1)² - 1)·C`` planes of ``(T+2h, T+2h-2g)``. Then the
+    27 pieces (three j-columns of ``(kc+2h) x (T+2·hb) x T`` each) and
+    the ``C·kc·T²`` output slab, both double-buffered.
     """
     kc, hb = fused_geometry(T, h)
     W = T + 2 * h
-    scratch = 4 * fields * (kc + 2 * h) * W * W
+    s = 2 * g + 1
+    window = fields * (kc + 2 * h) * _tile_bytes(W, W)
+    ring = fields * s * (s * s - 1) * _tile_bytes(W, W - 2 * g)
     pieces = 3 * (kc + 2 * h) * (T + 2 * hb) * T
-    return scratch + 2 * itemsize * fields * (pieces + kc * T * T)
+    return window + ring + 2 * itemsize * fields * (pieces + kc * T * T)
 
 
 def _fused_piece_index(i, z, nbr_ref, _bnd_ref, *, a: int, col: int,
@@ -405,6 +420,25 @@ def _fused_refresh(win, flags, d: int, Ek: int, Ei: int, bc):
         jax.lax.fori_loop(0, Ek, plane, 0)
 
 
+def _fused_shift(win, ring, q, slot, Ei: int, Oi: int, s: int):
+    """Write the (2g+1)² - 1 shifted tap copies of window plane q into
+    ring ``slot`` (q mod (2g+1)), every one at offset (0, 0) of its tile.
+
+    Copy (di, dj) is ``win[c, q, di:di+Oi, dj:dj+Oi]``; (0, 0) is the
+    window plane itself and is not copied. Axis by axis: the 2g lane
+    shifts (0, dj) keep all Ei rows, so each (di, dj) is then a sublane
+    shift of (0, dj) — or of the plane for dj = 0.
+    """
+    for c in range(win.shape[0]):
+        for dj in range(1, s):
+            ring[slot, dj - 1, c, :Ei, :Oi] = win[c, q, :Ei, dj:dj + Oi]
+        for di in range(1, s):
+            ring[slot, di * s - 1, c, :Oi, :Oi] = win[c, q, di:di + Oi, :Oi]
+            for dj in range(1, s):
+                ring[slot, di * s + dj - 1, c, :Oi, :Oi] = \
+                    ring[slot, dj - 1, c, di:di + Oi, :Oi]
+
+
 def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
                   S: int, kc: int, hb: int, rule, bc):
     """S substeps of tap-sum + update rule on one k-chunk, in VMEM.
@@ -420,15 +454,30 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
     Every substep tap-sums **all C channels** and hands the stacked
     fields to the rule (DESIGN.md §9).
 
+    Tap-copy ring (DESIGN.md §4): a tap at an offset (di, dj) off the
+    (8, 128) tiling costs a relayout of every vreg it reads, so each
+    window plane is shifted once per substep into its (2g+1)² - 1
+    offset copies, stored aligned in the ``ring`` scratch, and every
+    tap is an aligned load of a copy — (0, 0) reads the window plane
+    itself. Output plane p reads planes p..p+2g, so the ring holds 2g+1
+    planes' copies in slots q mod (2g+1): iteration p first shifts
+    plane p+2g into the slot plane p-1 held, then sums, then overwrites
+    window plane p, whose copies (and whose (0, 0) read) are done by
+    then. The taps are summed in the same (dk, di, dj) order from the
+    same values as a sum of window slices, so every output is
+    bit-identical to it.
+
     Clamped runs (DESIGN.md §8): before every substep, the outer
     ``g·(S-u)`` ghost layers on faces flagged in ``bnd_ref`` (the second
     scalar-prefetch operand) are substituted with boundary values —
     dirichlet constants or the replicated domain-edge plane, per channel
     — so domain sites only ever consume valid taps and clamped faces
-    temporally block exactly as deep as periodic ones.
+    temporally block exactly as deep as periodic ones. The ring is
+    formed from the refreshed window.
     """
-    pieces, o_ref, win = refs[:27], refs[27], refs[28]
+    pieces, o_ref, win, ring = refs[:27], refs[27], refs[28], refs[29]
     multi = len(o_ref.shape) == 5
+    C = win.shape[0]
     h = S * g
     _fused_assemble(pieces, win, T, h, kc, hb)
     i, z = pl.program_id(0), pl.program_id(1)
@@ -443,19 +492,31 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
         if bc.clamped:
             _fused_refresh(win, flags, d, Ek, Ei, bc)
 
+        def first(q, carry, Ei=Ei, Oi=Oi):     # planes 0..2g-1 into the ring
+            _fused_shift(win, ring, q, q, Ei, Oi, s)
+            return carry
+        jax.lax.fori_loop(0, 2 * g, first, 0)
+
         def plane(p, carry, Ei=Ei, Oi=Oi, last=u == S - 1):
-            x = [win[:, p + dk, :Ei, :Ei] for dk in range(s)]
-            tap = []
-            for c in range(win.shape[0]):
+            slots = [jax.lax.rem(p + dk, s) for dk in range(s)]
+            _fused_shift(win, ring, p + 2 * g, slots[2 * g], Ei, Oi, s)
+
+            def tap(dk, di, dj, c):
+                if di == dj == 0:
+                    return win[c, p + dk, :Oi, :Oi]
+                return ring[slots[dk], di * s + dj - 1, c, :Oi, :Oi]
+
+            taps = []
+            for c in range(C):
                 acc = jnp.zeros((Oi, Oi), jnp.float32)
                 for dk in range(s):
                     for di in range(s):
                         for dj in range(s):
                             acc = acc + w_ref[dk, di, dj].astype(jnp.float32) \
-                                * x[dk][c, di:di + Oi, dj:dj + Oi]
-                tap.append(acc)
-            centre = x[g][:, g:g + Oi, g:g + Oi]
-            new = rule.apply(centre, jnp.stack(tap), g)
+                                * tap(dk, di, dj, c)
+                taps.append(acc)
+            centre = jnp.stack([tap(g, g, g, c) for c in range(C)])
+            new = rule.apply(centre, jnp.stack(taps), g)
             if not last:
                 win[:, p, :Oi, :Oi] = new
             elif multi:
@@ -565,7 +626,8 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
     kern = functools.partial(_fused_kernel, T=T, s=s, g=g, S=S, kc=kc, hb=hb,
                              rule=r, bc=bc)
     W = T + 2 * h
-    scratch = [pltpu.VMEM((C, kc + 2 * h, W, W), jnp.float32)]
+    scratch = [pltpu.VMEM((C, kc + 2 * h, W, W), jnp.float32),
+               pltpu.VMEM((s, s * s - 1, C, W, W - 2 * g), jnp.float32)]
     return pl.pallas_call(
         kern,
         out_shape=out_shape,

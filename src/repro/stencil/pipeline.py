@@ -302,7 +302,7 @@ def _plan_search(M: int, g: int, max_S: int, vmem_limit: int, itemsize: int,
             h = S * g
             if h <= T and T % h == 0:
                 if compiled:
-                    vm = fused_kernel_vmem_bytes(T, h, fields, itemsize)
+                    vm = fused_kernel_vmem_bytes(T, h, fields, itemsize, g=g)
                     fits = vm <= VMEM_LIMIT_BYTES
                 else:
                     vm = fused_vmem_bytes(T, g, S, itemsize, fields=fields)

@@ -298,6 +298,84 @@ def test_fused_kernel_k_chunks_match_oracle(rule, bc):
         np.testing.assert_array_equal(np.asarray(chunked), np.asarray(oracle))
 
 
+# ------------------------------------------------------- tap-copy ring
+RING_CASES = [
+    ("gol", 2, 1, "periodic"), ("gol", 4, 1, "periodic"),
+    ("jacobi", 2, 1, "periodic"), ("jacobi", 4, 1, "periodic"),
+    ("wave", 2, 1, "periodic"), ("wave", 4, 1, "periodic"),
+    ("jacobi", 2, 2, "periodic"),
+    ("wave", 2, 1, "mixed-i"),
+]
+
+
+def _ring_store(rule, M_, T_):
+    C = get_rule(rule).channels
+    fields = rng.normal(size=(C, M_, M_, M_)).astype(np.float32)
+    if rule == "gol":
+        fields = (fields > 0.5).astype(np.float32)
+    store = blockize_fields(jnp.asarray(fields), T_, kind="hilbert")
+    return store[0] if C == 1 else store
+
+
+@pytest.mark.parametrize("rule,S,g,bc", RING_CASES)
+def test_fused_ring_matches_sequential_launches(rule, S, g, bc):
+    """The tap-copy ring at a geometry the small matrix does not reach:
+    M=64, T=32 runs four k-chunks per block (kc < T), and the shrinking
+    window (34-40 rows and lanes) is not a whole number of sublane
+    tiles. One S-substep launch equals S single-substep launches bit
+    for bit, and the jnp oracle exactly for the rules whose sums are
+    exact (gol) or FMA-immune (wave)."""
+    from repro.core.boundary import as_boundary, axes_periodic, mixed
+    from repro.core.neighbors import boundary_face_table
+
+    M_, T_ = 64, 32
+    bcs = mixed(i="neumann0") if bc == "mixed-i" else as_boundary(bc)
+    nt = M_ // T_
+    nbr = neighbor_table("hilbert", nt, periodic=axes_periodic(bcs))
+    bnd = boundary_face_table("hilbert", nt) if bcs.clamped else None
+    store = _ring_store(rule, M_, T_)
+    w = uniform_weights(g)
+    fused = stencil_step_fused(store, w, nbr, bnd, g=g, S=S, rule=rule,
+                               bc=bcs)
+    seq = store
+    for _ in range(S):
+        seq = stencil_step_fused(seq, w, nbr, bnd, g=g, S=1, rule=rule,
+                                 bc=bcs)
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(seq))
+    if rule != "jacobi":
+        oracle = ref.stencil_fused_ref(store, w, nbr, S=S, rule=rule,
+                                       bc=bcs, bnd=bnd)
+        np.testing.assert_array_equal(np.asarray(fused), np.asarray(oracle))
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_fused_ring_identity_is_resident_sum(g):
+    """rule="identity" through the ring at M=64, T=32 reproduces the
+    resident tap-sum kernel, which slices its window directly."""
+    nbr = neighbor_table("hilbert", 2)
+    store = _ring_store("jacobi", 64, 32)
+    w = uniform_weights(g)
+    a = stencil_step_fused(store, w, nbr, g=g, S=1, rule="identity")
+    b = stencil_sum_resident(store, w, nbr, g=g)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fused_kernel_vmem_counts_ring():
+    """The kernel's VMEM, hand-worked at T=128, h=4 (kc = hb = 8): every
+    scratch plane pads to whole (8, 128) tiles, so a 136-row plane of
+    134 or 132 lanes takes 136 x 256 x 4 = 139264 B."""
+    plane = 136 * 256 * 4
+    window = 16 * plane                         # (kc + 2h) planes
+    streamed = 2 * 4 * (3 * 16 * 144 * 128 + 8 * 128 * 128)
+    assert fused_kernel_vmem_bytes(128, 4, g=1) == \
+        window + 3 * 8 * plane + streamed       # 3 planes x 8 shifts
+    assert fused_kernel_vmem_bytes(128, 4, g=2) == \
+        window + 5 * 24 * plane + streamed      # 5 planes x 24 shifts
+    assert fused_kernel_vmem_bytes(128, 4, 2, g=2) == \
+        2 * fused_kernel_vmem_bytes(128, 4, g=2)
+    assert fused_kernel_vmem_bytes(128, 4, 2, g=2) <= VMEM_LIMIT_BYTES
+
+
 def test_fused_geometry_keeps_tpu_tiling():
     from repro.kernels.stencil3d import fused_geometry
 

@@ -17,9 +17,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.neighbors import boundary_face_table, neighbor_table
-from repro.kernels import backend
+from repro.kernels import backend, stencil3d
 from repro.kernels.ops import uniform_weights
-from repro.kernels.stencil3d import stencil_step_fused, stencil_sum_resident
+from repro.kernels.stencil3d import (VMEM_LIMIT_BYTES, fused_kernel_vmem_bytes,
+                                     stencil_step_fused, stencil_sum_resident)
 from repro.stencil import Gol3d, Gol3dConfig, ResidentPipeline
 
 HBM_BYTES = int(15.75 * 2 ** 30)   # what XLA lets one v5e program use
@@ -86,6 +87,52 @@ def test_fused_kernel_compiles(one_chip, compiled_path, rule, bc, M):
     fn = jax.jit(lambda store: stencil_step_fused(
         store, uniform_weights(g), nbr, bnd, g=g, S=S, rule=rule, bc=bc))
     _check(fn.lower(sds(shape)).compile())
+
+
+def _fused_fn(rule, M, S, g):
+    T = 128
+    nt = M // T
+    return jax.jit(lambda store: stencil_step_fused(
+        store, uniform_weights(g), neighbor_table("hilbert", nt), g=g, S=S,
+        rule=rule))
+
+
+def _fused_store(one_chip, rule, M):
+    T, nb = 128, (M // 128) ** 3
+    shape = (nb, T, T, T) if rule != "wave" else (2, nb, T, T, T)
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+
+def test_fused_ring_compiles_two_channels(one_chip, compiled_path):
+    """wave's two channels each take a tap-copy ring, within the limit."""
+    assert fused_kernel_vmem_bytes(128, 4, 2, g=1) <= VMEM_LIMIT_BYTES
+    _check(_fused_fn("wave", 512, 4, 1).lower(
+        _fused_store(one_chip, "wave", 512)).compile())
+
+
+def test_fused_ring_vmem_is_what_the_compiler_allocates(
+        one_chip, compiled_path, monkeypatch):
+    """g=2, S=2 carries the widest ring, 5 planes x 24 shifts (16 MiB of
+    the kernel's 26 MiB). The kernel compiles with its scoped limit set
+    1/32 above ``fused_kernel_vmem_bytes`` (Mosaic takes some of the
+    room it is given for itself) and is refused 1 MiB below it: the
+    model counts what the kernel allocates, the ring included."""
+    T, h, g = 128, 4, 2
+    model = fused_kernel_vmem_bytes(T, h, g=g)
+    assert model <= VMEM_LIMIT_BYTES
+    store = _fused_store(one_chip, "jacobi", 256)
+
+    def compile_under(limit):
+        monkeypatch.setattr(stencil3d, "VMEM_LIMIT_BYTES", limit)
+        stencil_step_fused.clear_cache()
+        return _fused_fn("jacobi", 256, 2, g).lower(store).compile()
+
+    try:
+        _check(compile_under(model + model // 32))
+        with pytest.raises(jax.errors.JaxRuntimeError, match="vmem"):
+            compile_under(model - 2 ** 20)
+    finally:
+        stencil_step_fused.clear_cache()
 
 
 def test_resident_pipeline_run_fn_compiles(one_chip, compiled_path):
