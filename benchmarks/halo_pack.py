@@ -8,8 +8,8 @@ one descriptor for kernels/sfc_gather.py.
 
 The ``exchange/`` rows sweep the *deep* exchange depth h = S·g of the
 communication-avoiding distributed pipeline (DESIGN.md §7): six width-h
-faces packed straight from the resident block store (the hybrid
-store_spec ordering), with the modelled ICI bytes per exchange and per
+faces cut from the resident block store by block slices, as the
+exchange packs them, with the modelled ICI bytes per exchange and per
 *timestep* from the shared accounting helpers — so the perf trajectory
 carries network traffic alongside the HBM numbers.
 """
@@ -24,10 +24,10 @@ import numpy as np
 
 from repro.core import (HILBERT, MORTON, NEUMANN0, ROW_MAJOR, apply_ordering,
                         blockize)
-from repro.core.layout import store_spec
 from repro.core.surfaces import PAPER_SURFACE_NAMES, run_stats
 from repro.kernels.ops import pack_surface
 from repro.stencil import exchange_bytes_per_step, exchange_items_per_exchange
+from repro.stencil.halo import _face_slab
 
 FACE_GROUPS = (("k0", "k1"), ("i0", "i1"), ("j0", "j1"))
 N_REPS = 20
@@ -78,16 +78,15 @@ def deep_rows(sizes=(32, 64), depths=(1, 2, 4), g=1, T=8):
     for M in sizes:
         cube = jnp.asarray(rng.random((M, M, M)).astype(np.float32))
         for kind in ("morton", "hilbert"):
-            hspec = store_spec(kind, T)
-            store = blockize(cube, T, kind=kind).reshape(-1)
+            store = blockize(cube, T, kind=kind)[None]
             for S in depths:
                 h = S * g
                 if h > T or T % h:
                     continue
 
                 @jax.jit
-                def pack_all(d, hspec=hspec, M=M, h=h):
-                    return [pack_surface(d, hspec, M, h, f)
+                def pack_all(d, kind=kind, h=h):
+                    return [_face_slab(d, kind, T, h, f)
                             for pair in FACE_GROUPS for f in pair]
 
                 jax.block_until_ready(pack_all(store))  # compile
@@ -120,16 +119,15 @@ def clamped_exchange_rows(sizes=(32, 64), depths=(1, 4), g=1, T=8,
     for M in sizes:
         cube = jnp.asarray(rng.random((M, M, M)).astype(np.float32))
         for kind in ("morton", "hilbert"):
-            hspec = store_spec(kind, T)
-            store = blockize(cube, T, kind=kind).reshape(-1)
+            store = blockize(cube, T, kind=kind)[None]
             for S in depths:
                 h = S * g
                 if h > T or T % h:
                     continue
 
                 @jax.jit
-                def pack_all(d, hspec=hspec, M=M, h=h):
-                    return [pack_surface(d, hspec, M, h, f)
+                def pack_all(d, kind=kind, h=h):
+                    return [_face_slab(d, kind, T, h, f)
                             for pair in FACE_GROUPS for f in pair]
 
                 jax.block_until_ready(pack_all(store))  # compile

@@ -12,7 +12,7 @@ from .pipeline import (  # noqa: F401
 from .domain import Decomposition3D, make_stencil_mesh, STENCIL_AXES  # noqa: F401
 from .halo import (  # noqa: F401
     exchange_shell, make_distributed_step, shard_boundary_flags, shard_state,
-    shard_substeps, stencil_block_kind, surface_slab_scatter, unshard_state,
+    shard_substeps, stencil_block_kind, unshard_state,
 )
 from .runner import (  # noqa: F401
     CheckpointedRun, RunHealthError, RunHooks, health_check,

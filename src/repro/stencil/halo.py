@@ -1,26 +1,28 @@
-"""Distributed halo exchange with SFC pack/unpack (paper §3.2/§4, on mesh).
+"""Distributed halo exchange from the SFC block store (paper §3.2/§4, on mesh).
 
-The paper's halo pattern — pack faces into contiguous buffers via
-precomputed index lists, exchange with neighbours, unpack — mapped to
-JAX: ``shard_map`` over a 3D device mesh, ``jax.lax.ppermute`` ring
-shifts per axis (axis-sequential, corner-correct).
+The paper's halo pattern — pack faces into contiguous buffers, exchange
+with neighbours, unpack — mapped to JAX: ``shard_map`` over a 3D device
+mesh, ``jax.lax.ppermute`` ring shifts per axis (axis-sequential,
+corner-correct).
 
 Communication-avoiding form (DESIGN.md §7): each shard keeps its state
 as the resident curve-ordered ``(nb, T, T, T)`` block store for the
-whole K-step loop — the store *is* path-ordered state under the hybrid
-ordering ``layout.store_spec(kind, T)``, so every face (not just the
-slab axis) packs straight from storage via ``ops.pack_surface``. One
-exchange moves *deep* faces of width ``h = S·g`` and funds S fused
-substeps (same window-shrink math as ``stencil_step_fused``): the
-received shell scatters into shell blocks appended after the core store
-(core/neighbors.extended_neighbor_table addresses them), and the fused
-kernel — or its jnp oracle — advances S whole timesteps per HBM
-round-trip with no per-step ``undo_ordering``/``apply_ordering`` and no
-canonical-cube materialisation, ever.
+whole K-step loop. The curve orders whole blocks, so every face of the
+shard is the outer h layers of the nt² blocks on that side: each face
+packs by static slices of those blocks, found through a block table
+read off ``layout.block_order`` (nt² entries, not a list of sites), and
+one transpose into its canonical slab. One exchange moves *deep* faces
+of width ``h = S·g`` and funds S fused substeps (same window-shrink math
+as ``stencil_step_fused``): the received shell scatters into shell
+blocks appended after the core store (core/neighbors.extended_neighbor_table
+addresses them), and the fused kernel — or its jnp oracle — advances S
+whole timesteps per HBM round-trip with no per-step
+``undo_ordering``/``apply_ordering`` and no canonical-cube
+materialisation, ever.
 
 Multi-field stores (DESIGN.md §9): a C-channel workload keeps its state
 as the stacked ``(C, nb, T, T, T)`` store. All C channels share one
-block permutation and one set of face index lists, so a deep exchange
+block permutation and one set of face block tables, so a deep exchange
 packs **every channel** into the same six messages — per-axis ICI
 extents simply gain the ×C factor — and the shell scatter/extended
 store carry the stacked axis through to the fused kernel unchanged.
@@ -46,45 +48,26 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core import OrderingSpec, path_positions, path_to_rmo, rmo_to_path
+from repro.core import OrderingSpec, path_to_rmo, rmo_to_path
 from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
                                  as_boundary, axes_periodic)
-from repro.core.layout import (apply_ordering, needs_element_perm,
-                               store_spec, undo_ordering)
+from repro.core.layout import (apply_ordering, block_order,
+                               needs_element_perm, store_spec, undo_ordering)
 from repro.core.neighbors import (block_kind_of, boundary_face_table,
                                   extended_neighbor_table, ring_perms,
                                   shell_block_count)
-from repro.core.surfaces import (face_coords, shell_slab_positions,
-                                 shell_slab_shapes)
-from repro.kernels import ops
+from repro.core.surfaces import shell_slab_positions, shell_slab_shapes
 from repro.kernels import ref as kref
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import get_rule
-from repro.kernels.stencil3d import stencil_step_fused
+from repro.kernels.stencil3d import SUBLANES, stencil_step_fused
 
 from .domain import STENCIL_AXES
 
-__all__ = ["surface_slab_scatter", "exchange_shell", "shard_substeps",
+__all__ = ["exchange_shell", "shard_substeps",
            "shard_boundary_flags", "make_distributed_step",
            "stencil_block_kind", "shard_state", "unshard_state",
            "to_store", "from_store"]
-
-
-@functools.lru_cache(maxsize=256)
-def surface_slab_scatter(spec: OrderingSpec, M: int, g: int, face: str) -> np.ndarray:
-    """Positions mapping a path-ordered face buffer into its (g,M,M)-like slab.
-
-    ``slab.ravel()[pos[t]] = buf[t]`` reconstructs the face in canonical
-    (row-major, face-local) layout. Works for any of the six faces and
-    any width ``g`` (the deep exchange passes h = S·g); the slab spans
-    the face's two free axes plus the g-width axis, in (k,i,j) order with
-    the face axis collapsed to width g.
-    """
-    # slab sites in slab row-major order; pack order is ascending path
-    p = path_positions(spec, *face_coords(face, M, g), M).ravel()
-    pos = np.argsort(p, kind="stable").astype(np.int32)
-    pos.setflags(write=False)
-    return pos
 
 
 def stencil_block_kind(spec: OrderingSpec) -> str:
@@ -95,25 +78,58 @@ def stencil_block_kind(spec: OrderingSpec) -> str:
     return kind if kind in ("morton", "hilbert") else "morton"
 
 
-@jax.named_scope("sfc.pack")
-def _pack_to_slab(store_flat, hspec, M, h, face, shape):
-    """Pack one deep face from the (C, nb·T³) store, canonical slab layout."""
-    buf = ops.pack_surface(store_flat, hspec, M, h, face)  # (C, L)
-    pos = surface_slab_scatter(hspec, M, h, face)
-    C = store_flat.shape[0]
-    return jnp.zeros((C, h * M * M), buf.dtype).at[:, pos].set(buf) \
-        .reshape((C,) + shape)
+@functools.lru_cache(maxsize=256)
+def _face_blocks(kind: str, nt: int, face: str) -> np.ndarray:
+    """The face's block table: ``(nt, nt)`` store positions of the blocks
+    on that side of the shard, indexed by their block coordinates along
+    the face's two free axes in (k, i, j) order."""
+    ax = "kij".index(face[0])
+    bo = block_order(kind, nt)
+    on = np.flatnonzero(bo[:, ax] == (0 if face[1] == "0" else nt - 1))
+    free = [d for d in range(3) if d != ax]
+    pos = np.empty((nt, nt), np.int64)
+    pos[bo[on, free[0]], bo[on, free[1]]] = on
+    pos.setflags(write=False)
+    return pos
 
 
-@jax.named_scope("sfc.unpack")
-def _unpack_recv(buf, hspec, M, h, face, shape):
-    """Scatter a received deep-face buffer (sender's pack order) into the
-    canonical slab — sender and receiver share the index lists, so the
-    receiver knows the order the remote pack produced."""
-    pos = surface_slab_scatter(hspec, M, h, face)
-    C = buf.shape[0]
-    return jnp.zeros((C, h * M * M), buf.dtype).at[:, pos].set(buf) \
-        .reshape((C,) + shape)
+def _face_slab(store: jnp.ndarray, kind: str, T: int, h: int,
+               face: str) -> jnp.ndarray:
+    """One deep face of the ``(C, nb, T, T, T)`` block store, as its
+    canonical slab: ``(C, h, M, M)``, ``(C, M, h, M)`` or ``(C, M, M, h)``.
+
+    The face is the outer h planes, rows or lanes (h ≤ T) of the nt²
+    whole blocks on that side; the face's block table says where the
+    ``kind`` curve put each, a static slice cuts its layers, and one
+    concatenate plus a transpose lays them out row-major over the face.
+    Each cut is ``SUBLANES`` layers deep at least (then trimmed to h):
+    a cut thinner than an (8, 128) tile makes XLA relayout the whole
+    store on the TPU to serve it.
+    """
+    C, nb = store.shape[:2]
+    nt = round(nb ** (1 / 3))
+    assert nt ** 3 == nb and store.shape[2:] == (T,) * 3 and h <= T, \
+        (store.shape, T, h)
+    ax = "kij".index(face[0])
+    depth = min(T, -(-h // SUBLANES) * SUBLANES)
+    lo = 0 if face[1] == "0" else T - depth
+    start, limit = [0] * 5, [C, 0, T, T, T]
+    start[2 + ax], limit[2 + ax] = lo, lo + depth
+    cuts = []
+    for t in _face_blocks(kind, nt, face).ravel():
+        start[1], limit[1] = int(t), int(t) + 1
+        cuts.append(jax.lax.slice(store, start, limit))
+    width = [T, T, T]
+    width[ax] = depth
+    x = jnp.concatenate(cuts, axis=1).reshape([C, nt, nt] + width)
+    free = [d for d in range(3) if d != ax]
+    perm = [0]
+    for d in range(3):
+        perm += [3 + d] if d == ax else [1 + free.index(d), 3 + d]
+    slab = x.transpose(perm).reshape(
+        [C] + [depth if d == ax else nt * T for d in range(3)])
+    off = 0 if face[1] == "0" else depth - h
+    return jax.lax.slice_in_dim(slab, off, off + h, axis=1 + ax)
 
 
 def _bc_face_fill(face: jnp.ndarray, axis: int, side: str,
@@ -142,17 +158,19 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
     """Deep (width-h) corner-correct shell exchange from the block store.
 
     ``store_flat`` is the shard's ``(nb·T³,)`` ravelled curve-ordered
-    block store — path-ordered state under ``store_spec(kind, T)``, so
-    *all six* faces pack via the paper's precomputed index lists
-    (ops.pack_surface), none from a materialised cube. A multi-field
-    shard passes the stacked ``(C, nb·T³)`` store: every channel packs
-    through the same index lists into the same six messages, so the
-    per-axis ICI volume simply gains the ×C factor (DESIGN.md §9) and
-    the returned slabs carry the leading channel axis. Axis-sequential
-    scheme: the k faces are the bare M² surfaces; the i faces carry the
-    k-received edges; the j faces carry both — after three ppermute
-    rounds the six returned slabs tile the shell of the (M+2h)³ extended
-    domain exactly (shapes: core/surfaces.shell_slab_shapes).
+    block store, viewed as its ``(nb, T, T, T)`` blocks: *all six* own
+    faces pack from it by static slices of the blocks on each side
+    (:func:`_face_slab`), none from a materialised cube and none through
+    per-site index lists. A multi-field shard passes the stacked
+    ``(C, nb·T³)`` store: every channel packs through the same block
+    tables into the same six messages, so the per-axis ICI volume simply
+    gains the ×C factor (DESIGN.md §9) and the returned slabs carry the
+    leading channel axis. Axis-sequential scheme: the k faces are the
+    bare M² surfaces; the i faces carry the k-received edges; the j faces
+    carry both — after three ppermute rounds the six returned slabs tile
+    the shell of the (M+2h)³ extended domain exactly (shapes:
+    core/surfaces.shell_slab_shapes). Every message is a canonical slab,
+    so a receiver uses it as it arrives.
 
     Per-axis ICI volume is C·2h·M², C·2h·(M+2h)·M, C·2h·(M+2h)² items —
     the ``exchange_items_per_exchange`` model in stencil/pipeline.py.
@@ -171,11 +189,12 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
     bc = as_boundary(bc)
     periodic = axes_periodic(bc)
     ax_bcs = bc.axes
-    hspec = store_spec(kind, T)
     squeeze = store_flat.ndim == 1
     if squeeze:
         store_flat = store_flat[None]
-    shp_k, _, shp_i, _, shp_j, _ = shell_slab_shapes(M, h)
+    store = store_flat.reshape(store_flat.shape[0], -1, T, T, T)
+    assert store.shape[1] == (M // T) ** 3, (store.shape, M)
+    _, _, shp_i, _, shp_j, _ = shell_slab_shapes(M, h)
 
     @jax.named_scope("sfc.pack")
     def _fill_edges(slab_lo, slab_hi, face_lo, face_hi, axis, ax_name):
@@ -190,29 +209,30 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
                             slab_hi)
         return slab_lo, slab_hi
 
-    # --- k axis: pack the deep slab faces, ring-shift, unpack
+    @jax.named_scope("sfc.unpack")
+    def _received(sl, *slabs):
+        """The strips of received slabs that the next axis forwards."""
+        return [s[sl] for s in slabs]
+
+    # --- k axis: the bare deep faces, ring-shifted as canonical slabs
     with jax.named_scope("sfc.pack"):
-        buf_k0 = ops.pack_surface(store_flat, hspec, M, h, "k0")
-        buf_k1 = ops.pack_surface(store_flat, hspec, M, h, "k1")
+        face_k0 = _face_slab(store, kind, T, h, "k0")
+        face_k1 = _face_slab(store, kind, T, h, "k1")
     fwd, bwd = ring_perms(jax.lax.psum(1, axis_names[0]), periodic=periodic[0])
-    recv_lo = jax.lax.ppermute(buf_k1, axis_names[0], fwd)  # prev's high face
-    recv_hi = jax.lax.ppermute(buf_k0, axis_names[0], bwd)  # next's low face
-    slab_k_lo = _unpack_recv(recv_lo, hspec, M, h, "k1", shp_k)
-    slab_k_hi = _unpack_recv(recv_hi, hspec, M, h, "k0", shp_k)
+    slab_k_lo = jax.lax.ppermute(face_k1, axis_names[0], fwd)  # prev's high face
+    slab_k_hi = jax.lax.ppermute(face_k0, axis_names[0], bwd)  # next's low face
     if not periodic[0]:
-        own_k0 = _pack_to_slab(store_flat, hspec, M, h, "k0", shp_k)
-        own_k1 = _pack_to_slab(store_flat, hspec, M, h, "k1", shp_k)
         slab_k_lo, slab_k_hi = _fill_edges(slab_k_lo, slab_k_hi,
-                                           own_k0, own_k1, 0, axis_names[0])
+                                           face_k0, face_k1, 0, axis_names[0])
 
     # --- i axis: core faces + k-received edges (corner-correct)
+    def _i_face(mine, sl):
+        k_lo, k_hi = _received((..., sl, slice(None)), slab_k_lo, slab_k_hi)
+        return jnp.concatenate([k_lo, mine, k_hi], axis=-3)
+
     with jax.named_scope("sfc.pack"):
-        my_i0 = _pack_to_slab(store_flat, hspec, M, h, "i0", (M, h, M))
-        my_i1 = _pack_to_slab(store_flat, hspec, M, h, "i1", (M, h, M))
-        face_i0 = jnp.concatenate(
-            [slab_k_lo[..., :h, :], my_i0, slab_k_hi[..., :h, :]], axis=-3)
-        face_i1 = jnp.concatenate(
-            [slab_k_lo[..., M - h:, :], my_i1, slab_k_hi[..., M - h:, :]], axis=-3)
+        face_i0 = _i_face(_face_slab(store, kind, T, h, "i0"), slice(0, h))
+        face_i1 = _i_face(_face_slab(store, kind, T, h, "i1"), slice(M - h, M))
     fwd, bwd = ring_perms(jax.lax.psum(1, axis_names[1]), periodic=periodic[1])
     slab_i_lo = jax.lax.ppermute(face_i1, axis_names[1], fwd)
     slab_i_hi = jax.lax.ppermute(face_i0, axis_names[1], bwd)
@@ -223,16 +243,14 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
 
     # --- j axis: core faces + both received edge sets
     def _j_face(mine, sl):
-        mid = jnp.concatenate(
-            [slab_k_lo[..., sl], mine, slab_k_hi[..., sl]], axis=-3)
-        return jnp.concatenate(
-            [slab_i_lo[..., sl], mid, slab_i_hi[..., sl]], axis=-2)
+        k_lo, k_hi, i_lo, i_hi = _received(
+            (..., sl), slab_k_lo, slab_k_hi, slab_i_lo, slab_i_hi)
+        mid = jnp.concatenate([k_lo, mine, k_hi], axis=-3)
+        return jnp.concatenate([i_lo, mid, i_hi], axis=-2)
 
     with jax.named_scope("sfc.pack"):
-        my_j0 = _pack_to_slab(store_flat, hspec, M, h, "j0", (M, M, h))
-        my_j1 = _pack_to_slab(store_flat, hspec, M, h, "j1", (M, M, h))
-        face_j0 = _j_face(my_j0, slice(0, h))
-        face_j1 = _j_face(my_j1, slice(M - h, M))
+        face_j0 = _j_face(_face_slab(store, kind, T, h, "j0"), slice(0, h))
+        face_j1 = _j_face(_face_slab(store, kind, T, h, "j1"), slice(M - h, M))
     fwd, bwd = ring_perms(jax.lax.psum(1, axis_names[2]), periodic=periodic[2])
     slab_j_lo = jax.lax.ppermute(face_j1, axis_names[2], fwd)
     slab_j_hi = jax.lax.ppermute(face_j0, axis_names[2], bwd)
@@ -390,8 +408,8 @@ def make_distributed_step(mesh: jax.sharding.Mesh, spec: OrderingSpec,
     The legacy per-step reference for DistributedPipeline (which runs the
     same :func:`shard_substeps` round at depth S): no per-step full-cube
     repack — the state converts to the block store and back (one
-    permutation gather each way), all six faces pack from the store via
-    the index lists, and the compute is the fused S=1 path. Bit-identical
+    permutation gather each way), all six faces pack from the store by
+    block slices, and the compute is the fused S=1 path. Bit-identical
     to the pipeline at every S (f32), and to the pre-rebuild slice-loop
     reference for integer-valued rules (gol).
     """

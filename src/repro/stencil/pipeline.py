@@ -565,7 +565,7 @@ class DistributedPipeline:
     ``(C, nb, T, T, T)`` for a multi-field rule (DESIGN.md §9) — for the
     whole K-step loop (one permutation gather in, one out — never per
     step), packs *deep* width-S·g faces of every channel straight from
-    that store via the precomputed index lists, and advances S whole
+    that store by static slices of its face blocks, and advances S whole
     timesteps per exchange through the fused kernel path
     (halo.shard_substeps). Bit-identical (f32) to S sequential
     :func:`repro.stencil.halo.make_distributed_step` steps.

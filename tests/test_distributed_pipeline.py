@@ -2,12 +2,13 @@
 
 Three layers of coverage:
 
-- pure-local tests (any device count): deep pack/scatter round-trips at
-  h = S·g ∈ {1,2,3,4}, shell scatter completeness, extended neighbour
+- pure-local tests (any device count): deep face slabs cut from the
+  block store at h = S·g ∈ {1,2,3,4}, shell scatter completeness, extended neighbour
   tables, the exchange-aware bytes model and plan();
-- a 1×1×1-mesh test (any device count): the full exchange+compute round
-  with every ppermute a self-send — periodic wrap, checked against the
-  global oracle in-process;
+- 1×1×1-mesh tests (any device count): the full exchange+compute round
+  with every ppermute a self-send — periodic wrap and clamped fills,
+  checked against the padded cube and the global oracle in-process, and
+  the compiled exchange's face packing free of element gathers;
 - the acceptance matrix on a ≥8-device mesh: DistributedPipeline with S
   substeps per exchange vs S sequential make_distributed_step steps,
   bit-identical, for all four orderings × {gol, jacobi} × S ∈ {1, 2, 4}.
@@ -32,16 +33,15 @@ from repro.core.neighbors import (SELF_COL, extended_neighbor_table,
                                   neighbor_table, shell_block_count,
                                   shell_block_index)
 from repro.core.surfaces import shell_slab_positions, shell_slab_shapes
+from repro.core.boundary import NEUMANN0, dirichlet, pad_cube
 from repro.kernels import ref as kref
-from repro.kernels.ops import pack_surface
 from repro.stencil import (DistributedPipeline, distributed_bytes_per_step,
                            exchange_bytes_per_step,
                            exchange_items_per_exchange, fused_vmem_bytes,
                            make_distributed_step, make_stencil_mesh,
                            resident_bytes_per_step, shard_state,
-                           surface_slab_scatter, unshard_state,
-                           VMEM_BUDGET_BYTES)
-from repro.stencil.halo import exchange_shell, shard_substeps
+                           unshard_state, VMEM_BUDGET_BYTES)
+from repro.stencil.halo import _face_slab, exchange_shell, shard_substeps
 
 rng = np.random.default_rng(7)
 
@@ -57,43 +57,64 @@ FACE_SHAPES = {
 }
 
 
-# --------------------------------------------- deep pack/scatter (satellite)
+# ------------------------------------------ deep face slabs (satellite)
+def _store(cube, kind, T):
+    """(C, M, M, M) cubes -> the (C, nb, T, T, T) block store."""
+    from repro.core import blockize_fields
+
+    return blockize_fields(jnp.asarray(cube), T, kind=kind)
+
+
 @pytest.mark.parametrize("spec", ORDERINGS, ids=lambda s: s.name)
 @pytest.mark.parametrize("h", [1, 2, 3, 4])
 def test_deep_pack_scatter_roundtrip(spec, h):
-    """pack_surface + surface_slab_scatter at width h = S·g reconstruct
-    the canonical face slice exactly, for every face and ordering."""
-    M = 8
-    cube = rng.normal(size=(M, M, M)).astype(np.float32)
-    path = apply_ordering(jnp.asarray(cube), spec)
+    """A store whose blocks follow the ordering's curve packs every deep
+    face of width h = S·g into the canonical face slice exactly."""
+    M, T = 8, 4
+    cube = rng.normal(size=(1, M, M, M)).astype(np.float32)
+    store = _store(cube, spec.kind, T)
     for face, take in FACE_SLICES.items():
-        buf = pack_surface(path, spec, M, h, face)
-        pos = surface_slab_scatter(spec, M, h, face)
-        shape = FACE_SHAPES[face[0]](M, h)
-        slab = np.zeros(h * M * M, np.float32)
-        slab[pos] = np.asarray(buf)
-        np.testing.assert_array_equal(slab.reshape(shape),
-                                      take(cube, h), err_msg=face)
+        slab = _face_slab(store, spec.kind, T, h, face)
+        assert slab.shape == (1,) + FACE_SHAPES[face[0]](M, h), face
+        np.testing.assert_array_equal(np.asarray(slab)[0],
+                                      take(cube[0], h), err_msg=face)
 
 
 @pytest.mark.parametrize("kind", ["morton", "hilbert", "row_major"])
 def test_deep_pack_from_block_store(kind):
     """The block store is path-ordered state under store_spec(kind, T):
-    deep faces pack straight from the ravelled store."""
+    deep faces pack straight from the ravelled store, viewed as blocks."""
     from repro.core import blockize
 
     M, T, h = 16, 8, 4
     cube = rng.normal(size=(M, M, M)).astype(np.float32)
     store = blockize(jnp.asarray(cube), T, kind=kind)
     hspec = store_spec(kind, T)
+    flat = np.asarray(store).ravel()
     np.testing.assert_array_equal(
-        np.asarray(store).ravel(),
-        np.asarray(apply_ordering(jnp.asarray(cube), hspec)))
-    buf = pack_surface(store.reshape(-1), hspec, M, h, "k1")
-    pos = surface_slab_scatter(hspec, M, h, "k1")
-    slab = np.zeros(h * M * M, np.float32)
-    slab[pos] = np.asarray(buf)
-    np.testing.assert_array_equal(slab.reshape(h, M, M), cube[-h:])
+        flat, np.asarray(apply_ordering(jnp.asarray(cube), hspec)))
+    blocks = jnp.asarray(flat).reshape(1, -1, T, T, T)
+    for face, take in FACE_SLICES.items():
+        np.testing.assert_array_equal(
+            np.asarray(_face_slab(blocks, kind, T, h, face))[0],
+            take(cube, h), err_msg=face)
+
+
+@pytest.mark.parametrize("T", [4, 8])
+@pytest.mark.parametrize("h", [1, 2, 4])
+@pytest.mark.parametrize("C", [1, 2])
+@pytest.mark.parametrize("kind", ["row_major", "column_major", "morton",
+                                  "hilbert"])
+def test_face_slab_matches_canonical_faces(kind, C, h, T):
+    """Every face of a C-channel store, cut by block slices, equals the
+    canonical face slice of each channel's cube."""
+    M = 16
+    cube = rng.normal(size=(C, M, M, M)).astype(np.float32)
+    store = _store(cube, kind, T)
+    for face, take in FACE_SLICES.items():
+        got = np.asarray(_face_slab(store, kind, T, h, face))
+        want = np.stack([take(c, h) for c in cube])
+        np.testing.assert_array_equal(got, want, err_msg=face)
 
 
 def test_shell_slab_positions_cover_shell():
@@ -166,6 +187,52 @@ def test_exchange_shell_self_wrap_matches_pad():
     np.testing.assert_array_equal(i_hi, xp[:, e - h:, h:h + M])
     np.testing.assert_array_equal(j_lo, xp[:, :, :h])
     np.testing.assert_array_equal(j_hi, xp[:, :, e - h:])
+
+
+def _self_exchange(kind, M, T, h, bc):
+    """exchange_shell on a 1×1×1 mesh, jit'd: every ppermute a self-send."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    return jax.jit(shard_map(
+        lambda st: exchange_shell(st.reshape(st.shape[0], -1), kind, M, T,
+                                  h, bc=bc),
+        mesh=make_stencil_mesh((1, 1, 1)), in_specs=P(), out_specs=P(),
+        check_vma=False))
+
+
+@pytest.mark.parametrize("kind", ["morton", "hilbert"])
+@pytest.mark.parametrize("bc", [dirichlet(0.5), NEUMANN0], ids=lambda b: b.kind)
+def test_exchange_shell_self_clamped_matches_pad(bc, kind):
+    """On a 1-device mesh under a clamped contract no ppermute pair
+    exists, so every slab is the boundary fill: the six slabs of a
+    two-channel store equal each channel's cube padded by ``bc``."""
+    M, T, h, C = 16, 8, 3, 2
+    cube = rng.normal(size=(C, M, M, M)).astype(np.float32)
+    slabs = _self_exchange(kind, M, T, h, bc)(_store(cube, kind, T))
+    xp = np.stack([np.asarray(pad_cube(jnp.asarray(c), h, bc)) for c in cube])
+    e, core = M + 2 * h, slice(h, h + M)
+    want = (xp[:, :h, core, core], xp[:, e - h:, core, core],
+            xp[:, :, :h, core], xp[:, :, e - h:, core],
+            xp[:, :, :, :h], xp[:, :, :, e - h:])
+    for got, w in zip(slabs, want):
+        np.testing.assert_array_equal(np.asarray(got), w)
+
+
+@pytest.mark.parametrize("bc", ["periodic", "neumann0"])
+def test_exchange_packs_without_element_gathers(bc):
+    """The compiled exchange cuts its faces by block slices: no gather or
+    scatter op carries a pack scope (the index-list pack was an element
+    gather of every face site plus a scatter into its slab)."""
+    import re
+
+    M, T, h = 16, 4, 2
+    store = jax.ShapeDtypeStruct((1, (M // T) ** 3, T, T, T), jnp.float32)
+    text = _self_exchange("hilbert", M, T, h, bc).lower(store).compile().as_text()
+    ops = [line for line in text.splitlines()
+           if re.search(r"= \S+ (gather|scatter)\(", line)]
+    assert not [op for op in ops if re.search(r'op_name="[^"]*sfc\.(un)?pack', op)]
+    assert "sfc.pack" in text
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
