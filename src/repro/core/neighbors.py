@@ -144,16 +144,28 @@ def shell_block_index(nt: int) -> np.ndarray:
     The distributed pipeline (stencil/halo.py) appends the exchanged halo
     as *shell blocks* after the nt³ core store: a block at extended
     coords ``(bk, bi, bj) ∈ [-1, nt]³`` outside the core gets id
-    ``shell_block_index(nt)[bk+1, bi+1, bj+1]`` (row-major enumeration of
-    the shell), and lives at store row ``nt³ + id``. Core coords map to
-    -1 — core rows are addressed by the block curve's own path positions.
+    ``shell_block_index(nt)[bk+1, bi+1, bj+1]`` and lives at store row
+    ``nt³ + id``. Core coords map to -1 — core rows are addressed by the
+    block curve's own path positions.
+
+    The ids run slab by slab, in core/surfaces.shell_slab_shapes' order
+    (k-lo, k-hi, i-lo, i-hi, j-lo, j-hi), and row-major over each slab's
+    own block grid: ``(bi, bj) ∈ [0, nt)²`` for k, ``(bk, bj) ∈ [-1, nt] ×
+    [0, nt)`` for i, ``(bk, bi) ∈ [-1, nt]²`` for j. So each received slab
+    becomes a contiguous run of shell blocks by a pad, a reshape and a
+    transpose (stencil/halo._shell_blocks).
     """
     e = nt + 2
-    kk, ii, jj = np.meshgrid(*(np.arange(e),) * 3, indexing="ij")
-    core = ((kk >= 1) & (kk <= nt) & (ii >= 1) & (ii <= nt)
-            & (jj >= 1) & (jj <= nt))
+    core, ext = slice(1, nt + 1), slice(None)
     idx = np.full((e, e, e), -1, dtype=np.int32)
-    idx[~core] = np.arange(shell_block_count(nt), dtype=np.int32)
+    n = 0
+    for region in ((0, core, core), (e - 1, core, core),
+                   (ext, 0, core), (ext, e - 1, core),
+                   (ext, ext, 0), (ext, ext, e - 1)):
+        grid = idx[region]  # a view: the slab's 2-D block grid
+        grid[...] = np.arange(n, n + grid.size).reshape(grid.shape)
+        n += grid.size
+    assert n == shell_block_count(nt), (n, nt)
     idx.setflags(write=False)
     return idx
 

@@ -20,7 +20,7 @@ from .orderings import OrderingSpec, path_positions
 
 __all__ = ["FACES", "PAPER_SURFACE_NAMES", "face_coords", "surface_path_indices",
            "run_lengths", "RunStats", "run_stats", "surface_runs",
-           "shell_slab_shapes", "shell_slab_positions"]
+           "shell_slab_shapes"]
 
 FACES = ("k0", "k1", "i0", "i1", "j0", "j1")
 
@@ -101,49 +101,6 @@ def shell_slab_shapes(M: int, h: int) -> tuple[tuple[int, int, int], ...]:
     """
     e = M + 2 * h
     return ((h, M, M), (h, M, M), (e, h, M), (e, h, M), (e, e, h), (e, e, h))
-
-
-@functools.lru_cache(maxsize=128)
-def shell_slab_positions(nt: int, T: int, h: int) -> np.ndarray:
-    """Scatter positions of the six shell slabs into the shell block store.
-
-    The distributed pipeline holds the exchanged halo as *shell blocks*
-    appended after the core store (core/neighbors.shell_block_index):
-    ``shell.ravel()[pos] = concat(slab.ravel() for six slabs)`` fills an
-    ``(shell_block_count(nt), T, T, T)`` array so that the fused kernel's
-    neighbour-slice addressing (kernels/stencil3d._piece_specs) reads the
-    halo exactly where a periodic in-store neighbour would hold it: a
-    low-side shell block carries its data in its *last* h-slab, a
-    high-side one in its first. Slab order matches
-    :func:`shell_slab_shapes`; h ≤ T.
-    """
-    from .neighbors import shell_block_index
-
-    assert h <= T, (h, T)
-    M = nt * T
-    sid = shell_block_index(nt)
-
-    def _axis(e):
-        # extended-domain coord e ∈ [-h, M+h) -> (block coord, in-block offset)
-        blk = np.where(e < 0, -1, np.where(e >= M, nt, e // T))
-        off = np.where(e < 0, T + e, np.where(e >= M, e - M, e % T))
-        return blk, off
-
-    lo, hi = np.arange(-h, 0), np.arange(M, M + h)
-    core, ext = np.arange(M), np.arange(-h, M + h)
-    regions = ((lo, core, core), (hi, core, core),
-               (ext, lo, core), (ext, hi, core),
-               (ext, ext, lo), (ext, ext, hi))
-    parts = []
-    for kr, ir, jr in regions:
-        ek, ei, ej = np.meshgrid(kr, ir, jr, indexing="ij")
-        (bk, ok), (bi, oi), (bj, oj) = _axis(ek), _axis(ei), _axis(ej)
-        s = sid[bk + 1, bi + 1, bj + 1]
-        parts.append((s.astype(np.int64) * T ** 3
-                      + (ok * T + oi) * T + oj).ravel())
-    pos = np.concatenate(parts).astype(np.int32)
-    pos.setflags(write=False)
-    return pos
 
 
 def surface_runs(spec: OrderingSpec, M: int, g: int, face: str):

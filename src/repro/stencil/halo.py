@@ -13,18 +13,18 @@ packs by static slices of those blocks, found through a block table
 read off ``layout.block_order`` (nt² entries, not a list of sites), and
 one transpose into its canonical slab. One exchange moves *deep* faces
 of width ``h = S·g`` and funds S fused substeps (same window-shrink math
-as ``stencil_step_fused``): the received shell scatters into shell
-blocks appended after the core store (core/neighbors.extended_neighbor_table
-addresses them), and the fused kernel — or its jnp oracle — advances S
-whole timesteps per HBM round-trip with no per-step
-``undo_ordering``/``apply_ordering`` and no canonical-cube
-materialisation, ever.
+as ``stencil_step_fused``): each received slab becomes its own run of
+shell blocks, appended after the core store, by a pad, a reshape and a
+transpose (core/neighbors.extended_neighbor_table addresses them), and
+the fused kernel — or its jnp oracle — advances S whole timesteps per
+HBM round-trip with no per-step ``undo_ordering``/``apply_ordering``
+and no canonical-cube materialisation, ever.
 
 Multi-field stores (DESIGN.md §9): a C-channel workload keeps its state
 as the stacked ``(C, nb, T, T, T)`` store. All C channels share one
 block permutation and one set of face block tables, so a deep exchange
 packs **every channel** into the same six messages — per-axis ICI
-extents simply gain the ×C factor — and the shell scatter/extended
+extents simply gain the ×C factor — and the shell blocks/extended
 store carry the stacked axis through to the fused kernel unchanged.
 
 On a TPU torus with Hilbert device ordering (launch/mesh.py) the six
@@ -54,9 +54,8 @@ from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
 from repro.core.layout import (apply_ordering, block_order,
                                needs_element_perm, store_spec, undo_ordering)
 from repro.core.neighbors import (block_kind_of, boundary_face_table,
-                                  extended_neighbor_table, ring_perms,
-                                  shell_block_count)
-from repro.core.surfaces import shell_slab_positions, shell_slab_shapes
+                                  extended_neighbor_table, ring_perms)
+from repro.core.surfaces import FACES, shell_slab_shapes
 from repro.kernels import ref as kref
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import get_rule
@@ -263,6 +262,45 @@ def exchange_shell(store_flat: jnp.ndarray, kind: str, M: int, T: int,
     return tuple(s[0] for s in slabs) if squeeze else slabs
 
 
+def _shell_blocks(slab: jnp.ndarray, nt: int, T: int, h: int,
+                  face: str) -> jnp.ndarray:
+    """One received shell slab as its own run of shell blocks.
+
+    ``slab`` is the ``face`` side's canonical slab from
+    :func:`exchange_shell` (any leading axes, such as the channel axis,
+    ride along); the result is ``(..., n, T, T, T)``, the slab's n shell
+    blocks in core/neighbors.shell_block_index order. The slab is thin
+    (h) along its own axis, extended (M+2h) along the axes before it and
+    core (M) along those after. While it is thin, its extended axes are
+    padded by T−h zeros on both sides to whole blocks, every axis but the
+    thin one splits into (blocks, T), and one transpose brings the block
+    indices to the front. Only then is the thin axis padded to T, away
+    from the core (a low-side block holds its data in its last h layers,
+    a high-side one in its first): that pad is the one large write, and
+    XLA fuses it into the concatenate that builds the extended store. No
+    site is scattered: this is :func:`_face_slab` run backwards.
+    """
+    lead, sp = slab.shape[:-3], slab.shape[-3:]
+    ax = "kij".index(face[0])
+    assert sp[ax] == h <= T and sp[ax + 1:] == (nt * T,) * (2 - ax), (sp, face)
+    x = jnp.pad(slab.reshape((-1,) + sp),
+                [(0, 0)] + [(T - h, T - h)] * ax + [(0, 0)] * (3 - ax))
+    shape, blocks, inner = [x.shape[0]], [], []
+    for d, n in enumerate(x.shape[1:]):
+        if d == ax:
+            inner.append(len(shape))
+            shape.append(n)
+        else:
+            blocks.append(len(shape))
+            inner.append(len(shape) + 1)
+            shape += [n // T, T]
+    x = x.reshape(shape).transpose([0] + blocks + inner)
+    x = x.reshape(lead + (-1,) + x.shape[-3:])
+    thin = (T - h, 0) if face[1] == "0" else (0, T - h)
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 3)
+                   + [thin if d == ax else (0, 0) for d in range(3)])
+
+
 def shard_boundary_flags(kind: str, nt: int,
                          axis_names=STENCIL_AXES) -> jnp.ndarray:
     """(nb, 6) clamped-domain-face flags for this shard's blocks.
@@ -293,12 +331,13 @@ def shard_substeps(store: jnp.ndarray, *, kind: str, M: int, g: int, S: int,
     store: (nb, T, T, T) curve-ordered local block store (shard_map
     body), or the stacked multi-field ``(C, nb, T, T, T)`` store when
     the rule declares C > 1 (DESIGN.md §9). Exchanges width S·g once —
-    all C channels in the same six messages — scatters the shell into
-    shell blocks appended after the core, and runs S whole timesteps
-    through ``stencil_step_fused`` (or its jnp oracle) with the extended
-    neighbour table — the distributed counterpart of one
-    ResidentPipeline launch. S sequential S=1 calls are bit-identical
-    (f32) to one S-deep call, same argument as the fused kernel.
+    all C channels in the same six messages — places each received slab
+    in its own shell blocks (:func:`_shell_blocks`), appended after the
+    core, and runs S whole timesteps through ``stencil_step_fused`` (or
+    its jnp oracle) with the extended neighbour table — the distributed
+    counterpart of one ResidentPipeline launch. S sequential S=1 calls
+    are bit-identical (f32) to one S-deep call, same argument as the
+    fused kernel.
 
     On clamped runs (``bc``, core.boundary — uniform or per-axis mixed)
     the exchange fills mesh-edge shell blocks with boundary values
@@ -315,19 +354,10 @@ def shard_substeps(store: jnp.ndarray, *, kind: str, M: int, g: int, S: int,
     h = S * g
     flat = store.reshape(store.shape[0], -1) if multi else store.reshape(-1)
     slabs = exchange_shell(flat, kind, M, T, h, axis_names, bc=bc)
-    pos = shell_slab_positions(nt, T, h)
     with jax.named_scope("sfc.shell"):
-        if multi:
-            C = store.shape[0]
-            vals = jnp.concatenate([s.reshape(C, -1) for s in slabs], axis=1)
-            shell = jnp.zeros((C, shell_block_count(nt) * T ** 3), store.dtype
-                              ).at[:, pos].set(vals).reshape(C, -1, T, T, T)
-            ext = jnp.concatenate([store, shell], axis=1)
-        else:
-            vals = jnp.concatenate([s.reshape(-1) for s in slabs])
-            shell = jnp.zeros((shell_block_count(nt) * T ** 3,), store.dtype
-                              ).at[pos].set(vals).reshape(-1, T, T, T)
-            ext = jnp.concatenate([store, shell], axis=0)
+        placed = [_shell_blocks(s, nt, T, h, face)
+                  for s, face in zip(slabs, FACES)]
+        ext = jnp.concatenate([store, *placed], axis=-4)
     nbr = extended_neighbor_table(kind, nt)
     bnd = shard_boundary_flags(kind, nt, axis_names) if bc.clamped else None
     w = uniform_weights(g)

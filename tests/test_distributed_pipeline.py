@@ -3,12 +3,13 @@
 Three layers of coverage:
 
 - pure-local tests (any device count): deep face slabs cut from the
-  block store at h = S·g ∈ {1,2,3,4}, shell scatter completeness, extended neighbour
-  tables, the exchange-aware bytes model and plan();
+  block store at h = S·g ∈ {1,2,3,4}, slab-by-slab shell block placement,
+  extended neighbour tables, the exchange-aware bytes model and plan();
 - 1×1×1-mesh tests (any device count): the full exchange+compute round
   with every ppermute a self-send — periodic wrap and clamped fills,
   checked against the padded cube and the global oracle in-process, and
-  the compiled exchange's face packing free of element gathers;
+  the compiled round free of element gathers (face packing) and of
+  scatters and sorts (shell placement);
 - the acceptance matrix on a ≥8-device mesh: DistributedPipeline with S
   substeps per exchange vs S sequential make_distributed_step steps,
   bit-identical, for all four orderings × {gol, jacobi} × S ∈ {1, 2, 4}.
@@ -32,7 +33,7 @@ from repro.core.layout import store_spec
 from repro.core.neighbors import (SELF_COL, extended_neighbor_table,
                                   neighbor_table, shell_block_count,
                                   shell_block_index)
-from repro.core.surfaces import shell_slab_positions, shell_slab_shapes
+from repro.core.surfaces import FACES, shell_slab_shapes
 from repro.core.boundary import NEUMANN0, dirichlet, pad_cube
 from repro.kernels import ref as kref
 from repro.stencil import (DistributedPipeline, distributed_bytes_per_step,
@@ -41,7 +42,8 @@ from repro.stencil import (DistributedPipeline, distributed_bytes_per_step,
                            make_distributed_step, make_stencil_mesh,
                            resident_bytes_per_step, shard_state,
                            unshard_state, VMEM_BUDGET_BYTES)
-from repro.stencil.halo import _face_slab, exchange_shell, shard_substeps
+from repro.stencil.halo import (_face_slab, _shell_blocks, exchange_shell,
+                                shard_substeps)
 
 rng = np.random.default_rng(7)
 
@@ -117,17 +119,61 @@ def test_face_slab_matches_canonical_faces(kind, C, h, T):
         np.testing.assert_array_equal(got, want, err_msg=face)
 
 
-def test_shell_slab_positions_cover_shell():
-    """The six slab scatters tile the shell skin disjointly, and each
-    position lands in the h-deep skin a fused-kernel piece spec reads."""
-    nt, T, h = 2, 8, 3
-    M = nt * T
-    pos = shell_slab_positions(nt, T, h)
-    assert pos.size == (M + 2 * h) ** 3 - M ** 3
-    assert pos.size == sum(int(np.prod(s)) for s in shell_slab_shapes(M, h))
-    assert np.unique(pos).size == pos.size
-    assert pos.min() >= 0
-    assert pos.max() < shell_block_count(nt) * T ** 3
+def _slab_regions(nt):
+    """Each shell slab's block grid inside the (nt+2)³ extended block
+    grid, in core/surfaces.shell_slab_shapes' order."""
+    e = nt + 2
+    core, ext = slice(1, nt + 1), slice(None)
+    return ((0, core, core), (e - 1, core, core), (ext, 0, core),
+            (ext, e - 1, core), (ext, ext, 0), (ext, ext, e - 1))
+
+
+@pytest.mark.parametrize("nt", [1, 2, 3, 4])
+def test_shell_block_index_runs_slab_by_slab(nt):
+    """The shell ids number each slab's block grid row-major, one
+    contiguous run per slab in slab order, every shell id once; core
+    coords map to -1."""
+    sid = shell_block_index(nt)
+    inner = sid[1:nt + 1, 1:nt + 1, 1:nt + 1]
+    np.testing.assert_array_equal(inner, -1)
+    n = 0
+    for region in _slab_regions(nt):
+        grid = sid[region]
+        np.testing.assert_array_equal(
+            grid, np.arange(n, n + grid.size).reshape(grid.shape))
+        n += grid.size
+    assert n == shell_block_count(nt)
+    np.testing.assert_array_equal(np.sort(sid[sid >= 0]), np.arange(n))
+
+
+@pytest.mark.parametrize("C", [1, 2])
+@pytest.mark.parametrize("nt,T,h", [(2, 8, 3), (4, 8, 4), (3, 4, 2)])
+def test_shell_blocks_hold_skin(nt, T, h, C):
+    """Placed slab by slab, every shell block holds the wrap-padded
+    cube's sites at its extended coords inside the h-deep skin, and
+    zeros outside it (a C=1 slab without its channel axis, too)."""
+    M, e = nt * T, nt + 2
+    cube = rng.normal(size=(C, M, M, M)).astype(np.float32)
+    xp = np.pad(cube, [(0, 0)] + [(h, h)] * 3, mode="wrap")
+    E, core = M + 2 * h, slice(h, h + M)
+    slabs = (xp[:, :h, core, core], xp[:, E - h:, core, core],
+             xp[:, :, :h, core], xp[:, :, E - h:, core],
+             xp[:, :, :, :h], xp[:, :, :, E - h:])
+    placed = [_shell_blocks(jnp.asarray(s if C > 1 else s[0]), nt, T, h, f)
+              for s, f in zip(slabs, FACES)]
+    shell = np.concatenate([np.asarray(p) for p in placed], axis=-4)
+    shell = shell.reshape(C, -1, T, T, T)
+    assert shell.shape[1] == shell_block_count(nt)
+    # the oracle: the skin inside whole blocks of the (nt+2)³ block grid
+    whole = np.zeros((C,) + (e * T,) * 3, np.float32)
+    skin = slice(T - h, T + M + h)
+    whole[:, skin, skin, skin] = xp
+    sid = shell_block_index(nt)
+    for bk, bi, bj in zip(*np.nonzero(sid >= 0)):
+        want = whole[:, bk * T:(bk + 1) * T, bi * T:(bi + 1) * T,
+                     bj * T:(bj + 1) * T]
+        np.testing.assert_array_equal(shell[:, sid[bk, bi, bj]], want,
+                                      err_msg=str((bk, bi, bj)))
 
 
 def test_extended_neighbor_table_core_and_shell():
@@ -233,6 +279,31 @@ def test_exchange_packs_without_element_gathers(bc):
            if re.search(r"= \S+ (gather|scatter)\(", line)]
     assert not [op for op in ops if re.search(r'op_name="[^"]*sfc\.(un)?pack', op)]
     assert "sfc.pack" in text
+
+
+@pytest.mark.parametrize("bc", ["periodic", "neumann0"])
+def test_shell_places_without_scatter_or_sort(bc):
+    """The compiled deep round places the received slabs by pads,
+    reshapes and transposes: no scatter or sort op, and no int32 constant
+    of one entry per shell site (the site-list placement scattered
+    through such a list, behind a sort of it on the TPU)."""
+    import re
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    M, T, g, S = 16, 4, 1, 2
+    skin = (M + 2 * S * g) ** 3 - M ** 3
+    fn = jax.jit(shard_map(
+        lambda st: shard_substeps(st, kind="hilbert", M=M, g=g, S=S,
+                                  rule="jacobi", bc=bc),
+        mesh=make_stencil_mesh((1, 1, 1)), in_specs=P(), out_specs=P(),
+        check_vma=False))
+    store = jax.ShapeDtypeStruct(((M // T) ** 3, T, T, T), jnp.float32)
+    lowered = fn.lower(store)
+    text = lowered.compile().as_text()
+    assert not re.findall(r"= \S+ (?:scatter|sort)\(", text)
+    assert "sfc.shell" in text
+    assert f"tensor<{skin}xi32>" not in lowered.as_text()
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
