@@ -27,3 +27,8 @@ def rows(M: int = 64, g: int = 1, b: int = 64, c: int = 512):
                 f"fig16_19/misses_M{M}_{spec.name}_{PAPER_SURFACE_NAMES[face]}",
                 dt, f"misses={m}"))
     return out
+
+
+if __name__ == "__main__":
+    for name, us, derived in rows():
+        print(f"{name},{us:.1f},{derived}")

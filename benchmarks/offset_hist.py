@@ -36,3 +36,8 @@ def rows():
                     f"n_distinct={s.n_distinct};mean_abs={s.mean_abs:.1f};"
                     f"frac_line64={s.frac_within_line:.3f}"))
     return out
+
+
+if __name__ == "__main__":
+    for name, us, derived in rows():
+        print(f"{name},{us:.1f},{derived}")

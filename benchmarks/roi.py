@@ -10,8 +10,7 @@ locality signal — the number of separate contiguous reads a storage
 tier must issue. The ROI suite is aligned power-of-two boxes, where
 hilbert/morton collapse whole octree subtrees into single ranges:
 hilbert is strictly below row-major on every row (asserted in
-tests/test_serve_roi.py, pinned exactly in CI via
-``benchmarks/diff.py --keys-threshold 0``).
+tests/test_serve_roi.py).
 """
 
 from __future__ import annotations

@@ -3,9 +3,9 @@
 Part 1: the resident-block pipeline — blockize once,
 run K steps entirely in curve order with in-kernel halo streaming and
 S-deep temporal blocking (stencil/pipeline.py; S substeps per HBM
-round-trip), verify bit-identity against the per-step repack pipeline,
-and print the modelled per-substep HBM bytes of repack / unfused /
-fused forms plus the (T, S) the plan() autotuner picks.
+round-trip), verify it against the ordering-independent reference run
+(kernels/ref.gol3d_step_ref), and print the modelled per-substep HBM
+bytes plus the (T, S) the plan() autotuner picks.
 
 Part 2: on eight devices (host devices on the CPU, forced before JAX
 is imported), decomposes a 32³ cube onto a 2×2×2 mesh and runs 10
@@ -48,20 +48,15 @@ def resident_demo(M=32, g=1, T=8, steps=10, S=4):
     import numpy as np
     import jax
 
-    from repro.core import HILBERT, MORTON
-    from repro.stencil import (Gol3d, Gol3dConfig, ResidentPipeline,
-                               repack_bytes_per_step, resident_bytes_per_step,
-                               resident_unfused_bytes_per_step)
+    from repro.core import HILBERT, MORTON, apply_ordering
+    from repro.stencil import Gol3d, Gol3dConfig, ResidentPipeline, \
+        resident_bytes_per_step
 
     print(f"[stencil_halo_demo] resident pipeline, M={M} g={g} T={T} "
           f"K={steps} steps, temporal blocking S={S}")
-    rep_b = repack_bytes_per_step(M, T, g)
-    unf_b = resident_unfused_bytes_per_step(M, T, g, steps)
-    fus_b = resident_bytes_per_step(M, T, g, steps, S=S)
-    print(f"  modelled HBM bytes/substep: repack={rep_b / 1e6:.2f} MB  "
-          f"resident(unfused)={unf_b / 1e6:.2f} MB  "
-          f"fused S={S}={fus_b / 1e6:.2f} MB  "
-          f"(x{rep_b / fus_b:.2f} vs repack, x{unf_b / fus_b:.2f} vs unfused)")
+    for depth in (1, S):
+        b = resident_bytes_per_step(M, T, g, steps, S=depth)
+        print(f"  modelled HBM bytes/substep, S={depth}: {b / 1e6:.2f} MB")
     auto = ResidentPipeline.plan(M, g=g)
     print(f"  plan(M={M}, g={g}) -> T={auto.T} S={auto.S} "
           f"(vmem {auto.vmem_bytes() / 1024:.0f} KiB, "
@@ -69,15 +64,7 @@ def resident_demo(M=32, g=1, T=8, steps=10, S=4):
     for spec in (MORTON, HILBERT):
         app = Gol3d(Gol3dConfig(M=M, g=g, ordering=spec, block_T=T,
                                 substeps=S))
-        # repack: warm the per-step jit, then time K steps
-        step = app.step_fn()
-        jax.block_until_ready(step(app.state_path))
-        t0 = time.perf_counter()
-        s = app.state_path
-        for _ in range(steps):
-            s = step(s)
-        sa = jax.block_until_ready(s)
-        t_rep = time.perf_counter() - t0
+        want = np.asarray(app.reference_run(steps))
         # fused resident: ceil(K/S) launches over the persistent store
         pipe = app.resident_pipeline()
         run = pipe.run_fn(steps)
@@ -85,14 +72,15 @@ def resident_demo(M=32, g=1, T=8, steps=10, S=4):
         t0 = time.perf_counter()
         out = jax.block_until_ready(run(pipe.to_blocks(app.cube)))
         t_res = time.perf_counter() - t0
-        from repro.core import apply_ordering
-        sb = apply_ordering(pipe.to_cube(out), spec)
-        ok = np.array_equal(np.asarray(sa), np.asarray(sb))
-        print(f"  {spec.name:10s} repack {t_rep * 1e3 / steps:6.1f} ms/step  "
-              f"fused S={pipe.S} {t_res * 1e3 / steps:6.1f} ms/step  "
-              f"bit-identical: {ok}")
+        got = pipe.to_cube(out)
+        ok = np.array_equal(np.asarray(got), want)
+        assert np.array_equal(np.asarray(app.run(steps)),
+                              np.asarray(apply_ordering(got, spec)))
+        print(f"  {spec.name:10s} fused S={pipe.S} "
+              f"{t_res * 1e3 / steps:6.1f} ms/step  matches oracle: {ok}")
         assert ok
     print("resident pipeline OK")
+
 
 def wave_demo(M=32, g=1, T=8, steps=8):
     """Part 3: the C=2 wave workload on the multi-field block store."""

@@ -17,8 +17,8 @@ from .surfaces import (  # noqa: F401
     FACES, PAPER_SURFACE_NAMES, surface_path_indices, run_stats, surface_runs,
 )
 from .layout import (  # noqa: F401
-    apply_ordering, undo_ordering, blockize, unblockize, blockize_with_halo,
-    blockize_fields, unblockize_fields, block_order,
+    apply_ordering, undo_ordering, blockize, unblockize, blockize_fields,
+    unblockize_fields, block_order,
 )
 from .neighbors import (  # noqa: F401
     OFFSETS_FULL, OFFSETS_FACE, FACE_COLS, SELF_COL,

@@ -3,7 +3,7 @@
 The paper's experiments — and the SEM locality study (arXiv:2104.08416)
 it builds on — run on *physical* domains whose edges do not wrap. This
 module is the one definition of that contract, shared by every pipeline
-form (repack, resident, fused, distributed) and their jnp oracles:
+form (resident, distributed) and their jnp oracles:
 
 - ``periodic``         — wrap at the domain edge (the torus default);
 - ``dirichlet(value)`` — ghost sites hold a fixed value at all times;

@@ -22,7 +22,7 @@ from .orderings import OrderingSpec, path_to_rmo, rmo_to_path, _check_pow2, _fla
 _SIMPLE_KINDS = ("row_major", "column_major", "morton", "hilbert")
 
 __all__ = [
-    "apply_ordering", "undo_ordering", "block_order", "blockize", "unblockize", "blockize_with_halo",
+    "apply_ordering", "undo_ordering", "block_order", "blockize", "unblockize",
     "blockize_fields", "unblockize_fields", "store_spec", "needs_element_perm",
 ]
 
@@ -205,35 +205,3 @@ def unblockize_fields(store: jnp.ndarray, M: int,
                       kind: str = "morton") -> jnp.ndarray:
     """Inverse of :func:`blockize_fields`: (C, nb, T³) -> (C, M, M, M)."""
     return _from_blocks(store, M, kind)
-
-
-def blockize_with_halo(x: jnp.ndarray, T: int, g: int, kind: str = "morton",
-                       periodic: bool = True, bc=None) -> jnp.ndarray:
-    """(M,M,M) -> (nb, T+2g, T+2g, T+2g), curve-ordered, halos included.
-
-    This is the pack step feeding kernels/stencil3d.py: each block carries
-    its own halo so the kernel needs no neighbour communication. Halo
-    duplication factor is ((T+2g)/T)³.
-
-    ``bc`` (core.boundary.BoundarySpec or kind string) selects the ghost
-    extension of the repack pipeline and overrides ``periodic`` when
-    given; the bare ``periodic=False`` legacy toggle is edge replication
-    (i.e. neumann0).
-    """
-    from .boundary import NEUMANN0, PERIODIC, pad_cube
-
-    M = x.shape[0]
-    nt = M // T
-    assert nt * T == M
-    if bc is None:
-        bc = PERIODIC if periodic else NEUMANN0
-    xp = pad_cube(x, g, bc)
-    bo = block_order(kind, nt)
-    # static window gather: start offsets per block
-    starts = bo * T  # in padded coords the halo window starts at bo*T
-    w = T + 2 * g
-    rng = np.arange(w)
-    kk = starts[:, 0][:, None] + rng[None, :]           # (nb, w)
-    ii = starts[:, 1][:, None] + rng[None, :]
-    jj = starts[:, 2][:, None] + rng[None, :]
-    return xp[kk[:, :, None, None], ii[:, None, :, None], jj[:, None, None, :]]

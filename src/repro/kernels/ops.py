@@ -14,17 +14,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.boundary import PERIODIC
-from repro.core.layout import blockize_with_halo, unblockize
 from repro.core.orderings import OrderingSpec
 from repro.core.surfaces import surface_path_indices
 
 from . import ref
 from .flash_attn import flash_attention_fwd
 from .sfc_gather import gather_rows
-from .stencil3d import stencil_sum_blocks
 
-__all__ = ["gol3d_step", "pack_surface", "unpack_surface",
+__all__ = ["pack_surface", "unpack_surface",
            "flash_attention", "sfc_gather_take", "uniform_weights"]
 
 
@@ -37,30 +34,6 @@ def uniform_weights(g: int) -> np.ndarray:
     w[g, g, g] = 0.0
     w.setflags(write=False)
     return w
-
-
-@functools.partial(jax.jit, static_argnames=("g", "block_kind", "T",
-                                             "use_kernel", "bc", "interpret"))
-def gol3d_step(cube: jnp.ndarray, *, g: int, T: int = 8,
-               block_kind: str = "morton", use_kernel: bool = False,
-               bc=PERIODIC, interpret: bool | None = None) -> jnp.ndarray:
-    """One gol3d update via the SFC-blocked stencil pipeline.
-
-    blockize_with_halo (SFC layout) → stencil kernel → rule → unblockize.
-    Semantically identical to ref.gol3d_step_ref under the same ``bc``
-    (core.boundary contract: periodic wrap, dirichlet constant, or
-    neumann0 edge replication — the halo bake-in realises all three).
-    """
-    M = cube.shape[0]
-    blocks = blockize_with_halo(cube, T, g, kind=block_kind, bc=bc)
-    if use_kernel:
-        neigh = stencil_sum_blocks(blocks, uniform_weights(g), g=g,
-                                   interpret=interpret)
-    else:
-        neigh = ref.stencil_sum_ref(blocks, uniform_weights(g))
-    centre = blocks[:, g:g + T, g:g + T, g:g + T]
-    nxt = ref.gol_rule_ref(centre, neigh, g)
-    return unblockize(nxt, M, kind=block_kind)
 
 
 _ROW_PLANS: dict = {}

@@ -11,9 +11,8 @@ from repro.core.boundary import PERIODIC, as_boundary, pad_cube
 from .rules import apply_window_bc, get_rule
 
 __all__ = ["stencil_sum_ref", "gol_rule_ref", "gol3d_step_ref",
-           "assemble_halo_ref", "stencil_sum_resident_ref",
-           "stencil_fused_ref", "fields_step_ref", "gather_rows_ref",
-           "attention_ref"]
+           "assemble_halo_ref", "stencil_fused_ref", "fields_step_ref",
+           "gather_rows_ref", "attention_ref"]
 
 
 def stencil_sum_ref(blocks: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
@@ -45,9 +44,9 @@ def assemble_halo_ref(store: jnp.ndarray, nbr: jnp.ndarray, g: int) -> jnp.ndarr
     nb ≤ nb_src — the distributed extended store appends shell blocks
     after the core, so the table may index more blocks than it has rows;
     returns (nb, T+2g, T+2g, T+2g) (with the leading C kept for stacked
-    input). With the periodic table of the same ordering this is
-    bit-identical to layout.blockize_with_halo — the jnp oracle of the
-    in-kernel assembly in stencil3d.stencil_sum_resident.
+    input). With the periodic table of the same ordering every window
+    is the block's slice of the wrap-padded cube — the jnp oracle of the
+    in-kernel assembly in stencil3d.stencil_step_fused.
     """
     multi = store.ndim == 5
     T = store.shape[-3]
@@ -73,13 +72,6 @@ def assemble_halo_ref(store: jnp.ndarray, nbr: jnp.ndarray, g: int) -> jnp.ndarr
     return jnp.concatenate(slabs, axis=-3)
 
 
-def stencil_sum_resident_ref(store: jnp.ndarray, weights: jnp.ndarray,
-                             nbr: jnp.ndarray) -> jnp.ndarray:
-    """Oracle for stencil3d.stencil_sum_resident (no halo store in HBM)."""
-    g = (weights.shape[0] - 1) // 2
-    return stencil_sum_ref(assemble_halo_ref(store, nbr, g), weights)
-
-
 def stencil_fused_ref(store: jnp.ndarray, weights: jnp.ndarray,
                       nbr: jnp.ndarray, *, S: int = 1, rule: str = "gol",
                       bc=PERIODIC, bnd: jnp.ndarray | None = None) -> jnp.ndarray:
@@ -88,7 +80,7 @@ def stencil_fused_ref(store: jnp.ndarray, weights: jnp.ndarray,
     Assembles the wide (T+2·S·g)³ window once, then runs S substeps of
     tap-sum + rule with the window shrinking by g per side — the exact
     computation the fused kernel performs in VMEM, vectorised over nb.
-    Bit-identical (f32 stores) to S sequential resident steps. Accepts
+    Bit-identical (f32 stores) to S sequential single-substep calls. Accepts
     the distributed extended store (shell blocks appended after the
     core, nbr rows = core only) like the kernel does, and the stacked
     multi-field ``(C, nb, T³)`` store (DESIGN.md §9): every substep

@@ -1,61 +1,44 @@
-"""Pallas TPU kernels: SFC-blocked 3D weighted stencil (DESIGN.md §2–§3).
+"""Pallas TPU kernel: the SFC-blocked 3D stencil, fused (DESIGN.md §2–§4).
 
-Two forms of the paper's layout insight:
+``stencil_step_fused`` advances the resident block store: the
+un-haloed ``(nb, T, T, T)`` array whose block order in HBM follows a
+space-filling curve and which persists across timesteps
+(stencil/pipeline.ResidentPipeline). The halo is assembled **inside the
+kernel**: a precomputed SFC neighbour table (core/neighbors.py) rides
+the scalar-prefetch channel, so the index map of each grid step points
+every one of the 27 window pieces (6 faces, 12 edges, 8 corners, 1
+centre) at the right slice of the right neighbour block. No halo store
+exists in HBM and no per-step repack runs; because blocks are
+curve-ordered, consecutive grid steps ask for overlapping neighbour
+sets.
 
-``stencil_sum_blocks`` — the original *repack* form: the cube is stored
-as ``(nb, T+2g, T+2g, T+2g)`` halo-extended blocks whose order in HBM
-follows a space-filling curve (core/layout.blockize_with_halo). One grid
-step = one block: load the ``(T+2g)³`` window into VMEM, produce a ``T³``
-tile. Simple, but the halo store duplicates HBM by ``((T+2g)/T)³`` and
-must be rebuilt from the canonical cube every step — an O(M³) gather
-that swamps the kernel's contiguous-walk advantage (DESIGN.md §3).
+Temporal blocking (DESIGN.md §4): the update rule (kernels/rules.py)
+is the kernel's epilogue, and one launch runs ``S`` whole substeps per
+HBM round-trip: assemble a ``(T+2·S·g)³`` window from neighbour slices
+of extent ``S·g``, then alternate tap-sum + rule in VMEM with the
+window shrinking by ``g`` per side each substep, and write the next
+``T³`` state tile once. K timesteps cost ``ceil(K/S)`` launches; per
+substep the HBM stream is ``((T+2·S·g)³ + T³)/S`` — the
+locality-for-bandwidth trade of Reissmann & Jahre, paid for with
+redundant boundary flops. ``rule="identity"`` with S=1 returns the raw
+tap sum.
 
-``stencil_sum_resident`` — the *resident* form: the store is the
-un-haloed ``(nb, T, T, T)`` block array that persists across timesteps,
-and the halo is assembled **inside the kernel**. A precomputed SFC
-neighbour table (core/neighbors.py) rides the scalar-prefetch channel —
-the same mechanism as kernels/sfc_gather.py — so the index map of grid
-step ``i`` can point each of the 27 window pieces (6 faces, 12 edges,
-8 corners, 1 centre) at the right slice of the right neighbour block.
-The HBM read per step is exactly ``(T+2g)³`` per block with *no* halo
-store in HBM and *no* per-step repack; because blocks are curve-ordered,
-consecutive grid steps ask for overlapping neighbour sets, which Pallas'
-revisiting-block elision turns into VMEM reuse.
-
-``stencil_step_fused`` — the *temporal-blocked* form (DESIGN.md §4): the
-resident kernel above still writes an f32 tap-sum array to HBM and
-leaves the update rule to a second pass. This kernel fuses the rule
-epilogue (kernels/rules.py) into the launch and runs ``S`` whole
-substeps per HBM round-trip: assemble a ``(T+2·S·g)³`` window from
-neighbour slices of extent ``S·g``, then alternate tap-sum + rule in
-VMEM with the window shrinking by ``g`` per side each substep, and
-write the next ``T³`` state tile once. K timesteps cost ``ceil(K/S)``
-launches; per substep the HBM stream drops from
-``(T+2g)³ + 3·T³`` (resident + rule pass) to
-``((T+2·S·g)³ + T³)/S`` — the locality-for-bandwidth trade of
-Reissmann & Jahre, paid for with redundant boundary flops.
-
-Boundary contract (DESIGN.md §8): ``stencil_step_fused`` takes a
-``core.boundary`` contract (uniform or per-axis mixed) plus a second
-scalar-prefetched ``(nb, 6)`` table of per-block clamped-face flags;
-before every substep the flagged ghost layers are substituted with
-boundary values (rules.apply_window_bc), so physical domains temporally
-block exactly as deep as periodic ones.
-``stencil_sum_blocks``/``stencil_sum_resident`` stay periodic-only
-baselines (the repack form realises clamped runs by padding at blockize
-time instead).
+Boundary contract (DESIGN.md §8): the kernel takes a ``core.boundary``
+contract (uniform or per-axis mixed) plus a second scalar-prefetched
+``(nb, 6)`` table of per-block clamped-face flags; before every substep
+the flagged ghost layers are substituted with boundary values
+(rules.apply_window_bc), so physical domains temporally block exactly
+as deep as periodic ones.
 
 Multi-field stores (DESIGN.md §9): a rule that declares C > 1 channels
 (``wave``) rides the stacked ``(C, nb, T³)`` store — the 27 piece specs
 gain a whole-store channel dimension, one grid step assembles C windows,
 tap-sums every channel, applies the rule to the stacked fields, and
-writes C tiles. C=1 stores keep the original 4-D kernel program
-byte-for-byte (bit-identity of the scalar rules to their pre-§9 runs is
-load-bearing: XLA's contraction choices shift with rank).
+writes C tiles. C=1 stores keep the 4-D kernel program.
 
 TPU layout (DESIGN.md §4): Mosaic refuses block shapes that cut the two
-minor dimensions off the (8, 128) tiling, so ``stencil_step_fused`` runs
-a ``(nb, T/kc)`` grid of k-chunks whose pieces are whole along j and
+minor dimensions off the (8, 128) tiling, so the kernel runs a
+``(nb, T/kc)`` grid of k-chunks whose pieces are whole along j and
 whole sublane tiles along i, and cuts the i/j halos in VMEM. A tap at
 an (i, j) offset off that tiling costs a rotate and a select per vreg,
 so each substep shifts every window plane once into its (2g+1)² - 1
@@ -65,13 +48,8 @@ in the original order (``_fused_kernel``). Its VMEM per grid step is
 the ``(C, kc+2h, T+2h, T+2h)`` window, the ring of
 ``(2g+1)·((2g+1)² - 1)·C`` planes of ``(T+2h, T+2h-2g)``, and the
 double-buffered pieces and output slab (``fused_kernel_vmem_bytes``) —
-13.1 MiB at T=128, S=4, g=1; only T=128 makes the store lane-dense. ``stencil_sum_resident``
-keeps the ``(h, T, h)`` pieces and does not lower for the TPU (it fails
-loudly there); it remains the interpret-mode baseline. A pure stencil
-is VPU work; the kernels unroll the (2g+1)³ taps for g ≤ 2, and
-``_tap_sum`` falls back to a ``fori_loop`` for larger g to bound code
-size. Correctness is checked against ref.stencil_sum_ref /
-ref.stencil_sum_resident_ref / ref.stencil_fused_ref.
+13.1 MiB at T=128, S=4, g=1; only T=128 makes the store lane-dense.
+Correctness is checked against ref.stencil_fused_ref.
 """
 
 from __future__ import annotations
@@ -89,10 +67,7 @@ from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
 from .backend import resolve_interpret
 from .rules import apply_window_bc, get_rule
 
-__all__ = ["stencil_sum_blocks", "stencil_sum_resident", "stencil_step_fused",
-           "fused_geometry", "fused_kernel_vmem_bytes"]
-
-_UNROLL_TAP_LIMIT = 125  # unroll (2g+1)^3 taps up to g=2
+__all__ = ["stencil_step_fused", "fused_geometry", "fused_kernel_vmem_bytes"]
 
 # The fused kernel's two scalar-prefetch tables live in SMEM (1 MiB on a
 # v5e core); leave room for Mosaic's own scalars.
@@ -102,174 +77,6 @@ SMEM_TABLE_BYTES = 3 * 2 ** 18
 # buffered pieces outgrows).
 VMEM_LIMIT_BYTES = 100 * 2 ** 20
 
-
-def _tap_sum(x: jnp.ndarray, w_ref, T: int, s: int) -> jnp.ndarray:
-    """acc[z] = sum_d w[d] * x[z+d] over the (s,s,s) taps; x: (T+s-1,)³."""
-    if s ** 3 <= _UNROLL_TAP_LIMIT:
-        acc = jnp.zeros((T, T, T), dtype=jnp.float32)
-        for dk in range(s):
-            for di in range(s):
-                for dj in range(s):
-                    acc = acc + w_ref[dk, di, dj].astype(jnp.float32) * (
-                        x[dk:dk + T, di:di + T, dj:dj + T])
-        return acc
-
-    def body(t, acc):
-        dk = t // (s * s)
-        di = (t // s) % s
-        dj = t % s
-        win = jax.lax.dynamic_slice(x, (dk, di, dj), (T, T, T))
-        return acc + w_ref[dk, di, dj].astype(jnp.float32) * win
-
-    return jax.lax.fori_loop(0, s * s * s, body,
-                             jnp.zeros((T, T, T), dtype=jnp.float32))
-
-
-# ---------------------------------------------------------------- repack form
-
-def _halo_kernel(w_ref, x_ref, o_ref, *, T: int, s: int):
-    o_ref[0] = _tap_sum(x_ref[0].astype(jnp.float32), w_ref, T, s)
-
-
-@functools.partial(jax.jit, static_argnames=("g", "interpret"))
-def stencil_sum_blocks(blocks: jnp.ndarray, weights: jnp.ndarray, *,
-                       g: int, interpret: bool | None = None) -> jnp.ndarray:
-    """acc[b] = sum_d w[d] * blocks[b, z+d] for every block b.
-
-    blocks:  (nb, T+2g, T+2g, T+2g)  — SFC-ordered, halo-extended
-    weights: (2g+1, 2g+1, 2g+1)
-    returns: (nb, T, T, T) float32
-    """
-    nb, W = blocks.shape[0], blocks.shape[1]
-    s = 2 * g + 1
-    T = W - 2 * g
-    assert weights.shape == (s, s, s), (weights.shape, s)
-    kern = functools.partial(_halo_kernel, T=T, s=s)
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((nb, T, T, T), jnp.float32),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((s, s, s), lambda i: (0, 0, 0)),        # weights: resident
-            pl.BlockSpec((1, W, W, W), lambda i: (i, 0, 0, 0)),  # one block/step
-        ],
-        out_specs=pl.BlockSpec((1, T, T, T), lambda i: (i, 0, 0, 0)),
-        interpret=resolve_interpret(interpret),
-    )(weights, blocks)
-
-
-# -------------------------------------------------------------- resident form
-
-def _assemble_window(refs) -> jnp.ndarray:
-    """Concatenate 27 piece refs (OFFSETS_FULL order) into one f32 window.
-
-    Piece (a,b,c) has shape (1, sz[a], sz[b], sz[c]) with sz = (h, T, h)
-    — or ``(C, 1, sz[a], sz[b], sz[c])`` in the multi-field store, where
-    the leading channel axis rides along (DESIGN.md §9): low halo, centre
-    span, high halo along each axis (h = halo width). Returns
-    ``(T+2h,)³`` or ``(C, (T+2h)³…)`` accordingly — concatenation is on
-    the last three (spatial) axes either way.
-    """
-    pieces = [(r[0] if len(r.shape) == 4 else r[:, 0]).astype(jnp.float32)
-              for r in refs]
-    slabs = []
-    n = 0
-    for _a in range(3):
-        planes = []
-        for _b in range(3):
-            planes.append(jnp.concatenate(pieces[n:n + 3], axis=-1))
-            n += 3
-        slabs.append(jnp.concatenate(planes, axis=-2))
-    return jnp.concatenate(slabs, axis=-3)
-
-
-def _resident_kernel(nbr_ref, w_ref, *refs, T: int, s: int):
-    """Assemble the (T+2g)³ window from 27 neighbour slices, then tap-sum."""
-    o_ref = refs[-1]
-    x = _assemble_window(refs[:-1])
-    o_ref[0] = _tap_sum(x, w_ref, T, s)
-
-
-def _piece_index(i, nbr_ref, *_extra_prefetch, col: int, bidx: tuple,
-                 channels: bool = False):
-    # nbr_ref[i, col] is the path position of the neighbour block this
-    # piece is sliced from; bidx addresses the slice in block-shape units.
-    # Extra scalar-prefetch refs (the fused kernel's bnd flags) don't
-    # steer piece addressing. Multi-field stores carry a leading channel
-    # axis whose single block always sits at index 0.
-    idx = (nbr_ref[i, col],) + bidx
-    return (0,) + idx if channels else idx
-
-
-def _piece_specs(T: int, h: int, channels: int | None = None) -> list:
-    """The 27 neighbour-slice BlockSpecs for a halo of width h (h | T).
-
-    Piece extent per axis is (h, T, h) — low halo, centre, high halo —
-    and the low piece reads the neighbour's *last* h-slab while centre
-    and high read from its first, addressed in block-shape units.
-    ``channels=C`` prepends the whole-store channel axis of the
-    multi-field ``(C, nb, T³)`` store (DESIGN.md §9) to every piece, so
-    one grid step streams the window of all C fields.
-    """
-    sz = (h, T, h)
-    last = (T // h - 1, 0, 0)
-    specs = []
-    for a in range(3):
-        for b in range(3):
-            for c in range(3):
-                col = a * 9 + b * 3 + c
-                shape = (1, sz[a], sz[b], sz[c])
-                if channels is not None:
-                    shape = (channels,) + shape
-                specs.append(pl.BlockSpec(
-                    shape,
-                    functools.partial(_piece_index, col=col,
-                                      bidx=(last[a], last[b], last[c]),
-                                      channels=channels is not None)))
-    return specs
-
-
-@functools.partial(jax.jit, static_argnames=("g", "interpret"))
-def stencil_sum_resident(store: jnp.ndarray, weights: jnp.ndarray,
-                         nbr: jnp.ndarray, *, g: int,
-                         interpret: bool | None = None) -> jnp.ndarray:
-    """In-kernel halo streaming over the persistent block store.
-
-    store:   (nb, T, T, T)  — SFC-ordered, *no* halo duplication
-    weights: (2g+1, 2g+1, 2g+1)
-    nbr:     (nb, 27) int32 — full periodic neighbour table of the same
-             ordering (core.neighbors.neighbor_table), scalar-prefetched
-    returns: (nb, T, T, T) float32, bit-identical to
-             stencil_sum_blocks(blockize_with_halo(...), ...)
-
-    Halo pieces are addressed in block-shape units, so g must divide T
-    (g ∈ {1, 2, 4, ...} for T = 8; use the repack form otherwise).
-    """
-    nb, T = store.shape[0], store.shape[1]
-    s = 2 * g + 1
-    assert store.shape == (nb, T, T, T), store.shape
-    assert weights.shape == (s, s, s), (weights.shape, s)
-    assert nbr.shape == (nb, 27), nbr.shape
-    if g > T or T % g:
-        raise ValueError(f"resident kernel needs g | T, got T={T}, g={g}")
-
-    in_specs = [pl.BlockSpec((s, s, s), lambda i, nbr_ref: (0, 0, 0))]
-    in_specs += _piece_specs(T, g)
-    kern = functools.partial(_resident_kernel, T=T, s=s)
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((nb, T, T, T), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nb,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, T, T, T), lambda i, nbr_ref: (i, 0, 0, 0)),
-        ),
-        interpret=resolve_interpret(interpret),
-    )(nbr.astype(jnp.int32), weights, *([store] * 27))
-
-
-# ------------------------------------------------------- temporal-blocked form
 
 SUBLANES = 8   # f32 rows in one (8, 128) vreg tile
 LANES = 128    # f32 lanes in one (8, 128) vreg tile: the lane-dense T

@@ -210,7 +210,7 @@ def extract_roi(store: np.ndarray, layout: StoreLayout, roi: ROI,
 
 def roi_model(layout: StoreLayout, roi: ROI, itemsize: int = 4) -> dict:
     """Deterministic accounting of one ROI query — the model the
-    ``roi/`` benchmark rows stamp and CI pins exactly.
+    ``roi/`` rows of benchmarks/roi.py print.
 
     blocks_touched: blocks whose extent intersects the ROI (= the block
                     box volume — curve-independent)

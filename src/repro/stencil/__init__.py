@@ -6,8 +6,7 @@ from .pipeline import (  # noqa: F401
     checkpoint_bytes_per_interval, checkpoint_traffic_fraction,
     distributed_bytes_per_step, exchange_bytes_per_step, exchange_face_items,
     exchange_items_per_exchange, fused_items_per_launch, fused_vmem_bytes,
-    repack_bytes_per_step, repack_items_per_step, resident_bytes_per_step,
-    resident_unfused_bytes_per_step, resident_unfused_items_per_step,
+    resident_bytes_per_step,
 )
 from .domain import Decomposition3D, make_stencil_mesh, STENCIL_AXES  # noqa: F401
 from .halo import (  # noqa: F401

@@ -6,17 +6,12 @@ ordering; the update walks the cube along the ordering's path, realised
 on TPU as the SFC-blocked kernel pipeline (kernels/stencil3d.py) whose
 grid order follows the curve because the blocks are laid out along it.
 
-Two execution modes (DESIGN.md §3):
-
-- per-step *repack* (``step_fn``/``run``): each step rebuilds the
-  halo-extended block store from the canonical cube — the seed pipeline,
-  kept as the equivalence baseline;
-- fused *resident* (``run_resident``): blockize once, run K steps on the
-  persistent curve-ordered store with in-kernel halo streaming
-  (stencil/pipeline.py), unblockize once. ``substeps`` (S) additionally
-  temporal-blocks the resident form — S whole updates per HBM
-  round-trip (DESIGN.md §4); ``substeps=0`` lets the pipeline's
-  ``plan()`` autotuner pick (T, S) under the VMEM budget.
+``run`` blockizes once, runs K steps on the persistent curve-ordered
+store with in-kernel halo streaming (stencil/pipeline.py, DESIGN.md
+§3), and unblockizes once. ``substeps`` (S) temporal-blocks the run —
+S whole updates per HBM round-trip (DESIGN.md §4); ``substeps=0`` lets
+the pipeline's ``plan()`` autotuner pick (T, S) under the VMEM budget.
+``run_distributed`` runs the same update on a device mesh.
 """
 
 from __future__ import annotations
@@ -30,7 +25,7 @@ import numpy as np
 
 from repro.core import (OrderingSpec, PERIODIC, ROW_MAJOR, BoundarySpec,
                         apply_ordering, as_boundary, undo_ordering)
-from repro.kernels import backend, ops
+from repro.kernels import backend
 from repro.kernels import ref as kref
 
 from .domain import Decomposition3D, STENCIL_AXES
@@ -58,8 +53,8 @@ class Gol3dConfig:
     bc:         boundary contract (core.boundary.BoundarySpec or kind
                 string): "periodic" wraps like a torus; "dirichlet" /
                 "neumann0" clamp the domain edges physically
-                (DESIGN.md §8) — every execution mode (repack, resident,
-                distributed) honours the same contract
+                (DESIGN.md §8) — the single-device and mesh runs
+                honour the same contract
     density:    initial live fraction of the random seed state
     seed:       RNG seed of the initial state
     """
@@ -103,28 +98,6 @@ class Gol3d:
         even when the logical state ordering is row/column-major)."""
         return stencil_block_kind(self.cfg.ordering)
 
-    def step_fn(self):
-        """jit-able (state_path -> state_path) single update (repack mode)."""
-        cfg = self.cfg
-        kind = self.block_kind
-
-        @jax.jit
-        def step(state_path):
-            cube = undo_ordering(state_path, cfg.ordering, cfg.M)
-            nxt = ops.gol3d_step(cube, g=cfg.g, T=cfg.block_T, block_kind=kind,
-                                 use_kernel=cfg.use_kernel, bc=cfg.bc)
-            return apply_ordering(nxt, cfg.ordering)
-
-        return step
-
-    def run(self, n_steps: int) -> jnp.ndarray:
-        step = self.step_fn()
-        s = self.state_path
-        for _ in range(n_steps):
-            s = step(s)
-        self.state_path = jax.block_until_ready(s)
-        return self.state_path
-
     def resident_pipeline(self) -> ResidentPipeline:
         """The fused driver over this app's block layout (DESIGN.md §3–§4).
 
@@ -156,10 +129,10 @@ class Gol3d:
 
         return run
 
-    def run_resident(self, n_steps: int) -> jnp.ndarray:
+    def run(self, n_steps: int) -> jnp.ndarray:
         """Fused multi-step run: the curve-ordered block store is the
         resident state for all n_steps; layout conversions happen once at
-        each end. Bit-identical to ``run`` (same block kind, same rule)."""
+        each end."""
         self.state_path = jax.block_until_ready(
             self.resident_fn(n_steps)(self.state_path))
         return self.state_path
@@ -187,7 +160,7 @@ class Gol3d:
     def run_distributed(self, mesh: jax.sharding.Mesh, n_steps: int) -> jnp.ndarray:
         """Shard the cube over the mesh, run K deep-exchange rounds, and
         gather back into this app's path-ordered state. Bit-identical to
-        ``run``/``run_resident`` on one device (same rule, f32 state)."""
+        ``run`` on one device (same rule, f32 state)."""
         pipe = self.distributed_pipeline(mesh)
         cube = pipe.run_cube(self.cube, n_steps)
         self.state_path = jax.block_until_ready(

@@ -342,7 +342,7 @@ def test_shard_unshard_roundtrip():
 # ----------------------------------------------- bytes model + plan (accept)
 def test_exchange_model_matches_slab_shapes():
     """The ICI model is exactly the six exchanged slab volumes — one
-    accounting between the exchange code and the benchmark rows."""
+    accounting between the exchange code and the model."""
     for M, g, S in [(16, 1, 1), (16, 1, 4), (64, 1, 4), (64, 2, 2)]:
         h = S * g
         slabs = sum(int(np.prod(s)) for s in shell_slab_shapes(M, h))
@@ -353,8 +353,7 @@ def test_exchange_model_matches_slab_shapes():
 def test_distributed_bytes_acceptance():
     """Acceptance: at the PR-2 reference point (local M=64, T=8, g=1)
     total modelled bytes/step (HBM + exchange) at S=4 is strictly below
-    S=1 — asserted from the shared helpers (same accounting as the
-    stencil_update rows)."""
+    S=1 — asserted from the shared helpers."""
     lo = distributed_bytes_per_step(64, 8, 1, 8, S=4)
     hi = distributed_bytes_per_step(64, 8, 1, 8, S=1)
     assert lo < hi

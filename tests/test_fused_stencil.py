@@ -13,21 +13,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import MORTON, blockize, blockize_fields
+from repro.core import (MORTON, NEUMANN0, PERIODIC, blockize,
+                        blockize_fields, dirichlet, mixed)
 from repro.core.neighbors import neighbor_table
 from repro.kernels import ref
 from repro.kernels.ops import uniform_weights
 from repro.kernels.rules import RULES, get_rule
 from repro.kernels.stencil3d import (VMEM_LIMIT_BYTES, fused_kernel_vmem_bytes,
-                                     stencil_step_fused, stencil_sum_resident)
+                                     stencil_step_fused)
 from repro.stencil import Gol3d, Gol3dConfig
 from repro.stencil.pipeline import (VMEM_BUDGET_BYTES, ResidentPipeline,
+                                    distributed_bytes_per_step,
+                                    exchange_bytes_per_step,
+                                    exchange_items_per_exchange,
                                     fused_items_per_launch, fused_vmem_bytes,
-                                    repack_bytes_per_step,
-                                    repack_items_per_step,
-                                    resident_bytes_per_step,
-                                    resident_unfused_bytes_per_step,
-                                    resident_unfused_items_per_step)
+                                    resident_bytes_per_step)
 
 rng = np.random.default_rng(11)
 
@@ -73,10 +73,11 @@ def test_fused_kernel_matches_sequential_seed_steps(kind, S, rule):
     oseq = store
     for _ in range(S):
         if r.channels == 1:
-            neigh = ref.stencil_sum_resident_ref(oseq, w, nbr)
+            neigh = ref.stencil_sum_ref(ref.assemble_halo_ref(oseq, nbr, G), w)
         else:  # per-channel tap sums of the stacked store
-            neigh = jnp.stack([ref.stencil_sum_resident_ref(oseq[c], w, nbr)
-                               for c in range(r.channels)])
+            neigh = jnp.stack([
+                ref.stencil_sum_ref(ref.assemble_halo_ref(oseq[c], nbr, G), w)
+                for c in range(r.channels)])
         oseq = r.apply(oseq.astype(jnp.float32), neigh, G).astype(store.dtype)
     np.testing.assert_array_equal(np.asarray(oracle), np.asarray(oseq))
     # ...and the kernel cross-family: exact for gol (integer sums) and
@@ -89,12 +90,12 @@ def test_fused_kernel_matches_sequential_seed_steps(kind, S, rule):
 
 
 def test_fused_identity_rule_is_raw_stencil_sum():
-    """rule="identity", S=1 reproduces the PR-1 resident tap-sum kernel."""
+    """rule="identity", S=1 returns the raw tap sum of the oracle."""
     w = uniform_weights(G)
     nbr = neighbor_table("morton", M // T)
     store = _store("morton", "jacobi")
     a = stencil_step_fused(store, w, nbr, g=G, S=1, rule="identity")
-    b = stencil_sum_resident(store, w, nbr, g=G)
+    b = ref.stencil_fused_ref(store, w, nbr, S=1, rule="identity")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -135,7 +136,7 @@ def test_pipeline_S_matches_oracle_reference():
     """Fused S through Gol3d (substeps knob) == canonical cube oracle."""
     app = Gol3d(Gol3dConfig(M=M, g=G, ordering=MORTON, block_T=T, substeps=2))
     want = app.reference_run(4)
-    app.run_resident(4)
+    app.run(4)
     np.testing.assert_array_equal(np.asarray(app.cube), np.asarray(want))
 
 
@@ -184,19 +185,7 @@ def test_plan_pipeline_runs_correctly():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-# --------------------------------------------------- bytes model + benchmarks
-def test_fused_bytes_model_acceptance():
-    """Acceptance: at (M=64, T=8, g=1, S=4) the fused path models ≥ 2×
-    fewer HBM bytes/substep than the PR-1 unfused resident path."""
-    fused = resident_bytes_per_step(64, 8, 1, 10, S=4)
-    unfused = resident_unfused_bytes_per_step(64, 8, 1, 10)
-    assert fused * 2 <= unfused
-    # and still strictly beats repack at every depth
-    for S in (1, 2, 4, 8):
-        assert resident_bytes_per_step(64, 8, 1, 10, S=S) < \
-            repack_bytes_per_step(64, 8, 1)
-
-
+# --------------------------------------------------------------- bytes model
 def test_bytes_model_has_interior_optimum_in_S():
     """At fixed T the per-substep window cost (T+2·S·g)³/S first falls
     (launch overheads amortise) then rises (window inflation wins):
@@ -209,34 +198,34 @@ def test_bytes_model_has_interior_optimum_in_S():
     assert pipe.bytes_per_step(100) <= min(b.values())
 
 
-def test_benchmark_rows_share_accounting():
-    """Satellite: stencil_update rows carry exactly the pipeline model's
-    numbers — one accounting helper across model and benchmarks."""
-    import sys
-    sys.path.insert(0, ".")
-    from benchmarks.run import _parse_derived
-    from benchmarks.stencil_update import resident_derived
+BCS = {"periodic": PERIODIC, "dirichlet": dirichlet(0.0),
+       "neumann0": NEUMANN0, "mixed": mixed(k="neumann0")}
 
-    M_, T_, g, S, K = 64, 8, 1, 4, 10
-    d = _parse_derived(resident_derived(M_, T_, g, S, K))
-    assert d["fused_bytes_per_substep"] == round(
-        resident_bytes_per_step(M_, T_, g, K, S=S))
-    assert d["unfused_bytes_per_step"] == round(
-        resident_unfused_bytes_per_step(M_, T_, g, K))
-    assert d["repack_bytes_per_step"] == round(repack_bytes_per_step(M_, T_, g))
-    assert d["fused_vs_unfused"] >= 2.0  # the acceptance ratio, as reported
-    # distributed totals ride the same helpers (DESIGN.md §7)
-    from repro.stencil import (distributed_bytes_per_step,
-                               exchange_bytes_per_step)
-    assert d["ici_bytes_per_step"] == round(exchange_bytes_per_step(M_, g, S))
-    assert d["distributed_bytes_per_step"] == round(
-        distributed_bytes_per_step(M_, T_, g, K, S=S))
-    assert d["distributed_bytes_per_step"] == round(
-        d["fused_bytes_per_substep"] + exchange_bytes_per_step(M_, g, S))
-    # items helpers and bytes helpers agree (itemsize=4)
-    assert repack_bytes_per_step(M_, T_, g) == 4 * repack_items_per_step(M_, T_, g)
-    assert fused_items_per_launch(M_, T_, g, 1) + 2 * (M_ // T_) ** 3 * T_ ** 3 \
-        == resident_unfused_items_per_step(M_, T_, g)
+
+@pytest.mark.parametrize("C", [1, 2])
+@pytest.mark.parametrize("bc", sorted(BCS))
+def test_benchmark_rows_share_accounting(bc, C):
+    """One accounting: the mesh model is the resident HBM model plus the
+    exchange model, for the mesh mean and for every shard, and the
+    resident model is the fused launch stream amortised over S plus the
+    layout boundary amortised over the run."""
+    M_, T_, g, S, K, procs = 64, 8, 1, 4, 10, (2, 2, 2)
+    bcs = BCS[bc]
+    res = resident_bytes_per_step(M_, T_, g, K, S=S, fields=C)
+    assert res == 4 * (fused_items_per_launch(M_, T_, g, S, fields=C) / S
+                       + C * 4 * M_ ** 3 / K)
+    for coords in [None] + [(a, b, c) for a in range(2) for b in range(2)
+                            for c in range(2)]:
+        ici = exchange_bytes_per_step(M_, g, S, bc=bcs, procs=procs,
+                                      coords=coords, fields=C)
+        assert ici == 4 * exchange_items_per_exchange(
+            M_, g, S, bc=bcs, procs=procs, coords=coords, fields=C) / S
+        assert distributed_bytes_per_step(
+            M_, T_, g, K, S=S, bc=bcs, procs=procs, coords=coords,
+            fields=C) == pytest.approx(res + ici)
+    # S amortises the stream: one launch of S steps, not S launches
+    assert fused_items_per_launch(M_, T_, g, S, fields=C) \
+        < S * fused_items_per_launch(M_, T_, g, 1, fields=C)
 
 
 # ----------------------------------------------------------- cache satellites
@@ -351,12 +340,12 @@ def test_fused_ring_matches_sequential_launches(rule, S, g, bc):
 @pytest.mark.parametrize("g", [1, 2])
 def test_fused_ring_identity_is_resident_sum(g):
     """rule="identity" through the ring at M=64, T=32 reproduces the
-    resident tap-sum kernel, which slices its window directly."""
+    oracle's tap sum, which slices its window directly."""
     nbr = neighbor_table("hilbert", 2)
     store = _ring_store("jacobi", 64, 32)
     w = uniform_weights(g)
     a = stencil_step_fused(store, w, nbr, g=g, S=1, rule="identity")
-    b = stencil_sum_resident(store, w, nbr, g=g)
+    b = ref.stencil_fused_ref(store, w, nbr, S=1, rule="identity")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

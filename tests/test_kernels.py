@@ -5,39 +5,49 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import HILBERT, MORTON, ROW_MAJOR, apply_ordering
+from repro.core import HILBERT, MORTON, ROW_MAJOR, apply_ordering, blockize
+from repro.core.neighbors import neighbor_table
 from repro.kernels import ref
 from repro.kernels.flash_attn import build_schedule, flash_attention_fwd
-from repro.kernels.ops import (flash_attention, gol3d_step, pack_surface,
+from repro.kernels.ops import (flash_attention, pack_surface,
                                sfc_gather_take, unpack_surface, _fold_gqa)
 from repro.kernels.sfc_gather import gather_rows
-from repro.kernels.stencil3d import stencil_sum_blocks
+from repro.kernels.stencil3d import stencil_step_fused
+from repro.stencil import Gol3d, Gol3dConfig
 
 rng = np.random.default_rng(42)
 
 
 # ----------------------------------------------------------------- stencil
-@pytest.mark.parametrize("g,T", [(1, 4), (1, 8), (2, 4), (3, 2)])
+@pytest.mark.parametrize("g,T", [(1, 4), (1, 8), (2, 4), (2, 8)])
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
 def test_stencil_kernel_allclose(g, T, dtype):
-    W = T + 2 * g
-    blocks = jnp.asarray(rng.normal(size=(6, W, W, W)).astype(np.float32)
-                         ).astype(dtype)
+    """The raw tap sum (rule="identity", one substep) of the fused
+    kernel against its oracle, normal weights, f32 and bf16 stores."""
+    M = 2 * T
+    cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
+    store = blockize(cube, T, kind="hilbert").astype(dtype)
+    nbr = neighbor_table("hilbert", M // T)
     w = jnp.asarray(rng.normal(size=(2 * g + 1,) * 3).astype(np.float32))
-    out_k = stencil_sum_blocks(blocks, w, g=g)
-    out_r = ref.stencil_sum_ref(blocks, w)
+    out_k = stencil_step_fused(store, w, nbr, g=g, S=1, rule="identity")
+    out_r = ref.stencil_fused_ref(store, w, nbr, S=1, rule="identity")
+    assert out_k.dtype == out_r.dtype == dtype
     tol = 1e-5 if dtype == np.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+    np.testing.assert_allclose(np.asarray(out_k, np.float32),
+                               np.asarray(out_r, np.float32),
                                rtol=tol, atol=tol)
 
 
 def test_gol3d_kernel_matches_canonical():
     cube = jnp.asarray((rng.random((16, 16, 16)) < 0.3).astype(np.float32))
     for g in (1, 2):
-        for kind in ("morton", "hilbert"):
-            a = gol3d_step(cube, g=g, T=4, block_kind=kind, use_kernel=True)
+        for spec in (MORTON, HILBERT):
+            app = Gol3d(Gol3dConfig(M=16, g=g, ordering=spec, block_T=4,
+                                    use_kernel=True),
+                        state_path=apply_ordering(cube, spec))
+            app.run(1)
             b = ref.gol3d_step_ref(cube, g=g)
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_array_equal(np.asarray(app.cube), np.asarray(b))
 
 
 # ------------------------------------------------------------------ gather

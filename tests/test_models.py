@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import HILBERT, MORTON, OrderingSpec
-from repro.core.layout import blockize, blockize_with_halo, unblockize
+from repro.core.layout import blockize, unblockize
 from repro.data import TokenPipeline
 from repro.models import build_model
 from repro.models.config import (HybridConfig, MLAConfig, ModelConfig,
@@ -144,20 +144,6 @@ def test_blockize_roundtrip(kind):
     blocks = blockize(x, T, kind)
     back = unblockize(blocks, M, kind)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
-
-
-@pytest.mark.parametrize("kind", ["morton", "hilbert"])
-def test_blockize_with_halo_periodic(kind):
-    M, T, g = 8, 4, 1
-    x = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
-    blocks = blockize_with_halo(x, T, g, kind, periodic=True)
-    xp = np.pad(np.asarray(x), g, mode="wrap")
-    from repro.core.layout import block_order
-    bo = block_order(kind, M // T)
-    for b in range(blocks.shape[0]):
-        bk, bi, bj = bo[b] * T
-        want = xp[bk:bk + T + 2 * g, bi:bi + T + 2 * g, bj:bj + T + 2 * g]
-        np.testing.assert_array_equal(np.asarray(blocks[b]), want)
 
 
 def test_pipeline_deterministic_and_seekable():

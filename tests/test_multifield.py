@@ -13,8 +13,7 @@ Coverage layers, mirroring the single-field suites:
 - plan(): the VMEM budget carries the ×C working set, so wave plans
   never exceed the budget and shrink under tight limits;
 - bytes model: every accounting helper's ``fields`` factor is exactly
-  ×C, the multifield benchmark rows carry precisely the helpers'
-  numbers, and run.py stamps ``fields`` into the JSON schema;
+  ×C, under every boundary contract;
 - exchange: the C-channel shell exchange on a 1×1×1 mesh equals the
   per-channel pad, packed through one set of messages;
 - the ≥8-device wave acceptance matrix: DistributedPipeline S-deep vs S
@@ -32,8 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (COLUMN_MAJOR, HILBERT, MORTON, NEUMANN0, ROW_MAJOR,
-                        blockize, blockize_fields, dirichlet, mixed,
+from repro.core import (COLUMN_MAJOR, HILBERT, MORTON, NEUMANN0, PERIODIC,
+                        ROW_MAJOR, blockize, blockize_fields, dirichlet, mixed,
                         unblockize_fields)
 from repro.core.neighbors import neighbor_table
 from repro.kernels import ref as kref
@@ -206,29 +205,33 @@ def test_bytes_model_fields_factor_is_exactly_C():
         16, 1, 4, bc=NEUMANN0, procs=(2, 2, 2), coords=(0, 0, 0))
 
 
-def test_multifield_benchmark_rows_share_accounting():
-    """Satellite: the multifield rows carry exactly the pipeline model's
-    ×C numbers, and run.py stamps ``fields`` into the JSON schema."""
-    sys.path.insert(0, ".")
-    from benchmarks.run import _parse_derived
-    from benchmarks.stencil_update import WAVE_FIELDS, multifield_derived
+BCS = {"periodic": PERIODIC, "dirichlet": dirichlet(0.0),
+       "neumann0": NEUMANN0, "mixed": mixed(j="dirichlet")}
 
-    M_, T_, g, S, K = 32, 8, 1, 4, 10
-    d = _parse_derived(multifield_derived(M_, T_, g, S, K))
-    assert d["fields"] == WAVE_FIELDS == 2
-    assert d["fused_bytes_per_substep"] == round(
-        resident_bytes_per_step(M_, T_, g, K, S=S, fields=2))
-    assert d["fused_bytes_per_field_substep"] == round(
-        resident_bytes_per_step(M_, T_, g, K, S=S, fields=2) / 2)
-    assert d["fused_vs_single_field"] == pytest.approx(2.0)
-    assert d["ici_bytes_per_step"] == round(
-        exchange_bytes_per_step(M_, g, S, fields=2))
-    assert d["distributed_bytes_per_step"] == round(
-        distributed_bytes_per_step(M_, T_, g, K, S=S, fields=2))
-    # run.py --json: fields is stamped top-level, defaulting to 1 for
-    # rows that predate the multi-field store
-    assert int(_parse_derived("fields=2;a=1").get("fields", 1)) == 2
-    assert int(_parse_derived("a=1").get("fields", 1)) == 1
+
+@pytest.mark.parametrize("C", [1, 2])
+@pytest.mark.parametrize("bc", sorted(BCS))
+def test_multifield_benchmark_rows_share_accounting(bc, C):
+    """The ×C factor under every contract: each model with ``fields=C``
+    is C times its single-field value, for the mesh mean and for every
+    shard, and the per-field stream of the C-channel store is the
+    single-field stream."""
+    M_, T_, g, S, K, procs = 32, 8, 1, 4, 10, (2, 2, 2)
+    bcs = BCS[bc]
+    res = resident_bytes_per_step(M_, T_, g, K, S=S, fields=C)
+    assert res / C == pytest.approx(resident_bytes_per_step(M_, T_, g, K,
+                                                            S=S))
+    assert fused_vmem_bytes(T_, g, S, fields=C) - fused_vmem_bytes(
+        T_, g, S) == (C - 1) * 4 * (2 * (T_ + 2 * S * g) ** 3 + 2 * T_ ** 3)
+    for coords in [None, (0, 0, 0), (1, 0, 1)]:
+        kw = dict(bc=bcs, procs=procs, coords=coords)
+        assert exchange_items_per_exchange(M_, g, S, fields=C, **kw) == \
+            C * exchange_items_per_exchange(M_, g, S, **kw)
+        assert exchange_bytes_per_step(M_, g, S, fields=C, **kw) == \
+            pytest.approx(C * exchange_bytes_per_step(M_, g, S, **kw))
+        assert distributed_bytes_per_step(M_, T_, g, K, S=S, fields=C,
+                                          **kw) == pytest.approx(
+            C * distributed_bytes_per_step(M_, T_, g, K, S=S, **kw))
 
 
 def test_pipeline_wave_bytes_accessors_carry_C():

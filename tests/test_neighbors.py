@@ -4,8 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (HILBERT, MORTON, ROW_MAJOR, OrderingSpec,
-                        blockize, blockize_with_halo, unblockize)
+from repro.core import (HILBERT, MORTON, PERIODIC, ROW_MAJOR, OrderingSpec,
+                        blockize, pad_cube, unblockize)
 from repro.core.neighbors import (FACE_COLS, OFFSETS_FACE, OFFSETS_FULL,
                                   SELF_COL, block_kind_of, neighbor_table,
                                   ring_perms)
@@ -13,9 +13,8 @@ from repro.core.layout import block_order
 from repro.core.orderings import path_to_rmo, rmo_to_path
 from repro.kernels import ref
 from repro.kernels.ops import uniform_weights
-from repro.kernels.stencil3d import stencil_sum_blocks, stencil_sum_resident
+from repro.kernels.stencil3d import stencil_step_fused
 from repro.stencil import Gol3d, Gol3dConfig, ResidentPipeline
-from repro.stencil.pipeline import repack_bytes_per_step, resident_bytes_per_step
 
 rng = np.random.default_rng(7)
 
@@ -99,23 +98,41 @@ def test_ring_perms():
     assert bwd == [(0, 3), (1, 0), (2, 1), (3, 2)]
 
 
-# ------------------------------------------------- in-kernel halo vs repacked
+# ------------------------------------------ in-kernel halo vs the padded cube
+def _halo_windows(cube, T, g, kind):
+    """Every block's (T+2g)³ window cut from the wrap-padded cube, in
+    curve order: what the assembled halo must hold."""
+    xp = np.asarray(pad_cube(cube, g, PERIODIC))
+    W = T + 2 * g
+    return np.stack([xp[k:k + W, i:i + W, j:j + W]
+                     for k, i, j in block_order(kind, cube.shape[0] // T) * T])
+
+
 @pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("g", [1, 2])
 def test_assemble_halo_bit_identical(kind, g):
     M, T = 16, 8
     cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
-    halo = blockize_with_halo(cube, T, g, kind=kind, periodic=True)
     store = blockize(cube, T, kind=kind)
     nbr = neighbor_table(kind, M // T)
     asm = ref.assemble_halo_ref(store, nbr, g)
-    np.testing.assert_array_equal(np.asarray(asm), np.asarray(halo))
+    np.testing.assert_array_equal(np.asarray(asm),
+                                  _halo_windows(cube, T, g, kind))
+
+
+def _identity_launch(cube, w, T, g, kind):
+    """(fused identity launch, its oracle, the assembled windows)."""
+    store = blockize(cube, T, kind=kind)
+    nbr = neighbor_table(kind, cube.shape[0] // T)
+    got = stencil_step_fused(store, w, nbr, g=g, S=1, rule="identity")
+    want = ref.stencil_fused_ref(store, w, nbr, S=1, rule="identity")
+    return got, want, ref.assemble_halo_ref(store, nbr, g)
 
 
 @pytest.mark.parametrize("kind", ("morton", "hilbert"))
 @pytest.mark.parametrize("g,T", [(1, 8), (2, 8), (1, 4), (4, 4)])
 def test_resident_kernel_bit_identical(kind, g, T):
-    """Pallas resident kernel == Pallas repack kernel, bit for bit.
+    """The fused kernel's raw tap sum == its jnp oracle, bit for bit.
 
     The weights are random signed powers of two, so every w·x product
     is exact: the two programs then agree bit for bit whether or not
@@ -127,34 +144,27 @@ def test_resident_kernel_bit_identical(kind, g, T):
     w = jnp.asarray((rng.choice([-1.0, 1.0], size=shape)
                      * np.exp2(rng.integers(-3, 4, size=shape)))
                     .astype(np.float32))
-    old = stencil_sum_blocks(
-        blockize_with_halo(cube, T, g, kind=kind, periodic=True), w, g=g)
-    new = stencil_sum_resident(blockize(cube, T, kind=kind), w,
-                               neighbor_table(kind, M // T), g=g)
-    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+    got, want, _ = _identity_launch(cube, w, T, g, kind)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("kind", ("morton", "hilbert"))
 @pytest.mark.parametrize("g,T", [(1, 8), (2, 8), (1, 4), (4, 4)])
 def test_resident_kernel_normal_weights_within_rounding(kind, g, T):
-    """With normal weights the two kernels may round differently: the
-    CPU compiler fuses a different subset of the multiply-adds into FMAs
-    in each program (at g=1 about a tenth of the sites differ, by at
-    most 5e-8 of the largest sum; g=2 and g=4 agree bit for bit). Two
-    roundings of an n-term sum differ by at most 2·n·u·Σ|w·x| (u = 2⁻²⁴);
-    a halo or neighbour-table fault moves a site by O(|w·x|)."""
+    """With normal weights the kernel and the oracle may round
+    differently: the CPU compiler fuses a different subset of the
+    multiply-adds into FMAs in each program. Two roundings of an n-term
+    sum differ by at most 2·n·u·Σ|w·x| (u = 2⁻²⁴); a halo or
+    neighbour-table fault moves a site by O(|w·x|)."""
     M = 16
     s = 2 * g + 1
     cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
     w = rng.normal(size=(s,) * 3).astype(np.float32)
-    halo = blockize_with_halo(cube, T, g, kind=kind, periodic=True)
-    old = stencil_sum_blocks(halo, jnp.asarray(w), g=g)
-    new = stencil_sum_resident(blockize(cube, T, kind=kind), jnp.asarray(w),
-                               neighbor_table(kind, M // T), g=g)
+    got, want, halo = _identity_launch(cube, jnp.asarray(w), T, g, kind)
     x = np.abs(np.asarray(halo, np.float64))
     mag = sum(abs(float(w[a, b, c])) * x[:, a:a + T, b:b + T, c:c + T]
               for a in range(s) for b in range(s) for c in range(s))
-    diff = np.abs(np.asarray(new, np.float64) - np.asarray(old, np.float64))
+    diff = np.abs(np.asarray(got, np.float64) - np.asarray(want, np.float64))
     assert np.all(diff <= 2 * s ** 3 * 2.0 ** -24 * mag)
 
 
@@ -162,7 +172,8 @@ def test_resident_kernel_rejects_non_dividing_g():
     store = jnp.zeros((8, 8, 8, 8), jnp.float32)
     nbr = neighbor_table("morton", 2)
     with pytest.raises(ValueError):
-        stencil_sum_resident(store, jnp.zeros((7, 7, 7)), nbr, g=3)
+        stencil_step_fused(store, jnp.zeros((7, 7, 7)), nbr, g=3, S=1,
+                           rule="identity")
 
 
 # -------------------------------------------------------------- fused pipeline
@@ -171,13 +182,23 @@ def test_resident_kernel_rejects_non_dividing_g():
 @pytest.mark.parametrize("M", [16, 32])
 @pytest.mark.parametrize("g", [1, 2])
 def test_resident_pipeline_matches_repack(ordering, M, g):
-    """Acceptance: resident run bit-identical to the per-step repack run."""
+    """The resident run == the ordering-independent oracles: gol through
+    Gol3d against gol3d_step_ref, exactly, and jacobi through
+    ResidentPipeline on the same block curve against fields_step_ref, to
+    the last-ulp drift of its mean (the two programs round it apart)."""
     steps = 3
-    a = Gol3d(Gol3dConfig(M=M, g=g, ordering=ordering, block_T=8))
-    b = Gol3d(Gol3dConfig(M=M, g=g, ordering=ordering, block_T=8))
-    sa = a.run(steps)
-    sb = b.run_resident(steps)
-    np.testing.assert_array_equal(np.asarray(sa), np.asarray(sb))
+    app = Gol3d(Gol3dConfig(M=M, g=g, ordering=ordering, block_T=8))
+    want = app.reference_run(steps)
+    app.run(steps)
+    np.testing.assert_array_equal(np.asarray(app.cube), np.asarray(want))
+    cube = jnp.asarray(rng.normal(size=(M, M, M)).astype(np.float32))
+    pipe = ResidentPipeline(M=M, T=8, g=g, kind=block_kind_of(ordering),
+                            rule="jacobi")
+    want = cube
+    for _ in range(steps):
+        want = ref.fields_step_ref(want, uniform_weights(g), g, rule="jacobi")
+    np.testing.assert_allclose(np.asarray(pipe.run(cube, steps)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("g", [1, 2])
@@ -185,7 +206,7 @@ def test_resident_pipeline_matches_oracle(g):
     """K=4 fused steps == the ordering-independent canonical oracle."""
     app = Gol3d(Gol3dConfig(M=16, g=g, ordering=HILBERT, block_T=8))
     want = app.reference_run(4)
-    app.run_resident(4)
+    app.run(4)
     np.testing.assert_array_equal(np.asarray(app.cube), np.asarray(want))
 
 
@@ -193,28 +214,15 @@ def test_resident_pipeline_kernel_mode():
     app = Gol3d(Gol3dConfig(M=16, g=1, ordering=MORTON, block_T=8,
                             use_kernel=True))
     want = app.reference_run(2)
-    app.run_resident(2)
+    app.run(2)
     np.testing.assert_array_equal(np.asarray(app.cube), np.asarray(want))
 
 
 def test_resident_step_preserves_weights_semantics():
-    """One resident step == one repack gol3d step at the op level."""
+    """One resident step == one step of the canonical gol3d oracle."""
     M, T, g = 16, 8, 1
     pipe = ResidentPipeline(M=M, T=T, g=g, kind="morton")
     cube = jnp.asarray((rng.random((M, M, M)) < 0.3).astype(np.float32))
     got = pipe.run(cube, 1)
     want = ref.gol3d_step_ref(cube, g)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_bytes_model_resident_wins():
-    """The point of the refactor: strictly fewer bytes/step for K >= 2,
-    with no ((T+2g)/T)³ duplication and no per-step O(M³) repack."""
-    for M, T, g in [(32, 8, 1), (32, 8, 2), (64, 8, 1), (64, 16, 2)]:
-        rep = repack_bytes_per_step(M, T, g)
-        for K in (2, 10, 100):
-            res = resident_bytes_per_step(M, T, g, K)
-            assert res < rep, (M, T, g, K)
-        # resident store itself is exactly M³ items — no halo duplication
-        pipe = ResidentPipeline(M=M, T=T, g=g)
-        assert pipe.nb * T ** 3 == M ** 3

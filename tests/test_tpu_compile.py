@@ -20,7 +20,7 @@ from repro.core.neighbors import boundary_face_table, neighbor_table
 from repro.kernels import backend, stencil3d
 from repro.kernels.ops import uniform_weights
 from repro.kernels.stencil3d import (VMEM_LIMIT_BYTES, fused_kernel_vmem_bytes,
-                                     stencil_step_fused, stencil_sum_resident)
+                                     stencil_step_fused)
 from repro.stencil import Gol3d, Gol3dConfig, ResidentPipeline
 
 HBM_BYTES = int(15.75 * 2 ** 30)   # what XLA lets one v5e program use
@@ -192,14 +192,3 @@ def test_kernel_cache_key_ignores_checkout(one_chip, compiled_path,
         assert root not in cut and b"src/repro/kernels/stencil3d.py" in cut
     finally:
         jax.config.update(name, was)
-
-
-def test_resident_sum_kernel_refused_not_interpreted(one_chip, compiled_path):
-    """stencil_sum_resident keeps its (h, T, h) halo pieces, off the TPU
-    tiling: on the chip it must be refused, never run interpreted."""
-    store = jax.ShapeDtypeStruct((512, 128, 128, 128), jnp.float32,
-                                 sharding=one_chip)
-    fn = jax.jit(lambda s: stencil_sum_resident(
-        s, uniform_weights(1), neighbor_table("hilbert", 8), g=1))
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        fn.lower(store).compile()
