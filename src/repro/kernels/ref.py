@@ -8,9 +8,9 @@ import numpy as np
 
 from repro.core.boundary import PERIODIC, as_boundary, pad_cube
 
-from .rules import apply_window_bc, get_rule
+from .rules import apply_window_bc, box_centre, get_rule
 
-__all__ = ["stencil_sum_ref", "gol_rule_ref", "gol3d_step_ref",
+__all__ = ["stencil_sum_ref", "tap_sum", "gol_rule_ref", "gol3d_step_ref",
            "assemble_halo_ref", "stencil_fused_ref", "fields_step_ref",
            "gather_rows_ref", "attention_ref"]
 
@@ -20,17 +20,45 @@ def stencil_sum_ref(blocks: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
 
     blocks:  (nb, T+2g, T+2g, T+2g)
     weights: (2g+1, 2g+1, 2g+1)
-    returns: (nb, T, T, T) — acc[b, z] = sum_d w[d] * blocks[b, z+d]
+    returns: (nb, T, T, T) — acc[b, z] = sum_d w[d] * blocks[b, z+d],
+             summed in the fused kernel's order (:func:`tap_sum`)
+    """
+    return tap_sum(blocks, weights)
+
+
+def tap_sum(x: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
+    """The (2g+1)³ tap sum over the last three axes of ``x`` (each
+    shrinks by 2g), in f32 and in the fused kernel's order.
+
+    Box weights (rules.box_centre) take the box form: a j pass, an i
+    pass and a k pass, each a left fold of 2g+1 offset slices in offset
+    order, and then, for a zero centre, ``- centre``. Any other weights
+    take the weighted form: ``w·x`` terms folded in (dk, di, dj) order.
     """
     s = weights.shape[0]
     g = (s - 1) // 2
-    T = blocks.shape[1] - 2 * g
-    acc = jnp.zeros((blocks.shape[0], T, T, T), dtype=jnp.float32)
+    Ok, Oi, Oj = (n - 2 * g for n in x.shape[-3:])
+    box = box_centre(weights)
+    if box is not None:
+        x = x.astype(jnp.float32)
+        r = x[..., :Oj]
+        for dj in range(1, s):
+            r = r + x[..., dj:dj + Oj]
+        part = r[..., :Oi, :]
+        for di in range(1, s):
+            part = part + r[..., di:di + Oi, :]
+        acc = part[..., :Ok, :, :]
+        for dk in range(1, s):
+            acc = acc + part[..., dk:dk + Ok, :, :]
+        if box == 0.0:
+            acc = acc - x[..., g:g + Ok, g:g + Oi, g:g + Oj]
+        return acc
+    acc = jnp.zeros(x.shape[:-3] + (Ok, Oi, Oj), dtype=jnp.float32)
     for dk in range(s):
         for di in range(s):
             for dj in range(s):
                 acc = acc + weights[dk, di, dj].astype(jnp.float32) * (
-                    blocks[:, dk:dk + T, di:di + T, dj:dj + T].astype(jnp.float32))
+                    x[..., dk:dk + Ok, di:di + Oi, dj:dj + Oj].astype(jnp.float32))
     return acc
 
 
@@ -124,11 +152,11 @@ def fields_step_ref(fields: jnp.ndarray, weights: jnp.ndarray, g: int,
 
     The ordering-independent sequential oracle of the C-channel stack
     (DESIGN.md §9): ghost-extend every channel under ``bc``
-    (core.boundary.pad_cube — per-axis for mixed contracts), accumulate
-    the weighted tap sum per channel **in the same dk,di,dj order as
-    stencil_sum_ref** (so f32 results match the blocked paths bitwise,
-    not just numerically), then apply the registry rule to the stacked
-    fields. A 3-D input is treated as C=1 and returned 3-D.
+    (core.boundary.pad_cube — per-axis for mixed contracts), take the
+    tap sum per channel **in the fused kernel's order** (:func:`tap_sum`,
+    so f32 results match the blocked paths bitwise, not just
+    numerically), then apply the registry rule to the stacked fields. A
+    3-D input is treated as C=1 and returned 3-D.
     """
     r = get_rule(rule)
     squeeze = fields.ndim == 3
@@ -142,12 +170,7 @@ def fields_step_ref(fields: jnp.ndarray, weights: jnp.ndarray, g: int,
     s = weights.shape[0]
     assert s == 2 * g + 1, (weights.shape, g)
     xp = jnp.stack([pad_cube(fields[c], g, bc) for c in range(C)])
-    tap = jnp.zeros((C, M, M, M), dtype=jnp.float32)
-    for dk in range(s):
-        for di in range(s):
-            for dj in range(s):
-                tap = tap + weights[dk, di, dj].astype(jnp.float32) * (
-                    xp[:, dk:dk + M, di:di + M, dj:dj + M].astype(jnp.float32))
+    tap = tap_sum(xp, weights)
     out = r.apply(fields.astype(jnp.float32), tap, g).astype(fields.dtype)
     return out[0] if squeeze else out
 

@@ -42,11 +42,12 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.boundary import BoundarySpec, MixedBoundary, as_boundary
 
 __all__ = ["UpdateRule", "RULES", "get_rule", "gol_thresholds",
-           "WAVE_KAPPA", "apply_window_bc"]
+           "WAVE_KAPPA", "apply_window_bc", "box_centre", "tap_form"]
 
 
 @dataclass(frozen=True)
@@ -217,6 +218,31 @@ def apply_window_bc(x: jnp.ndarray, flags, depth: int,
         x = jnp.where((iota < depth) & flag(2 * ax), lo_fill, x)
         x = jnp.where((iota >= E - depth) & flag(2 * ax + 1), hi_fill, x)
     return x
+
+
+def box_centre(weights) -> float | None:
+    """The centre weight if ``weights`` are the (2g+1)³ box, else None.
+
+    The box: concrete weights, every off-centre one exactly 1.0 and the
+    centre 0.0 or 1.0 (ops.uniform_weights is the zero-centre box). Its
+    tap sum needs no multiply and is separable, so the fused kernel and
+    the oracles sum it as three (2g+1)-tap passes (the box form,
+    stencil3d). Any other weights, or a traced array whose values are
+    unknown when the kernel is traced, take the weighted form.
+    """
+    if isinstance(weights, jax.core.Tracer):
+        return None
+    w = np.asarray(weights, np.float32)
+    mid = w.size // 2                      # the centre of a (2g+1)³ cube
+    centre = float(w.flat[mid])
+    off = np.delete(w.ravel(), mid)
+    return centre if centre in (0.0, 1.0) and np.all(off == 1.0) else None
+
+
+def tap_form(weights) -> str:
+    """``"box"`` or ``"weighted"``: the tap sum the fused kernel and its
+    oracles run for these weights (:func:`box_centre`)."""
+    return "weighted" if box_centre(weights) is None else "box"
 
 
 def get_rule(rule: str | UpdateRule) -> UpdateRule:

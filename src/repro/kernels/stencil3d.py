@@ -41,14 +41,34 @@ minor dimensions off the (8, 128) tiling, so the kernel runs a
 ``(nb, T/kc)`` grid of k-chunks whose pieces are whole along j and
 whole sublane tiles along i, and cuts the i/j halos in VMEM. A tap at
 an (i, j) offset off that tiling costs a rotate and a select per vreg,
-so each substep shifts every window plane once into its (2g+1)² - 1
-offset copies, stored aligned in a VMEM ring that holds the 2g+1
-planes in flight, and every tap is an aligned load of a copy, summed
-in the original order (``_fused_kernel``). Its VMEM per grid step is
-the ``(C, kc+2h, T+2h, T+2h)`` window, the ring of
-``(2g+1)·((2g+1)² - 1)·C`` planes of ``(T+2h, T+2h-2g)``, and the
-double-buffered pieces and output slab (``fused_kernel_vmem_bytes``) —
-13.1 MiB at T=128, S=4, g=1; only T=128 makes the store lane-dense.
+so each substep touches every window plane once per pass and keeps the
+result aligned in a VMEM ring that holds the 2g+1 planes in flight.
+
+Two tap-sum forms (DESIGN.md §4), chosen at trace time from the
+weights' values (rules.box_centre), share assembly, refresh, rule and
+write:
+
+- box, for concrete weights that are the (2g+1)³ box of ones with a
+  centre of 0 or 1 (ops.uniform_weights, which every pipeline passes):
+  each window plane is summed into one ij-partial per channel, a j
+  pass of lane-offset slices then an i pass of sublane-offset slices
+  (``_box_partial``); an output plane is the k fold of 2g+1 partials,
+  less the centre for a zero-centre box. No multiplies.
+- weighted, for any other weights or a traced array: each window plane
+  is shifted once into its (2g+1)² - 1 offset copies (``_fused_shift``)
+  and every one of the 27 taps is an aligned load of a copy, weighted
+  and summed in (dk, di, dj) order.
+
+Every fold runs left to right in offset order, and ref.tap_sum takes the
+same form in the same order, so kernel and oracle sum in one order;
+one S-substep launch is bit-identical to S single-substep launches in
+either form. The two forms sum in different orders: exactly equal for
+integer sums (gol), within f32 reassociation otherwise. VMEM per grid
+step is the ``(C, kc+2h, T+2h, T+2h)`` window, the ring — ``(2g+2)·C``
+planes of ``(T+2h, T+2h-2g)`` in the box form, ``(2g+1)·((2g+1)² -
+1)·C`` in the weighted form — and the double-buffered pieces and output
+slab (``fused_kernel_vmem_bytes``): 10.4 MiB (box) and 13.1 MiB
+(weighted) at T=128, S=4, g=1; only T=128 makes the store lane-dense.
 Correctness is checked against ref.stencil_fused_ref.
 """
 
@@ -65,7 +85,7 @@ from repro.core.boundary import (PERIODIC, BoundarySpec, MixedBoundary,
                                  as_boundary)
 
 from .backend import resolve_interpret
-from .rules import apply_window_bc, get_rule
+from .rules import apply_window_bc, box_centre, get_rule
 
 __all__ = ["stencil_step_fused", "fused_geometry", "fused_kernel_vmem_bytes"]
 
@@ -106,20 +126,23 @@ def _tile_bytes(rows: int, lanes: int) -> int:
 
 
 def fused_kernel_vmem_bytes(T: int, h: int, fields: int = 1,
-                            itemsize: int = 4, *, g: int = 1) -> int:
+                            itemsize: int = 4, *, g: int = 1,
+                            form: str = "weighted") -> int:
     """VMEM one grid step of ``stencil_step_fused`` allocates.
 
     Two f32 scratches, each plane padded to whole (8, 128) tiles: the
-    ``(C, kc+2h, T+2h, T+2h)`` window and the tap-copy ring of
-    ``(2g+1)·((2g+1)² - 1)·C`` planes of ``(T+2h, T+2h-2g)``. Then the
-    27 pieces (three j-columns of ``(kc+2h) x (T+2·hb) x T`` each) and
-    the ``C·kc·T²`` output slab, both double-buffered.
+    ``(C, kc+2h, T+2h, T+2h)`` window and the ring of planes of
+    ``(T+2h, T+2h-2g)`` — ``(2g+1)·((2g+1)² - 1)·C`` tap copies in the
+    weighted form, ``(2g+2)·C`` in the box form (rules.tap_form). Then
+    the 27 pieces (three j-columns of ``(kc+2h) x (T+2·hb) x T`` each)
+    and the ``C·kc·T²`` output slab, both double-buffered.
     """
     kc, hb = fused_geometry(T, h)
     W = T + 2 * h
     s = 2 * g + 1
     window = fields * (kc + 2 * h) * _tile_bytes(W, W)
-    ring = fields * s * (s * s - 1) * _tile_bytes(W, W - 2 * g)
+    planes = s + 1 if form == "box" else s * (s * s - 1)
+    ring = fields * planes * _tile_bytes(W, W - 2 * g)
     pieces = 3 * (kc + 2 * h) * (T + 2 * hb) * T
     return window + ring + 2 * itemsize * fields * (pieces + kc * T * T)
 
@@ -246,8 +269,28 @@ def _fused_shift(win, ring, q, slot, Ei: int, Oi: int, s: int):
                     ring[slot, dj - 1, c, di:di + Oi, :Oi]
 
 
-def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
-                  S: int, kc: int, hb: int, rule, bc):
+def _box_partial(win, ring, q, slot, Ei: int, Oi: int, s: int):
+    """Write the ij-partial box sum of window plane q into ring ``slot``
+    (q mod (2g+1)), aligned at offset (0, 0).
+
+    The j pass folds the 2g+1 lane-offset slices ``win[c, q, :Ei,
+    dj:dj+Oi]`` over all Ei rows and stages the sum aligned in ring slot
+    2g+1; the i pass folds its 2g+1 sublane-offset row slices. Each fold
+    is left to right in offset order, as ref.stencil_sum_ref's box form.
+    """
+    for c in range(win.shape[0]):
+        r = win[c, q, :Ei, :Oi]
+        for dj in range(1, s):
+            r = r + win[c, q, :Ei, dj:dj + Oi]
+        ring[s, c, :Ei, :Oi] = r
+        part = ring[s, c, :Oi, :Oi]
+        for di in range(1, s):
+            part = part + ring[s, c, di:di + Oi, :Oi]
+        ring[slot, c, :Oi, :Oi] = part
+
+
+def _fused_kernel(nbr_ref, bnd_ref, *refs, T: int, s: int, g: int,
+                  S: int, kc: int, hb: int, rule, bc, box: float | None):
     """S substeps of tap-sum + update rule on one k-chunk, in VMEM.
 
     Grid step (i, z) computes planes [z·kc, (z+1)·kc) of block i. Its
@@ -274,6 +317,13 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
     same values as a sum of window slices, so every output is
     bit-identical to it.
 
+    Box form (``box`` is the centre weight, rules.box_centre): the same
+    walk, but plane q's ring slot holds one ij-partial sum per channel
+    (``_box_partial``) instead of its tap copies, and output plane p is
+    the k fold of slots p..p+2g — no weights, no multiplies. A zero
+    centre is then taken off: ``tap = B - centre``, the centre read from
+    window plane p+g before plane p is overwritten.
+
     Clamped runs (DESIGN.md §8): before every substep, the outer
     ``g·(S-u)`` ghost layers on faces flagged in ``bnd_ref`` (the second
     scalar-prefetch operand) are substituted with boundary values —
@@ -282,7 +332,10 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
     temporally block exactly as deep as periodic ones. The ring is
     formed from the refreshed window.
     """
+    if box is None:
+        w_ref, refs = refs[0], refs[1:]
     pieces, o_ref, win, ring = refs[:27], refs[27], refs[28], refs[29]
+    form_plane = _fused_shift if box is None else _box_partial
     multi = len(o_ref.shape) == 5
     C = win.shape[0]
     h = S * g
@@ -300,29 +353,38 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
             _fused_refresh(win, flags, d, Ek, Ei, bc)
 
         def first(q, carry, Ei=Ei, Oi=Oi):     # planes 0..2g-1 into the ring
-            _fused_shift(win, ring, q, q, Ei, Oi, s)
+            form_plane(win, ring, q, q, Ei, Oi, s)
             return carry
         jax.lax.fori_loop(0, 2 * g, first, 0)
 
         def plane(p, carry, Ei=Ei, Oi=Oi, last=u == S - 1):
             slots = [jax.lax.rem(p + dk, s) for dk in range(s)]
-            _fused_shift(win, ring, p + 2 * g, slots[2 * g], Ei, Oi, s)
-
-            def tap(dk, di, dj, c):
-                if di == dj == 0:
-                    return win[c, p + dk, :Oi, :Oi]
-                return ring[slots[dk], di * s + dj - 1, c, :Oi, :Oi]
+            form_plane(win, ring, p + 2 * g, slots[2 * g], Ei, Oi, s)
 
             taps = []
-            for c in range(C):
-                acc = jnp.zeros((Oi, Oi), jnp.float32)
-                for dk in range(s):
-                    for di in range(s):
-                        for dj in range(s):
-                            acc = acc + w_ref[dk, di, dj].astype(jnp.float32) \
-                                * tap(dk, di, dj, c)
-                taps.append(acc)
-            centre = jnp.stack([tap(g, g, g, c) for c in range(C)])
+            if box is None:
+                def tap(dk, di, dj, c):
+                    if di == dj == 0:
+                        return win[c, p + dk, :Oi, :Oi]
+                    return ring[slots[dk], di * s + dj - 1, c, :Oi, :Oi]
+
+                for c in range(C):
+                    acc = jnp.zeros((Oi, Oi), jnp.float32)
+                    for dk in range(s):
+                        for di in range(s):
+                            for dj in range(s):
+                                acc = acc + w_ref[dk, di, dj].astype(
+                                    jnp.float32) * tap(dk, di, dj, c)
+                    taps.append(acc)
+                centre = jnp.stack([tap(g, g, g, c) for c in range(C)])
+            else:
+                cs = [win[c, p + g, g:g + Oi, g:g + Oi] for c in range(C)]
+                for c in range(C):
+                    acc = ring[slots[0], c, :Oi, :Oi]
+                    for dk in range(1, s):
+                        acc = acc + ring[slots[dk], c, :Oi, :Oi]
+                    taps.append(acc - cs[c] if box == 0.0 else acc)
+                centre = jnp.stack(cs)
             new = rule.apply(centre, jnp.stack(taps), g)
             if not last:
                 win[:, p, :Oi, :Oi] = new
@@ -334,8 +396,6 @@ def _fused_kernel(nbr_ref, bnd_ref, w_ref, *refs, T: int, s: int, g: int,
         jax.lax.fori_loop(0, Ek - 2 * g, plane, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("g", "S", "rule", "bc",
-                                             "interpret"))
 def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
                        nbr: jnp.ndarray, bnd: jnp.ndarray | None = None,
                        *, g: int, S: int = 1, rule: str = "gol",
@@ -354,7 +414,9 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
              one block permutation, neighbour table and grid: one grid
              step assembles C windows and writes C slabs.
     weights: (2g+1, 2g+1, 2g+1) tap weights (ops.uniform_weights for the
-             classic neighbour-count rules), shared by every channel
+             classic neighbour-count rules), shared by every channel.
+             Concrete box weights take the box form, any others the
+             weighted form (rules.tap_form), decided here at trace time
     nbr:     (nb, 27) int32 neighbour table (core.neighbors — periodic,
              clamped, mixed, or extended), scalar-prefetched; nb ≤
              nb_src, and column SELF_COL must be the row index (the
@@ -384,6 +446,14 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
     run in f32; non-f32 stores would round once per launch instead of
     once per step, so bit-identity to the sequential path is f32-only.
     """
+    return _fused_launch(store, weights, nbr, bnd, g=g, S=S, rule=rule,
+                         bc=bc, interpret=interpret, box=box_centre(weights))
+
+
+@functools.partial(jax.jit, static_argnames=("g", "S", "rule", "bc",
+                                             "interpret", "box"))
+def _fused_launch(store, weights, nbr, bnd, *, g: int, S: int, rule: str,
+                  bc, interpret: bool | None, box: float | None):
     interpret = resolve_interpret(interpret)
     r = get_rule(rule)
     multi = store.ndim == 5
@@ -420,7 +490,7 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
     def fixed(i, z, nbr_ref, bnd_ref):
         return (0, 0, 0)
 
-    in_specs = [pl.BlockSpec((s, s, s), fixed)]
+    in_specs = [] if box is not None else [pl.BlockSpec((s, s, s), fixed)]
     in_specs += _fused_piece_specs(T, h, kc, hb, C if multi else None)
     if multi:
         out_shape = jax.ShapeDtypeStruct((C, nb, T, T, T), store.dtype)
@@ -431,10 +501,12 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
         out_spec = pl.BlockSpec((1, kc, T, T),
                                 lambda i, z, nbr_ref, bnd_ref: (i, z, 0, 0))
     kern = functools.partial(_fused_kernel, T=T, s=s, g=g, S=S, kc=kc, hb=hb,
-                             rule=r, bc=bc)
+                             rule=r, bc=bc, box=box)
     W = T + 2 * h
+    ring = (s + 1, C) if box is not None else (s, s * s - 1, C)
     scratch = [pltpu.VMEM((C, kc + 2 * h, W, W), jnp.float32),
-               pltpu.VMEM((s, s * s - 1, C, W, W - 2 * g), jnp.float32)]
+               pltpu.VMEM(ring + (W, W - 2 * g), jnp.float32)]
+    operands = ([] if box is not None else [weights]) + [store] * 27
     return pl.pallas_call(
         kern,
         out_shape=out_shape,
@@ -448,4 +520,4 @@ def stencil_step_fused(store: jnp.ndarray, weights: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="stencil_step_fused",
         interpret=interpret,
-    )(nbr.astype(jnp.int32), bnd.astype(jnp.int32), weights, *([store] * 27))
+    )(nbr.astype(jnp.int32), bnd.astype(jnp.int32), *operands)

@@ -56,7 +56,7 @@ from repro.core.orderings import OrderingSpec
 from repro.kernels import ref as kref
 from repro.kernels import backend
 from repro.kernels.ops import uniform_weights
-from repro.kernels.rules import get_rule
+from repro.kernels.rules import get_rule, tap_form
 from repro.kernels.stencil3d import (LANES, VMEM_LIMIT_BYTES,
                                      fused_kernel_vmem_bytes,
                                      stencil_step_fused)
@@ -145,6 +145,12 @@ class ResidentPipeline:
     def channels(self) -> int:
         """C of the rule's store — the ×C factor of every byte model."""
         return get_rule(self.rule).channels
+
+    @property
+    def tap_form(self) -> str:
+        """The tap sum every launch runs: ``"box"`` for the uniform
+        weights every pipeline passes (rules.tap_form)."""
+        return tap_form(uniform_weights(self.g))
 
     # -- autotuner ---------------------------------------------------------
     @classmethod
@@ -297,7 +303,9 @@ def _plan_search(M: int, g: int, max_S: int, vmem_limit: int, itemsize: int,
             h = S * g
             if h <= T and T % h == 0:
                 if compiled:
-                    vm = fused_kernel_vmem_bytes(T, h, fields, itemsize, g=g)
+                    vm = fused_kernel_vmem_bytes(
+                        T, h, fields, itemsize, g=g,
+                        form=tap_form(uniform_weights(g)))
                     fits = vm <= VMEM_LIMIT_BYTES
                 else:
                     vm = fused_vmem_bytes(T, g, S, itemsize, fields=fields)
@@ -568,6 +576,8 @@ class DistributedPipeline:
     @property
     def channels(self) -> int:
         return get_rule(self.rule).channels
+
+    tap_form = ResidentPipeline.tap_form
 
     @property
     def procs(self) -> tuple[int, int, int]:

@@ -9,6 +9,7 @@ data, so cross-family checks are exact for gol (integer-valued sums)
 and allclose for jacobi.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,7 +19,7 @@ from repro.core import (MORTON, NEUMANN0, PERIODIC, blockize,
 from repro.core.neighbors import neighbor_table
 from repro.kernels import ref
 from repro.kernels.ops import uniform_weights
-from repro.kernels.rules import RULES, get_rule
+from repro.kernels.rules import RULES, box_centre, get_rule, tap_form
 from repro.kernels.stencil3d import (VMEM_LIMIT_BYTES, fused_kernel_vmem_bytes,
                                      stencil_step_fused)
 from repro.stencil import Gol3d, Gol3dConfig
@@ -252,13 +253,14 @@ def test_surface_row_plan_cached():
 
 
 # ------------------------------------------- chunked grid (TPU tiling form)
-@pytest.mark.parametrize("rule", ["gol", "jacobi", "wave"])
+@pytest.mark.parametrize("rule", ["gol", "jacobi", "wave", "identity"])
 @pytest.mark.parametrize("bc", ["periodic", "neumann0", "dirichlet"])
 def test_fused_kernel_k_chunks_match_oracle(rule, bc):
     """The (nb, T/kc) grid with 8-row i-halo pieces — the form that keeps
     every block shape on the TPU's (8, 128) tiling — equals the jnp
     oracle on every k-chunk (first and last chunks take one k halo from
-    the k-neighbour block, interior chunks take both from their own)."""
+    the k-neighbour block, interior chunks take both from their own).
+    identity runs normal weights: the weighted form, to rounding."""
     from repro.core.boundary import as_boundary
     from repro.core.neighbors import boundary_face_table
     from repro.kernels.stencil3d import fused_geometry
@@ -274,13 +276,18 @@ def test_fused_kernel_k_chunks_match_oracle(rule, bc):
     store = blockize_fields(jnp.asarray(fields), T_, kind="hilbert")
     if C == 1:
         store = store[0]
-    w = uniform_weights(G)
+    w = _ring_weights(rule, G)
     assert fused_geometry(T_, S * G) == (8, 8)        # 4 chunks, 8-row halos
     chunked = stencil_step_fused(store, w, nbr, bnd, g=G, S=S, rule=rule,
                                  bc=bc)
     oracle = ref.stencil_fused_ref(store, w, nbr, S=S, rule=rule, bc=bc,
                                    bnd=bnd)
-    if rule == "jacobi":
+    if rule == "identity":   # two steps of 27 normal-weighted terms reach
+        # ~200, and the two programs contract different multiply-adds
+        # into FMAs: they round apart by up to ~2e-5 of that scale
+        np.testing.assert_allclose(np.asarray(chunked), np.asarray(oracle),
+                                   rtol=1e-4, atol=1e-4)
+    elif rule == "jacobi":
         np.testing.assert_allclose(np.asarray(chunked), np.asarray(oracle),
                                    rtol=1e-5, atol=1e-5)
     else:
@@ -294,7 +301,17 @@ RING_CASES = [
     ("wave", 2, 1, "periodic"), ("wave", 4, 1, "periodic"),
     ("jacobi", 2, 2, "periodic"),
     ("wave", 2, 1, "mixed-i"),
+    ("identity", 2, 1, "periodic"), ("identity", 2, 2, "periodic"),
 ]
+
+
+def _ring_weights(rule, g):
+    """identity runs normal weights, so the 27-tap weighted form keeps
+    its coverage; every other rule the uniform box (the box form)."""
+    if rule == "identity":
+        return jnp.asarray(rng.normal(size=(2 * g + 1,) * 3)
+                           .astype(np.float32))
+    return uniform_weights(g)
 
 
 def _ring_store(rule, M_, T_):
@@ -313,7 +330,8 @@ def test_fused_ring_matches_sequential_launches(rule, S, g, bc):
     window (34-40 rows and lanes) is not a whole number of sublane
     tiles. One S-substep launch equals S single-substep launches bit
     for bit, and the jnp oracle exactly for the rules whose sums are
-    exact (gol) or FMA-immune (wave)."""
+    exact (gol) or FMA-immune (wave). identity runs normal weights
+    (``_ring_weights``): the 27-tap weighted form."""
     from repro.core.boundary import as_boundary, axes_periodic, mixed
     from repro.core.neighbors import boundary_face_table
 
@@ -323,7 +341,7 @@ def test_fused_ring_matches_sequential_launches(rule, S, g, bc):
     nbr = neighbor_table("hilbert", nt, periodic=axes_periodic(bcs))
     bnd = boundary_face_table("hilbert", nt) if bcs.clamped else None
     store = _ring_store(rule, M_, T_)
-    w = uniform_weights(g)
+    w = _ring_weights(rule, g)
     fused = stencil_step_fused(store, w, nbr, bnd, g=g, S=S, rule=rule,
                                bc=bcs)
     seq = store
@@ -331,7 +349,7 @@ def test_fused_ring_matches_sequential_launches(rule, S, g, bc):
         seq = stencil_step_fused(seq, w, nbr, bnd, g=g, S=1, rule=rule,
                                  bc=bcs)
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(seq))
-    if rule != "jacobi":
+    if rule in ("gol", "wave"):
         oracle = ref.stencil_fused_ref(store, w, nbr, S=S, rule=rule,
                                        bc=bcs, bnd=bnd)
         np.testing.assert_array_equal(np.asarray(fused), np.asarray(oracle))
@@ -363,6 +381,11 @@ def test_fused_kernel_vmem_counts_ring():
     assert fused_kernel_vmem_bytes(128, 4, 2, g=2) == \
         2 * fused_kernel_vmem_bytes(128, 4, g=2)
     assert fused_kernel_vmem_bytes(128, 4, 2, g=2) <= VMEM_LIMIT_BYTES
+    # the box form: 2g+1 ij-partial planes and the j-partial's stage
+    assert fused_kernel_vmem_bytes(128, 4, g=1, form="box") == \
+        window + 4 * plane + streamed
+    assert fused_kernel_vmem_bytes(128, 4, g=2, form="box") == \
+        window + 6 * plane + streamed
 
 
 def test_fused_geometry_keeps_tpu_tiling():
@@ -399,3 +422,104 @@ def test_platform_picks_kernel_mode(monkeypatch):
     tuned = ResidentPipeline.plan(1024, g=1)
     assert tuned.T == 128
     assert fused_kernel_vmem_bytes(128, tuned.S) <= VMEM_LIMIT_BYTES
+
+
+# ------------------------------------------------------------- the box form
+def _box_with(value, at=(1, 1, 1)):
+    """The g=1 box of ones with one weight set to ``value``."""
+    w = np.ones((3, 3, 3), np.float32)
+    w[at] = value
+    return w
+
+
+FORM_CASES = {
+    "uniform-g1": (lambda: uniform_weights(1), 0.0),
+    "uniform-g2": (lambda: uniform_weights(2), 0.0),
+    "uniform-g1-device": (lambda: jnp.asarray(uniform_weights(1)), 0.0),
+    "all-ones-g1": (lambda: _box_with(1.0), 1.0),
+    "all-ones-g2": (lambda: np.ones((5, 5, 5), np.float32), 1.0),
+    "normal-g1": (lambda: rng.normal(size=(3, 3, 3)).astype(np.float32), None),
+    "one-corner-off": (lambda: _box_with(2.0, at=(0, 0, 0)), None),
+    "half-centre": (lambda: _box_with(0.5), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FORM_CASES))
+def test_tap_form_detects_the_box(case):
+    """The box form is taken for concrete weights whose off-centre taps
+    are all exactly 1.0 and whose centre is 0.0 or 1.0; any other
+    weights take the weighted form."""
+    make, centre = FORM_CASES[case]
+    w = make()
+    assert box_centre(w) == centre
+    assert tap_form(w) == ("weighted" if centre is None else "box")
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_tap_form_of_traced_weights_is_weighted(g):
+    """Traced weights have no values when the kernel is traced."""
+    seen = []
+    jax.jit(lambda w: seen.append(tap_form(w)) or w)(uniform_weights(g))
+    assert tap_form(uniform_weights(g)) == "box"
+    assert seen == ["weighted"]
+
+
+BOX_CASES = [("gol", 2, 1), ("gol", 1, 2), ("jacobi", 2, 1),
+             ("jacobi", 1, 2), ("wave", 2, 1), ("identity", 1, 1),
+             ("identity", 1, 2)]
+
+
+@pytest.mark.parametrize("rule,S,g", BOX_CASES)
+def test_box_form_matches_weighted_form(rule, S, g):
+    """The box form (concrete uniform weights) against the weighted form
+    (the same weights traced) on normal data. They sum in different
+    orders: gol's integer sums agree exactly; the raw sum (identity)
+    within the f32 reassociation bound 2·n·u·Σ|x| per site (n = s³
+    terms, u = 2⁻²⁴); jacobi and wave within rtol = atol = 1e-5, the
+    tolerance the repo uses across programs for jacobi."""
+    M_, T_ = 16, 8
+    s = 2 * g + 1
+    nbr = neighbor_table("hilbert", M_ // T_)
+    store = _ring_store(rule, M_, T_)
+    w = uniform_weights(g)
+    box = stencil_step_fused(store, w, nbr, g=g, S=S, rule=rule)
+    weighted = jax.jit(lambda st, w: stencil_step_fused(
+        st, w, nbr, g=g, S=S, rule=rule))(store, w)
+    box, weighted = np.asarray(box), np.asarray(weighted)
+    if rule == "gol":
+        np.testing.assert_array_equal(box, weighted)
+    elif rule == "identity":
+        halo = np.abs(np.asarray(ref.assemble_halo_ref(store, nbr, g),
+                                 np.float64))
+        mag = sum(halo[:, a:a + T_, b:b + T_, c:c + T_]
+                  for a in range(s) for b in range(s) for c in range(s))
+        diff = np.abs(box.astype(np.float64) - weighted)
+        assert np.all(diff <= 2 * s ** 3 * 2.0 ** -24 * mag)
+        assert not np.array_equal(box, weighted)   # two orders, not one
+    else:
+        np.testing.assert_allclose(box, weighted, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["jacobi3d-g1-hilbert-1024",
+                                  "jacobi3d-g1-hilbert-mesh2x2-512"])
+def test_benchmark_pipelines_take_the_box_form(name):
+    """Both benchmark configurations launch the box form: the pipelines
+    pass the uniform weights, whose form is decided at trace time."""
+    import json
+    from pathlib import Path
+
+    from repro.core.layout import store_spec
+    from repro.stencil import DistributedPipeline, make_stencil_mesh
+
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / f"{name}.json")
+    c = json.loads(path.read_text())
+    if "mesh" in c:   # the form does not depend on the mesh's shape
+        pipe = DistributedPipeline(
+            mesh=make_stencil_mesh((1, 1, 1)),
+            spec=store_spec(c["kind"], c["T"]), M=c["M"], T=c["T"],
+            g=c["g"], S=c["S"], rule=c["rule"], bc=c["bc"])
+    else:
+        pipe = ResidentPipeline(M=c["M"], T=c["T"], g=c["g"], kind=c["kind"],
+                                S=c["S"], rule=c["rule"], bc=c["bc"])
+    assert pipe.tap_form == "box"

@@ -89,12 +89,20 @@ def test_fused_kernel_compiles(one_chip, compiled_path, rule, bc, M):
     _check(fn.lower(sds(shape)).compile())
 
 
-def _fused_fn(rule, M, S, g):
+def _fused_lowered(one_chip, rule, M, S, g, form="box"):
+    """The fused kernel lowered for one chip at T=128. The box form takes
+    the uniform weights as a constant; the weighted form takes them as
+    an operand, whose values are unknown when the kernel is traced."""
     T = 128
     nt = M // T
-    return jax.jit(lambda store: stencil_step_fused(
-        store, uniform_weights(g), neighbor_table("hilbert", nt), g=g, S=S,
-        rule=rule))
+    nbr = neighbor_table("hilbert", nt)
+    store = _fused_store(one_chip, rule, M)
+    if form == "box":
+        return jax.jit(lambda store: stencil_step_fused(
+            store, uniform_weights(g), nbr, g=g, S=S, rule=rule)).lower(store)
+    w = jax.ShapeDtypeStruct((2 * g + 1,) * 3, jnp.float32, sharding=one_chip)
+    return jax.jit(lambda store, w: stencil_step_fused(
+        store, w, nbr, g=g, S=S, rule=rule)).lower(store, w)
 
 
 def _fused_store(one_chip, rule, M):
@@ -104,35 +112,40 @@ def _fused_store(one_chip, rule, M):
 
 
 def test_fused_ring_compiles_two_channels(one_chip, compiled_path):
-    """wave's two channels each take a tap-copy ring, within the limit."""
-    assert fused_kernel_vmem_bytes(128, 4, 2, g=1) <= VMEM_LIMIT_BYTES
-    _check(_fused_fn("wave", 512, 4, 1).lower(
-        _fused_store(one_chip, "wave", 512)).compile())
+    """wave's two channels each take a ring, within the limit, in both
+    tap-sum forms."""
+    for form in ("box", "weighted"):
+        assert fused_kernel_vmem_bytes(128, 4, 2, g=1, form=form) \
+            <= VMEM_LIMIT_BYTES
+        _check(_fused_lowered(one_chip, "wave", 512, 4, 1, form).compile())
 
 
 def test_fused_ring_vmem_is_what_the_compiler_allocates(
         one_chip, compiled_path, monkeypatch):
-    """g=2, S=2 carries the widest ring, 5 planes x 24 shifts (16 MiB of
-    the kernel's 26 MiB). The kernel compiles with its scoped limit set
-    1/32 above ``fused_kernel_vmem_bytes`` (Mosaic takes some of the
+    """In both tap-sum forms the kernel compiles with its scoped limit
+    set 1/32 above ``fused_kernel_vmem_bytes`` (Mosaic takes some of the
     room it is given for itself) and is refused 1 MiB below it: the
-    model counts what the kernel allocates, the ring included."""
-    T, h, g = 128, 4, 2
-    model = fused_kernel_vmem_bytes(T, h, g=g)
-    assert model <= VMEM_LIMIT_BYTES
-    store = _fused_store(one_chip, "jacobi", 256)
+    model counts what the kernel allocates, the ring of the form that
+    runs included. The weighted form at g=2, S=2 carries the widest
+    ring, 5 planes x 24 shifts (16 MiB of the kernel's 26 MiB); the box
+    form at the cells' g=1, S=4 holds one ij-partial plane per slot and
+    the j-partial's stage."""
+    T = 128
 
-    def compile_under(limit):
+    def compile_under(limit, form, S, g):
         monkeypatch.setattr(stencil3d, "VMEM_LIMIT_BYTES", limit)
-        stencil_step_fused.clear_cache()
-        return _fused_fn("jacobi", 256, 2, g).lower(store).compile()
+        stencil3d._fused_launch.clear_cache()
+        return _fused_lowered(one_chip, "jacobi", 256, S, g, form).compile()
 
     try:
-        _check(compile_under(model + model // 32))
-        with pytest.raises(jax.errors.JaxRuntimeError, match="vmem"):
-            compile_under(model - 2 ** 20)
+        for form, S, g in (("weighted", 2, 2), ("box", 4, 1)):
+            model = fused_kernel_vmem_bytes(T, S * g, g=g, form=form)
+            assert model <= VMEM_LIMIT_BYTES
+            _check(compile_under(model + model // 32, form, S, g))
+            with pytest.raises(jax.errors.JaxRuntimeError, match="vmem"):
+                compile_under(model - 2 ** 20, form, S, g)
     finally:
-        stencil_step_fused.clear_cache()
+        stencil3d._fused_launch.clear_cache()
 
 
 def test_resident_pipeline_run_fn_compiles(one_chip, compiled_path):
